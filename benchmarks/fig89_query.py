@@ -153,6 +153,17 @@ def _forward_join_rows(rels, query_cells):
     return cur
 
 
+def _backward_join_rows(rels, query_cells):
+    """Hash-join backward propagation over uncompressed row matrices."""
+    cur = _ravel(query_cells, rels[-1].out_shape)
+    for rel in reversed(rels):
+        in_r = _ravel(rel.in_idx, rel.in_shape)
+        out_r = _ravel(rel.out_idx, rel.out_shape)
+        mask = np.isin(out_r, cur)
+        cur = np.unique(in_r[mask])
+    return cur
+
+
 def _forward_array_scan(rels, query_cells):
     """Vectorized equality scan per query cell (the Array baseline)."""
     cur = _ravel(query_cells, rels[0].in_shape)
@@ -823,29 +834,40 @@ def _permutation_lineage(shape, rng) -> LineageRelation:
     return LineageRelation(shape, shape, cells, cells[perm]).canonical()
 
 
-def _build_accel_dag(shape, branches: int, hops: int, seed: int = 0):
-    """``src`` fans out to ``branches`` independent permutation chains of
+def _accel_dag_edges(shape, branches: int, hops: int, seed: int = 0):
+    """The accel DAG's ``(src, dst, relation)`` edges in ingest order:
+    ``src`` fans out to ``branches`` independent permutation chains of
     ``hops`` tables each, all fanning back into ``out``:
 
         src → b{b}h0 → … → b{b}h{H-1} → out      (for each branch b)
+    """
+    rng = np.random.default_rng(seed)
+    edges = []
+    for b in range(branches):
+        prev = "src"
+        for h in range(hops):
+            name = f"b{b}h{h}"
+            edges.append((prev, name, _permutation_lineage(shape, rng)))
+            prev = name
+        edges.append((prev, "out", _permutation_lineage(shape, rng)))
+    return edges
+
+
+def _build_accel_dag(shape, branches: int, hops: int, seed: int = 0):
+    """An in-memory store of :func:`_accel_dag_edges`.
 
     Every hop's table is a fresh random bijection, so each plan wave holds
     ``branches`` small dense joins — the workload the batched executor
     packs into one blocked evaluation and the per-hop loop dispatches one
     at a time.
     """
-    rng = np.random.default_rng(seed)
     log = DSLog(store_forward=True)
     log.define_array("src", shape)
     log.define_array("out", shape)
-    for b in range(branches):
-        prev = "src"
-        for h in range(hops):
-            name = f"b{b}h{h}"
-            log.define_array(name, shape)
-            log.add_lineage(prev, name, _permutation_lineage(shape, rng))
-            prev = name
-        log.add_lineage(prev, "out", _permutation_lineage(shape, rng))
+    for src, dst, rel in _accel_dag_edges(shape, branches, hops, seed):
+        if dst not in log.arrays:
+            log.define_array(dst, shape)
+        log.add_lineage(src, dst, rel)
     return log
 
 
@@ -970,11 +992,11 @@ def _run_layout_ablation(smoke: bool = False, verbose: bool = True) -> dict:
     """Masked cross-product launch vs the block-diagonal tile schedule.
 
     One large ragged frontier (≥16 segments), both launch layouts forced
-    through :func:`repro.kernels.ops.segmented_range_join_pairs` under the
-    interpreter, pair lists asserted bit-identical to each other and to a
-    per-segment ``range_join_pairs`` oracle.  The interpreter charges every
-    scheduled tile, so the time ratio tracks the tile ratio — the same
-    quantity that sets real-accelerator cost, reported alongside as
+    through :func:`repro.kernels.ops.segmented_range_join_pairs` on the
+    device JAX finds (compiled on a TPU, the interpreter elsewhere), pair
+    lists asserted bit-identical to each other and to a per-segment
+    ``range_join_pairs`` oracle.  Both charge every scheduled tile, so the
+    time ratio tracks the tile ratio, reported alongside as
     ``tiles_visited`` / ``tiles_skipped``.
     """
     from repro.kernels.ops import range_join_pairs, segmented_range_join_pairs
@@ -985,15 +1007,13 @@ def _run_layout_ablation(smoke: bool = False, verbose: bool = True) -> dict:
 
     def run(layout):
         pairs, info = segmented_range_join_pairs(
-            segs, block_q=block_q, block_r=block_r, interpret=True,
-            layout=layout,
+            segs, block_q=block_q, block_r=block_r, layout=layout,
         )
         ts = []
         for _ in range(repeats):
             t0 = time.perf_counter()
             pairs, info = segmented_range_join_pairs(
-                segs, block_q=block_q, block_r=block_r, interpret=True,
-                layout=layout,
+                segs, block_q=block_q, block_r=block_r, layout=layout,
             )
             ts.append(time.perf_counter() - t0)
         return sorted(ts)[len(ts) // 2], pairs, info
@@ -1001,7 +1021,7 @@ def _run_layout_ablation(smoke: bool = False, verbose: bool = True) -> dict:
     dense_s, dense_pairs, dense_info = run("dense")
     diag_s, diag_pairs, diag_info = run("blockdiag")
     for s, (q_lo, q_hi, r_lo, r_hi) in enumerate(segs):
-        want = range_join_pairs(q_lo, q_hi, r_lo, r_hi, interpret=True)
+        want = range_join_pairs(q_lo, q_hi, r_lo, r_hi)
         for label, got in (("dense", dense_pairs[s]), ("blockdiag", diag_pairs[s])):
             assert np.array_equal(got[0], want[0]) and np.array_equal(
                 got[1], want[1]

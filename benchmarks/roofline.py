@@ -97,7 +97,12 @@ def analyze(rec: dict) -> dict | None:
 
 def run_roofline(root="experiments/dryrun/pod16x16", verbose=True,
                  out_md="experiments/roofline.md"):
-    rows = [a for a in (analyze(r) for r in load_records(root)) if a]
+    recs = load_records(root)
+    if not recs:
+        raise FileNotFoundError(
+            f"no dry-run records under {root!r}; run repro.launch.dryrun first"
+        )
+    rows = [a for a in (analyze(r) for r in recs) if a]
     rows.sort(key=lambda r: (r["arch"], r["shape"]))
     if verbose:
         hdr = (f"  {'arch':18s} {'shape':12s} {'compute_s':>10s} {'memory_s':>10s}"

@@ -13,6 +13,10 @@ import json
 import os
 import sys
 
+import jax
+
+from repro.compile_cache import enable_compile_cache
+
 
 def _emit(name: str, us: float, derived) -> None:
     print(f"{name},{us:.1f},{derived}")
@@ -262,12 +266,7 @@ def bench_roofline(quick: bool) -> None:
     from .roofline import run_roofline
 
     print("# Roofline — per (arch x shape) from dry-run artifacts", flush=True)
-    try:
-        rows = run_roofline()
-    except Exception as e:
-        print(f"roofline unavailable (run launch.dryrun first): {e}")
-        return
-    for r in rows:
+    for r in run_roofline():
         _emit(
             f"roofline/{r['arch']}/{r['shape']}",
             max(r["compute_s"], r["memory_s"], r["collective_s"]) * 1e6,
@@ -309,6 +308,8 @@ BENCHES = {
     "roofline": bench_roofline,
     "kernels": bench_kernels,
 }
+# benches that need artifacts made elsewhere run only when named in --only
+_OPT_IN = {"roofline"}
 
 # set by main(); benches that support an extra-small CI mode consult it
 _SMOKE = False
@@ -323,7 +324,15 @@ def main() -> None:
     ap.add_argument("--only", default="")
     args = ap.parse_args()
     _SMOKE = args.smoke
-    names = args.only.split(",") if args.only else list(BENCHES)
+    names = (
+        args.only.split(",")
+        if args.only
+        else [n for n in BENCHES if n not in _OPT_IN]
+    )
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"# device platform={dev.platform} kind={dev.device_kind} "
+          f"count={len(jax.devices())}", flush=True)
     print("name,us_per_call,derived")
     for nm in names:
         BENCHES[nm](args.quick or args.smoke)

@@ -91,9 +91,11 @@ SEED_COUNTERS = (
     "sig_tables_written",
     "bytes_written",
     # batched plan-step execution: packed dense dispatches (device kernel
-    # launches, or their CPU-twin equivalents), how many joins rode each,
-    # and pack occupancy (rows used vs padded)
+    # launches, or their CPU-twin equivalents — twin_launches counts the
+    # latter alone, so a chip run can tell the two apart), how many joins
+    # rode each, and pack occupancy (rows used vs padded)
     "kernel_launches",
+    "twin_launches",
     "joins_packed",
     "batch_rows",
     "batch_rows_padded",

@@ -1062,6 +1062,7 @@ class BatchedJoinExecutor:
         # the twin is one fused dispatch per frontier: count it like a
         # launch so CPU runs meter batching the same way TPU runs do
         self._stats("kernel_launches", 1)
+        self._stats("twin_launches", 1)
         self._stats("joins_packed", len(rest))
         self._stats("batch_rows", rows)
         self._stats("batch_rows_padded", rows)

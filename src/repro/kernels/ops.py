@@ -2,8 +2,8 @@
 
 These handle packing/padding from the natural numpy layouts used by
 ``repro.core`` into the 128-lane int32 tiles the kernels expect, and select
-``interpret=True`` automatically when no TPU is attached (this container) so
-the kernel bodies are validated on CPU.
+``interpret=True`` automatically when no TPU is attached, so the kernel
+bodies are validated on CPU.
 
 The packers are int32: coordinates outside the int32 range cannot ride the
 kernel path (they would silently wrap — the bug this module now refuses).
@@ -37,6 +37,12 @@ _I32 = np.iinfo(np.int32)
 
 
 def default_interpret() -> bool:
+    """Interpret the kernels unless JAX's default device is a TPU.
+
+    This is the CPU test path, and it is silent by design; a chip run
+    proves the compiled path ran from ``io_stats`` (``twin_launches == 0``)
+    and ``batched(tpu:…)`` plan notes, as ``chip_smoke.py`` does.
+    """
     return jax.devices()[0].platform != "tpu"
 
 

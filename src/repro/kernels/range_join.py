@@ -6,13 +6,16 @@ compressed-table key boxes ``R``, emit the boolean matrix
 
 TPU adaptation: this is an all-pairs predicate with the same data-movement
 shape as an attention-score block — we tile ``Q`` rows × ``R`` rows into
-VMEM blocks and evaluate the conjunction over attributes entirely in
-registers, so each (q, r) tile pair is materialized once in VMEM and never
-round-trips through HBM.  The attribute axis (≤ a few) is carried in the
-lane dimension of each operand tile.
+VMEM blocks and evaluate the conjunction over attributes in registers,
+8 ``Q`` rows at a time, so each (q, r) tile pair is materialized once in
+VMEM and never round-trips through HBM.  ``Q`` carries its attributes in
+the lane dimension; ``R`` reaches the kernel transposed, attributes in the
+sublane dimension, so attribute ``j`` is a ``Q`` column against an ``R``
+row and both broadcast to the tile without a relayout.
 
 Inputs are packed ``[N, 2*l]`` int32 (lo columns then hi columns), padded to
-128 lanes; the mask output block is ``(block_q, block_r)`` int32.  Row
+128 lanes; the wrappers transpose ``R``'s used lanes on the device.  The
+mask output block is ``(block_q, block_r)`` int32.  Row
 counts need **not** be multiples of the block sizes: the kernel pads both
 operands internally with *empty* boxes (``lo = 1, hi = 0`` — they overlap
 nothing) and slices the padding back off the mask, so callers hand in
@@ -45,6 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
+_SUBLANES = 8
 
 
 def check_lane_capacity(n_attrs: int, segmented: bool = False) -> None:
@@ -66,17 +70,60 @@ def check_lane_capacity(n_attrs: int, segmented: bool = False) -> None:
         )
 
 
-def _kernel(q_ref, r_ref, out_ref, *, n_attrs: int):
-    q = q_ref[...]  # [TQ, LANES]
-    r = r_ref[...]  # [TR, LANES]
-    ok = jnp.ones((q.shape[0], r.shape[0]), dtype=jnp.bool_)
+ROW_CHUNK = _SUBLANES  # Q rows per inner step: one sublane tile
+
+
+def _check_block_q(block_q: int) -> None:
+    if block_q % ROW_CHUNK:
+        raise ValueError(
+            f"block_q={block_q} must be a multiple of the {ROW_CHUNK}-row chunk"
+        )
+
+
+def _overlap_rows(q_ref, rt_ref, r0, n_attrs: int) -> jax.Array:
+    """The conjunction over attributes for ``ROW_CHUNK`` Q rows × the R block.
+
+    ``q_ref`` is a ``[TQ, LANES]`` row block and ``rt_ref`` a transposed
+    ``[R_SUBLANES, TR]`` block, so attribute ``j`` is a width-1 column of Q
+    and a height-1 row of R: both broadcast to ``[ROW_CHUNK, TR]`` without
+    a lane ↔ sublane relayout.  (A 1-D lane column reshaped to ``[:, None]``
+    needs one, and Mosaic spills it to VMEM — tens of MB at 256×256.)  The
+    chunk keeps every temporary a few vregs wide whatever the tile or the
+    attribute count.
+    """
+    q = q_ref[pl.ds(r0, ROW_CHUNK), :]  # [ROW_CHUNK, LANES]
+    ok = None
     for j in range(n_attrs):  # static unroll over attributes
-        q_lo = q[:, j][:, None]
-        q_hi = q[:, n_attrs + j][:, None]
-        r_lo = r[:, j][None, :]
-        r_hi = r[:, n_attrs + j][None, :]
-        ok &= (q_lo <= r_hi) & (r_lo <= q_hi)
-    out_ref[...] = ok.astype(jnp.int32)  # dslint: ignore[int32-cast] bool mask
+        q_lo = q[:, j : j + 1]  # [ROW_CHUNK, 1]
+        q_hi = q[:, n_attrs + j : n_attrs + j + 1]
+        r_lo = rt_ref[j : j + 1, :]  # [1, TR]
+        r_hi = rt_ref[n_attrs + j : n_attrs + j + 1, :]
+        hit = (q_lo <= r_hi) & (r_lo <= q_hi)
+        ok = hit if ok is None else ok & hit
+    return ok.astype(jnp.int32)  # dslint: ignore[int32-cast] bool mask
+
+
+def _fill_mask(q_ref, rt_ref, out_ref, *, n_attrs: int) -> None:
+    """Write the ``[TQ, TR]`` tile mask into ``out_ref``, chunk by chunk."""
+
+    def body(c, carry):
+        r0 = pl.multiple_of(c * ROW_CHUNK, ROW_CHUNK)
+        rows = _overlap_rows(q_ref, rt_ref, r0, n_attrs)
+        out_ref[pl.ds(r0, ROW_CHUNK), :] = rows
+        return carry
+
+    jax.lax.fori_loop(0, q_ref.shape[0] // ROW_CHUNK, body, 0)
+
+
+def _transpose_r(r_packed: jax.Array, n_attrs: int) -> jax.Array:
+    """``[NR, LANES]`` → ``[R_SUBLANES, NR]``: R's lo/hi lanes as rows.
+
+    Only the ``2 * n_attrs`` used lanes travel, rounded up to the 8-row
+    sublane tile; the block then spans the whole first axis, which is
+    always a legal block shape.
+    """
+    rows = -(-2 * n_attrs // _SUBLANES) * _SUBLANES
+    return r_packed[:, :rows].T
 
 
 def _pad_empty(packed: jax.Array, n: int, mult: int, n_attrs: int) -> jax.Array:
@@ -108,44 +155,36 @@ def range_join_mask(
     with empty boxes and the returned mask is sliced back to ``[NQ, NR]``.
     """
     check_lane_capacity(n_attrs)
+    _check_block_q(block_q)
     nq, lanes = q_packed.shape
     nr, lanes_r = r_packed.shape
     if lanes != LANES or lanes_r != LANES:
         raise ValueError(f"operands must be packed to {LANES} lanes")
     qp = _pad_empty(q_packed, nq, block_q, n_attrs)
-    rp = _pad_empty(r_packed, nr, block_r, n_attrs)
-    grid = (qp.shape[0] // block_q, rp.shape[0] // block_r)
+    rt = _transpose_r(_pad_empty(r_packed, nr, block_r, n_attrs), n_attrs)
+    grid = (qp.shape[0] // block_q, rt.shape[1] // block_r)
     mask = pl.pallas_call(
-        functools.partial(_kernel, n_attrs=n_attrs),
+        functools.partial(_fill_mask, n_attrs=n_attrs),
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_q, LANES), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_r, LANES), lambda i, j: (j, 0)),
+            pl.BlockSpec((rt.shape[0], block_r), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_q, block_r), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((qp.shape[0], rp.shape[0]), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((qp.shape[0], rt.shape[1]), jnp.int32),
         interpret=interpret,
-    )(qp, rp)
+    )(qp, rt)
     return mask[:nq, :nr]
 
 
-def _tile_kernel(tq_ref, tr_ref, q_ref, r_ref, out_ref, *, n_attrs: int):
+def _tile_kernel(tq_ref, tr_ref, q_ref, rt_ref, out_ref, *, n_attrs: int):
     """One scheduled tile: the overlap conjunction for its (q, r) blocks.
 
     ``tq_ref``/``tr_ref`` are the prefetched tile schedules — consumed by
     the BlockSpec index maps, not the body, which sees exactly the operand
     blocks the schedule selected.
     """
-    q = q_ref[...]  # [block_q, LANES]
-    r = r_ref[...]  # [block_r, LANES]
-    ok = jnp.ones((q.shape[0], r.shape[0]), dtype=jnp.bool_)
-    for j in range(n_attrs):  # static unroll over attributes
-        q_lo = q[:, j][:, None]
-        q_hi = q[:, n_attrs + j][:, None]
-        r_lo = r[:, j][None, :]
-        r_hi = r[:, n_attrs + j][None, :]
-        ok &= (q_lo <= r_hi) & (r_lo <= q_hi)
-    out_ref[0] = ok.astype(jnp.int32)  # dslint: ignore[int32-cast] bool mask
+    _fill_mask(q_ref, rt_ref, out_ref.at[0], n_attrs=n_attrs)
 
 
 @functools.partial(
@@ -176,6 +215,7 @@ def range_join_tile_masks(
     not T.
     """
     check_lane_capacity(n_attrs)
+    _check_block_q(block_q)
     nq, lanes = q_packed.shape
     nr, lanes_r = r_packed.shape
     if lanes != LANES or lanes_r != LANES:
@@ -186,12 +226,13 @@ def range_join_tile_masks(
             f"(got {nq} q rows / {nr} r rows for {block_q}x{block_r} tiles)"
         )
     n_tiles = tile_q.shape[0]
+    rt = _transpose_r(r_packed, n_attrs)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((block_q, LANES), lambda t, tq, tr: (tq[t], 0)),
-            pl.BlockSpec((block_r, LANES), lambda t, tq, tr: (tr[t], 0)),
+            pl.BlockSpec((rt.shape[0], block_r), lambda t, tq, tr: (0, tr[t])),
         ],
         out_specs=pl.BlockSpec((1, block_q, block_r), lambda t, tq, tr: (t, 0, 0)),
     )
@@ -200,4 +241,4 @@ def range_join_tile_masks(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_tiles, block_q, block_r), jnp.int32),
         interpret=interpret,
-    )(tile_q, tile_r, q_packed, r_packed)
+    )(tile_q, tile_r, q_packed, rt)

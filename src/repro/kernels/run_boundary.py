@@ -9,12 +9,13 @@ TPU adaptation (vs. the paper's scalar Python scan): the scan has no loop
 dependence once the previous row is available, so we tile rows into VMEM
 blocks of ``(block_rows, 128)`` int32 and compare each block against itself
 shifted by one row.  The single cross-tile dependency (the last row of the
-previous tile) is precomputed as a tiny ``[num_tiles, 128]`` side input —
-an O(N / block_rows) strided gather done once by XLA, so the kernel reads
-every element of the sorted table exactly once from HBM.  The column axis is
-padded to the 128-lane width; group-key columns and the two merge-interval
-columns travel in the same tile so the whole boundary predicate fuses into
-one VMEM pass (numpy needs C+2 separate comparison sweeps).
+previous tile) is precomputed as a tiny side input — one 8-row block per
+tile with the tail in its first row, an O(N / block_rows) strided gather
+done once by XLA — so the kernel reads every element of the sorted table
+exactly once from HBM.  The column axis is padded to the 128-lane width;
+group-key columns and the two merge-interval columns travel in the same
+tile so the whole boundary predicate fuses into one VMEM pass (numpy needs
+C+2 separate comparison sweeps).
 
 Layout:  ``packed[:, :n_keys]`` = group-key columns,
          ``packed[:, n_keys]`` = merge ``lo``, ``packed[:, n_keys+1]`` =
@@ -30,12 +31,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 LANES = 128
+_SUBLANES = 8
 
 
 def _kernel(packed_ref, prev_ref, out_ref, *, n_keys: int):
     """One row-tile: boundary flags for rows [i*T, (i+1)*T)."""
     block = packed_ref[...]  # [T, LANES] int32
-    prev_tail = prev_ref[...]  # [1, LANES]  last row of previous tile
+    prev_tail = prev_ref[0:1, :]  # [1, LANES]  last row of previous tile
     # previous-row view: shift block down by one, filling row 0 from the tail
     prev_rows = jnp.concatenate([prev_tail, block[:-1, :]], axis=0)
 
@@ -82,13 +84,17 @@ def run_boundaries_packed(
     tails = packed[block_rows - 1 :: block_rows][:-1]
     sentinel = jnp.full((1, LANES), jnp.iinfo(jnp.int32).min, jnp.int32)
     prev = jnp.concatenate([sentinel, tails], axis=0)  # [num_tiles, LANES]
+    # each tail heads its own 8-row block: a block's second-minor dim must
+    # be a multiple of the 8-row sublane tile (or span the whole array)
+    prev = jnp.pad(prev[:, None, :], ((0, 0), (0, _SUBLANES - 1), (0, 0)))
+    prev = prev.reshape(num_tiles * _SUBLANES, LANES)
 
     flags = pl.pallas_call(
         functools.partial(_kernel, n_keys=n_keys),
         grid=(num_tiles,),
         in_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, LANES), lambda i: (i, 0)),
+            pl.BlockSpec((_SUBLANES, LANES), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n + pad, 1), jnp.int32),

@@ -2,46 +2,27 @@
 
 Everything is a function — importing this module never touches jax device
 state (the dry-run must set XLA_FLAGS before first jax init).
-
-Compat: ``jax.sharding.AxisType`` (and the ``axis_types`` kwarg of
-``jax.make_mesh``) only exist on newer JAX releases.  ``_compat_make_mesh``
-passes explicit-Auto axis types when the running JAX supports them and
-silently constructs a plain mesh otherwise, so the same call sites work on
-both (this container ships 0.4.37, which has neither).
 """
 
 from __future__ import annotations
 
 import jax
-
-try:  # JAX >= 0.4.38
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed JAX
-    AxisType = None
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh", "local_mesh"]
 
 
-def _compat_make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(AxisType.Auto,) * len(axes)
-            )
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat_make_mesh(shape, axes)
-
-
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    return _compat_make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def local_mesh(model_parallel: int = 1):

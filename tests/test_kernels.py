@@ -292,3 +292,101 @@ def test_run_boundary_pads_non_multiple_rows(n):
     assert got.shape == (n,)
     want = run_boundaries_ref(jnp.asarray(packed), 1)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _boxes(r, n, l, coords=(0, 40), span=5):
+    lo = r.integers(*coords, (n, l))
+    return lo, lo + r.integers(0, span, (n, l))
+
+
+def _twin(q_lo, q_hi, r_lo, r_hi):
+    """Pairs from the executor's numpy twin (R as ``[l, N]`` columns)."""
+    from repro.core.query import _twin_pairs
+
+    return _twin_pairs(
+        q_lo, q_hi, np.ascontiguousarray(r_lo.T), np.ascontiguousarray(r_hi.T)
+    )
+
+
+@pytest.mark.parametrize("l", [1, 2, 4, 8])
+@pytest.mark.parametrize("n", [255, 256, 257])
+def test_row_chunked_mask_matches_ref_and_twin(n, l):
+    """The row-chunked body over a transposed R block, at and around one
+    256-row tile: the mask equals the jnp reference and the pairs equal the
+    numpy twin's, for every attribute count the chip compile covers."""
+    from repro.kernels.ops import _pack_boxes
+
+    r = np.random.default_rng(10 * n + l)
+    q_lo, q_hi = _boxes(r, n, l)
+    r_lo, r_hi = _boxes(r, 513 - n, l)
+    q, rp = _pack_boxes(q_lo, q_hi, l), _pack_boxes(r_lo, r_hi, l)
+    mask = range_join_mask(
+        jnp.asarray(q), jnp.asarray(rp), n_attrs=l, block_q=256, block_r=256,
+        interpret=True,
+    )
+    want = range_join_mask_ref(jnp.asarray(q), jnp.asarray(rp), l)
+    np.testing.assert_array_equal(np.asarray(mask), np.asarray(want))
+    qi, ri = range_join_pairs(q_lo, q_hi, r_lo, r_hi, interpret=True)
+    wq, wr = _twin(q_lo, q_hi, r_lo, r_hi)
+    np.testing.assert_array_equal(qi, wq)
+    np.testing.assert_array_equal(ri, wr)
+
+
+@pytest.mark.parametrize("l", [1, 2, 4, 8])
+def test_tile_masks_match_ref_per_tile(l):
+    """Every scheduled tile of range_join_tile_masks equals the reference
+    mask of its (q block, r block) — in schedule order, off-diagonal and
+    repeated tiles included."""
+    from repro.kernels.ops import _pack_boxes, _pad_packed_rows
+    from repro.kernels.range_join import range_join_tile_masks
+
+    r = np.random.default_rng(l)
+    bq, br = 128, 256
+    q = _pad_packed_rows(_pack_boxes(*_boxes(r, 257, l), l), bq, l)
+    rp = _pad_packed_rows(_pack_boxes(*_boxes(r, 300, l), l), br, l)
+    tq = np.array([0, 2, 1, 0, 2], np.int32)
+    tr = np.array([1, 0, 1, 0, 1], np.int32)
+    masks = np.asarray(range_join_tile_masks(
+        jnp.asarray(q), jnp.asarray(rp), jnp.asarray(tq), jnp.asarray(tr),
+        n_attrs=l, block_q=bq, block_r=br, interpret=True,
+    ))
+    want = np.asarray(range_join_mask_ref(jnp.asarray(q), jnp.asarray(rp), l))
+    for t, (i, j) in enumerate(zip(tq, tr)):
+        np.testing.assert_array_equal(
+            masks[t], want[i * bq : (i + 1) * bq, j * br : (j + 1) * br]
+        )
+
+
+@pytest.mark.parametrize("layout", ["dense", "blockdiag"])
+@pytest.mark.parametrize("n", [255, 256, 257])
+def test_segmented_pad_graze_matches_twin(n, layout):
+    """Boxes spanning the padding sentinel (lo=1, hi=0) at 256-row segment
+    edges: both launch layouts at the default geometry return the twin's
+    pair lists, so no padded row ever leaks into a result."""
+    from repro.kernels.ops import segmented_range_join_pairs
+
+    r = np.random.default_rng(n)
+    segs = []
+    for k, l in enumerate((2, 1, 3)):
+        nq, nr = n + k - 1, 2 * n - k
+        q_lo, q_hi = _boxes(r, nq, l, coords=(-4, 2), span=6)
+        r_lo, r_hi = _boxes(r, nr, l, coords=(-4, 2), span=6)
+        segs.append((q_lo, q_hi, r_lo, r_hi))
+    got, info = segmented_range_join_pairs(
+        segs, block_q=256, block_r=256, interpret=True, layout=layout
+    )
+    assert info["layout"] == layout
+    for (q_lo, q_hi, r_lo, r_hi), (qi, ri) in zip(segs, got):
+        wq, wr = _twin(q_lo, q_hi, r_lo, r_hi)
+        np.testing.assert_array_equal(qi, wq)
+        np.testing.assert_array_equal(ri, wr)
+
+
+def test_range_join_rejects_block_q_off_row_chunk():
+    """block_q must be a whole number of row chunks (sublane tiles)."""
+    q = np.zeros((16, 128), np.int32)
+    with pytest.raises(ValueError, match="block_q"):
+        range_join_mask(
+            jnp.asarray(q), jnp.asarray(q), n_attrs=1, block_q=12,
+            block_r=128, interpret=True,
+        )
