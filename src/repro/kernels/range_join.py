@@ -48,7 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
-_SUBLANES = 8
+SUBLANES = 8  # rows of one int32 vreg tile
 
 
 def check_lane_capacity(n_attrs: int, segmented: bool = False) -> None:
@@ -70,31 +70,28 @@ def check_lane_capacity(n_attrs: int, segmented: bool = False) -> None:
         )
 
 
-ROW_CHUNK = _SUBLANES  # Q rows per inner step: one sublane tile
-
-
 def _check_block_q(block_q: int) -> None:
-    if block_q % ROW_CHUNK:
+    if block_q % SUBLANES:
         raise ValueError(
-            f"block_q={block_q} must be a multiple of the {ROW_CHUNK}-row chunk"
+            f"block_q={block_q} must be a multiple of the {SUBLANES}-row chunk"
         )
 
 
 def _overlap_rows(q_ref, rt_ref, r0, n_attrs: int) -> jax.Array:
-    """The conjunction over attributes for ``ROW_CHUNK`` Q rows × the R block.
+    """The conjunction over attributes for ``SUBLANES`` Q rows × the R block.
 
     ``q_ref`` is a ``[TQ, LANES]`` row block and ``rt_ref`` a transposed
     ``[R_SUBLANES, TR]`` block, so attribute ``j`` is a width-1 column of Q
-    and a height-1 row of R: both broadcast to ``[ROW_CHUNK, TR]`` without
+    and a height-1 row of R: both broadcast to ``[SUBLANES, TR]`` without
     a lane ↔ sublane relayout.  (A 1-D lane column reshaped to ``[:, None]``
     needs one, and Mosaic spills it to VMEM — tens of MB at 256×256.)  The
     chunk keeps every temporary a few vregs wide whatever the tile or the
     attribute count.
     """
-    q = q_ref[pl.ds(r0, ROW_CHUNK), :]  # [ROW_CHUNK, LANES]
+    q = q_ref[pl.ds(r0, SUBLANES), :]  # [SUBLANES, LANES]
     ok = None
     for j in range(n_attrs):  # static unroll over attributes
-        q_lo = q[:, j : j + 1]  # [ROW_CHUNK, 1]
+        q_lo = q[:, j : j + 1]  # [SUBLANES, 1]
         q_hi = q[:, n_attrs + j : n_attrs + j + 1]
         r_lo = rt_ref[j : j + 1, :]  # [1, TR]
         r_hi = rt_ref[n_attrs + j : n_attrs + j + 1, :]
@@ -107,12 +104,12 @@ def _fill_mask(q_ref, rt_ref, out_ref, *, n_attrs: int) -> None:
     """Write the ``[TQ, TR]`` tile mask into ``out_ref``, chunk by chunk."""
 
     def body(c, carry):
-        r0 = pl.multiple_of(c * ROW_CHUNK, ROW_CHUNK)
+        r0 = pl.multiple_of(c * SUBLANES, SUBLANES)
         rows = _overlap_rows(q_ref, rt_ref, r0, n_attrs)
-        out_ref[pl.ds(r0, ROW_CHUNK), :] = rows
+        out_ref[pl.ds(r0, SUBLANES), :] = rows
         return carry
 
-    jax.lax.fori_loop(0, q_ref.shape[0] // ROW_CHUNK, body, 0)
+    jax.lax.fori_loop(0, q_ref.shape[0] // SUBLANES, body, 0)
 
 
 def _transpose_r(r_packed: jax.Array, n_attrs: int) -> jax.Array:
@@ -122,7 +119,7 @@ def _transpose_r(r_packed: jax.Array, n_attrs: int) -> jax.Array:
     sublane tile; the block then spans the whole first axis, which is
     always a legal block shape.
     """
-    rows = -(-2 * n_attrs // _SUBLANES) * _SUBLANES
+    rows = -(-2 * n_attrs // SUBLANES) * SUBLANES
     return r_packed[:, :rows].T
 
 

@@ -30,8 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANES = 128
-_SUBLANES = 8
+from .range_join import LANES, SUBLANES
 
 
 def _kernel(packed_ref, prev_ref, out_ref, *, n_keys: int):
@@ -86,15 +85,15 @@ def run_boundaries_packed(
     prev = jnp.concatenate([sentinel, tails], axis=0)  # [num_tiles, LANES]
     # each tail heads its own 8-row block: a block's second-minor dim must
     # be a multiple of the 8-row sublane tile (or span the whole array)
-    prev = jnp.pad(prev[:, None, :], ((0, 0), (0, _SUBLANES - 1), (0, 0)))
-    prev = prev.reshape(num_tiles * _SUBLANES, LANES)
+    prev = jnp.pad(prev[:, None, :], ((0, 0), (0, SUBLANES - 1), (0, 0)))
+    prev = prev.reshape(num_tiles * SUBLANES, LANES)
 
     flags = pl.pallas_call(
         functools.partial(_kernel, n_keys=n_keys),
         grid=(num_tiles,),
         in_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((_SUBLANES, LANES), lambda i: (i, 0)),
+            pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n + pad, 1), jnp.int32),
