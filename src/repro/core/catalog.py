@@ -37,12 +37,13 @@ N independently persisted shards.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Sequence
+from typing import Any, Callable, Iterator, Sequence
 
 import numpy as np
 
@@ -103,6 +104,12 @@ SEED_COUNTERS = (
     # cross-product tiles the block-diagonal layout skipped
     "batch_tiles_visited",
     "batch_tiles_skipped",
+    # bytes those kernel dispatches moved (packed operands and tile
+    # schedules up, masks or tile stacks back) and the pairs the dense
+    # joins emitted, kernel and twin alike
+    "h2d_bytes",
+    "d2h_bytes",
+    "join_pairs",
     # materialized views + answer cache (repro/core/views.py)
     "view_hits",
     "view_misses",
@@ -575,10 +582,11 @@ class DSLog:
 
     def commit(self) -> None:
         """Durability barrier: every logged mutation is on disk on return."""
-        if self._pipeline is not None:
-            self._pipeline.commit()
-        elif self._wal is not None:
-            self._wal.flush(sync=True)
+        with maybe_span(self._active_trace, "commit", kind="commit"):
+            if self._pipeline is not None:
+                self._pipeline.commit()
+            elif self._wal is not None:
+                self._wal.flush(sync=True)
 
     def checkpoint(self) -> None:
         """Fold the WAL into the manifest: incremental save + truncation."""
@@ -627,7 +635,9 @@ class DSLog:
         assert self.root is not None
         if self._wal is None:
             self._wal = WriteAheadLog(
-                os.path.join(self.root, WAL_FILENAME), metrics=self.metrics
+                os.path.join(self.root, WAL_FILENAME),
+                metrics=self.metrics,
+                trace_source=lambda: self._active_trace,
             )
         if pipeline is not None:
             self._pipeline = pipeline
@@ -648,11 +658,12 @@ class DSLog:
         # they flush synchronously (below) and never truncate, so a torn
         # tail is the worst a crash leaves.  Truncation stays lease-gated
         # in the save()/checkpoint paths.
-        wal.append(rtype, meta, blobs)  # dsflow: ignore[wal-lease]
-        if self._pipeline is not None:
-            self._pipeline.notify(wal)
-        else:  # no pipeline attached (plain load): stay conservative
-            wal.flush(sync=True)
+        with maybe_span(self._active_trace, "wal.append", kind="wal.append"):
+            wal.append(rtype, meta, blobs)  # dsflow: ignore[wal-lease]
+            if self._pipeline is not None:
+                self._pipeline.notify(wal)
+            else:  # no pipeline attached (plain load): stay conservative
+                wal.flush(sync=True)
 
     def _wal_append_root(self, rtype: str, meta: dict, blobs=()) -> None:
         """Log a store-level record (arrays, ops, versions, predictor).
@@ -834,9 +845,10 @@ class DSLog:
         if tables is not None:
             bwd, fwd = tables
         else:
-            bwd = compress(relation, "backward", self.compress_method)
+            tr = self._active_trace
+            bwd = compress(relation, "backward", self.compress_method, trace=tr)
             fwd = (
-                compress(relation, "forward", self.compress_method)
+                compress(relation, "forward", self.compress_method, trace=tr)
                 if self.store_forward
                 else None
             )
@@ -864,7 +876,11 @@ class DSLog:
         self._meta_dirty = True
         self.views.on_new_edge(src, dst)
         if self._wal is not None and not self._replaying:
-            meta, blobs = self._entry_wal_record(entry)
+            tr = self._active_trace
+            with maybe_span(tr, "wal.record", kind="wal.record") as sp:
+                meta, blobs = self._entry_wal_record(entry)
+                if tr is not None:
+                    sp.attrs["bytes"] = sum(len(b) for b in blobs)
             self._wal_append_entry("entry", meta, blobs)
         return entry
 
@@ -1115,9 +1131,8 @@ class DSLog:
         loop — results are bit-identical either way.
 
         ``trace=True`` returns ``(result, QueryTrace)`` instead: a span
-        tree (plan / hop / kernel launch / exchange / cache probe / view
-        race) with per-span wall time and instrument deltas.  Tracing
-        never changes the answer.
+        tree of the query's stages (see :meth:`trace_scope`) with per-span
+        wall time and counts.  Tracing never changes the answer.
         """
         form = self._parse_query_args(args)
         if form[0] == "path":
@@ -1169,7 +1184,6 @@ class DSLog:
         (shared index probes, deduplicated boxes across in-flight queries).
         ``trace=True`` returns ``(result, QueryTrace)``.
         """
-        tr = QueryTrace(registry=self.metrics) if trace else None
         workers = parallel if parallel is not None else 0
         use_batched = (
             getattr(self.planner, "batched", True) if batched is None else batched
@@ -1179,18 +1193,11 @@ class DSLog:
             if workers and workers > 1
             else ("batched" if use_batched else "serial")
         )
-        prev = self._active_trace
-        if tr is not None:
-            self._active_trace = tr
-        t0 = time.perf_counter()
-        try:
+        with self.trace_scope() if trace else contextlib.nullcontext() as tr:
+            t0 = time.perf_counter()
             out, path_label = self._query_batch_impl(
                 args, merge, parallel, batched, tr, engine
             )
-        finally:
-            if tr is not None:
-                self._active_trace = prev
-                tr.finish()
         # per-path query latency: cache hit / view shortcut / full plan,
         # split by execution engine
         self.metrics.observe(
@@ -1198,6 +1205,25 @@ class DSLog:
         )
         self.metrics.inc("queries", path=path_label)
         return (out, tr) if trace else out
+
+    @contextlib.contextmanager
+    def trace_scope(self, label: str = "query") -> Iterator[QueryTrace]:
+        """Trace the block: a fresh :class:`QueryTrace` rooted at ``label``
+        is the store's active trace inside it, and is yielded.
+
+        Every instrumented stage the block reaches (query stages, ingest's
+        ``canonical`` / ``provrc.step1`` / ``provrc.step2``, ``wal.record``,
+        ``wal.append``, ``commit``, ``fsync``) records a span, and under a
+        JAX profiler session a ``dslog.<name>`` annotation.  The previous
+        trace is restored on exit.
+        """
+        prev = self._active_trace
+        with QueryTrace(label) as tr:
+            self._active_trace = tr
+            try:
+                yield tr
+            finally:
+                self._active_trace = prev
 
     def _query_batch_impl(
         self, args, merge, parallel, batched, tr, engine
@@ -1277,10 +1303,17 @@ class DSLog:
         self, name: str, queries: Sequence["np.ndarray | QueryBox"]
     ) -> list[QueryBox]:
         shape = self.arrays[name].shape
-        return [
-            q if isinstance(q, QueryBox) else QueryBox.from_cells(shape, q)
-            for q in queries
-        ]
+        tr = self._active_trace
+        with maybe_span(tr, "boxes_in", kind="boxes_in") as sp:
+            boxes = [
+                q if isinstance(q, QueryBox) else QueryBox.from_cells(shape, q)
+                for q in queries
+            ]
+            if tr is not None:
+                sp.attrs.update(
+                    queries=len(boxes), rows=sum(b.n_rows for b in boxes)
+                )
+        return boxes
 
     @staticmethod
     def _parse_query_args(args: tuple) -> tuple:

@@ -43,6 +43,8 @@ from typing import TYPE_CHECKING, Iterable, Sequence
 
 import numpy as np
 
+from repro.obs.trace import maybe_span
+
 from .query import (
     DENSE_FRACTION,
     INDEX_MIN_ROWS,
@@ -224,7 +226,7 @@ class QueryPlanner:
                 stats=self.log._bump,
                 tuner=getattr(self.log, "autotune", None),
                 metrics=getattr(self.log, "metrics", None),
-                trace_source=lambda: getattr(self.log, "_active_trace", None),
+                trace_source=self._trace,
             )
         return self._executor
 
@@ -341,7 +343,7 @@ class QueryPlanner:
             vplan = self._view_plan(
                 next(iter(src_set)), next(iter(dst_set)), frontier, nq0, batched
             )
-            tr = getattr(self.log, "_active_trace", None)
+            tr = self._trace()
             if vplan is not None and vplan.est_cost < plan.est_cost:
                 self.log._bump("view_hits")
                 if tr is not None:
@@ -720,7 +722,10 @@ class QueryPlanner:
                 for q in qs
             ]
             if merge:
-                boxes = [merge_boxes(q) for q in boxes]
+                with maybe_span(
+                    self._trace(), "merge", kind="merge", at="init"
+                ):
+                    boxes = [merge_boxes(q) for q in boxes]
             init[key] = boxes
             lengths.add(len(boxes))
         if len(lengths) > 1:
@@ -750,10 +755,13 @@ class QueryPlanner:
             # target answers are re-cut into the canonical decomposition —
             # equal cell sets become equal bytes, whatever plan produced
             # them.
-            out = {
-                name: [canonical_boxes(q) for q in boxes]
-                for name, boxes in out.items()
-            }
+            with maybe_span(
+                self._trace(), "merge", kind="merge", at="canonical"
+            ):
+                out = {
+                    name: [canonical_boxes(q) for q in boxes]
+                    for name, boxes in out.items()
+                }
         return out
 
     # ------------------------------------------------------------------ #
@@ -830,19 +838,23 @@ class QueryPlanner:
                 acc_lo[k].append(res.lo)
                 acc_hi[k].append(res.hi)
         boxes = []
-        for k in range(nB):
-            lo = (
-                np.concatenate(acc_lo[k])
-                if acc_lo[k]
-                else np.zeros((0, nd), np.int64)
-            )
-            hi = (
-                np.concatenate(acc_hi[k])
-                if acc_hi[k]
-                else np.zeros((0, nd), np.int64)
-            )
-            res = QueryBox(shape, lo, hi)
-            boxes.append(merge_boxes(res) if merge else res)
+        tr = self._trace()
+        with maybe_span(tr, "merge", kind="merge", at="hop") as sp:
+            for k in range(nB):
+                lo = (
+                    np.concatenate(acc_lo[k])
+                    if acc_lo[k]
+                    else np.zeros((0, nd), np.int64)
+                )
+                hi = (
+                    np.concatenate(acc_hi[k])
+                    if acc_hi[k]
+                    else np.zeros((0, nd), np.int64)
+                )
+                res = QueryBox(shape, lo, hi)
+                boxes.append(merge_boxes(res) if merge else res)
+            if tr is not None:
+                sp.attrs["rows"] = sum(b.n_rows for b in boxes)
         return boxes
 
     def _compute_node(
@@ -865,16 +877,24 @@ class QueryPlanner:
         gathered = self._gather_requests(plan, key, frontier)
         timings: list[float] | None = None
         if use_batched and gathered:
-            res_lists = self.executor.run(self._requests_for(gathered))
+            res_lists = self._run_wave(self._requests_for(gathered))
         else:
             # the per-hop loop is the one engine that can time individual
             # joins — EXPLAIN ANALYZE shows true per-hop wall time here
             res_lists = []
             timings = []
-            for _s, choice, qs in gathered:
-                t0 = time.perf_counter()
-                res_lists.append(self._join_choice(choice, qs))
-                timings.append(time.perf_counter() - t0)
+            tr = self._trace()
+            for step, choice, qs in gathered:
+                with maybe_span(tr, "hop", kind="hop") as sp:
+                    t0 = time.perf_counter()
+                    res_lists.append(self._join_choice(choice, qs))
+                    timings.append(time.perf_counter() - t0)
+                    if tr is not None:
+                        sp.attrs.update(
+                            qrows=sum(q.n_rows for q in qs),
+                            pairs=sum(r.n_rows for r in res_lists[-1]),
+                            **self._hop_attrs(plan, step, choice),
+                        )
         return self._assemble_node(
             plan, key, init, gathered, res_lists, nB, merge, timings=timings
         )
@@ -920,7 +940,7 @@ class QueryPlanner:
                 reqs.extend(self._requests_for(gathered[k]))
             if reqs:
                 t0 = time.perf_counter()
-                res = self.executor.run(reqs, workers=workers)
+                res = self._run_wave(reqs, workers)
                 dt_ms = (time.perf_counter() - t0) * 1e3
                 with self.log._stats_lock:
                     acc = plan.measured.setdefault("__exec_ms__", [0.0, 0])
@@ -938,6 +958,22 @@ class QueryPlanner:
                 done.add(k)
             pending = [k for k in pending if k not in done]
         return frontier
+
+    def _trace(self):
+        return getattr(self.log, "_active_trace", None)
+
+    def _run_wave(
+        self, reqs: list[JoinRequest], workers: int | None = None
+    ) -> list[list[QueryBox]]:
+        """One :meth:`BatchedJoinExecutor.run` call, traced as a ``wave``."""
+        tr = self._trace()
+        with maybe_span(tr, "wave", kind="wave") as sp:
+            if tr is not None:
+                sp.attrs.update(
+                    requests=len(reqs),
+                    rows=sum(q.n_rows for r in reqs for q in r.queries),
+                )
+            return self.executor.run(reqs, workers=workers)
 
     def _execute_parallel(
         self,
@@ -1087,20 +1123,29 @@ class QueryPlanner:
                 if elapsed is not None:
                     rec["ms"] += elapsed * 1e3
                     rec["timed"] += 1
-        tr = getattr(self.log, "_active_trace", None)
-        if tr is not None and step is not None:
+        tr = self._trace()
+        if tr is not None and step is not None and elapsed is None:
+            # batched engines join a whole wave at once: the hop is an
+            # instant record (the per-hop loop scopes it in _compute_node)
             tr.event(
                 "hop",
                 kind="hop",
-                u=plan.node_array[step.u] if plan is not None else step.u,
-                v=plan.node_array[step.v] if plan is not None else step.v,
-                lid=choice.lineage_id,
-                stored=choice.stored,
-                route=choice.describe_route(),
                 qrows=qrows,
                 pairs=pairs,
-                duration=elapsed,
+                **self._hop_attrs(plan, step, choice),
             )
+
+    @staticmethod
+    def _hop_attrs(
+        plan: QueryPlan | None, step: EdgeStep, choice: HopChoice
+    ) -> dict:
+        return {
+            "u": plan.node_array[step.u] if plan is not None else step.u,
+            "v": plan.node_array[step.v] if plan is not None else step.v,
+            "lid": choice.lineage_id,
+            "stored": choice.stored,
+            "route": choice.describe_route(),
+        }
 
     def _run_choice(
         self, choice: HopChoice, qs: list[QueryBox]

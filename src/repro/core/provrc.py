@@ -40,6 +40,8 @@ import itertools
 
 import numpy as np
 
+from repro.obs.trace import maybe_span
+
 from .intervals import coalesce_1d, lexsort_rows
 from .relation import LineageRelation
 from .table import CompressedTable
@@ -60,9 +62,20 @@ def compress(
     method: str = "auto",
     exact_threshold: int = 4096,
     stats: CompressStats | None = None,
+    trace=None,
 ) -> CompressedTable:
-    """Compress an uncompressed relation into a :class:`CompressedTable`."""
-    rel = rel.canonical()
+    """Compress an uncompressed relation into a :class:`CompressedTable`.
+
+    ``trace`` (a :class:`~repro.obs.trace.QueryTrace`, or None) records
+    ``canonical``, ``provrc.step1`` and ``provrc.step2`` spans, each with
+    the direction and its rows in and out.
+    """
+    with maybe_span(
+        trace, "canonical", kind="canonical", direction=direction
+    ) as sp:
+        rows_in = rel.out_idx.shape[0]
+        rel = rel.canonical()
+        sp.attrs.update(rows_in=rows_in, rows_out=rel.out_idx.shape[0])
     if direction == "backward":
         keys, vals = rel.out_idx, rel.in_idx
         key_shape, val_shape = rel.out_shape, rel.in_shape
@@ -90,22 +103,31 @@ def compress(
         stats["rows_in"] = n
 
     # ---- Step 1: range encoding over value attributes ------------------- #
-    for i in range(m - 1, -1, -1):
-        key_lo, key_hi, val_lo, val_hi, val_ref = _step1_pass(
-            key_lo, key_hi, val_lo, val_hi, val_ref, i
-        )
+    with maybe_span(
+        trace, "provrc.step1", kind="provrc.step1", direction=direction
+    ) as sp:
+        for i in range(m - 1, -1, -1):
+            key_lo, key_hi, val_lo, val_hi, val_ref = _step1_pass(
+                key_lo, key_hi, val_lo, val_hi, val_ref, i
+            )
+        sp.attrs.update(rows_in=n, rows_out=key_lo.shape[0])
 
     # ---- Step 2: relative transform + range encoding over keys ---------- #
-    if method == "paper":
-        key_lo, key_hi, val_lo, val_hi, val_ref = _step2_paper(
-            key_lo, key_hi, val_lo, val_hi, val_ref
-        )
-    elif method == "vector":
-        key_lo, key_hi, val_lo, val_hi, val_ref = _step2_vector(
-            key_lo, key_hi, val_lo, val_hi, val_ref
-        )
-    else:
-        raise ValueError(method)
+    with maybe_span(
+        trace, "provrc.step2", kind="provrc.step2", direction=direction
+    ) as sp:
+        rows_step2 = key_lo.shape[0]
+        if method == "paper":
+            key_lo, key_hi, val_lo, val_hi, val_ref = _step2_paper(
+                key_lo, key_hi, val_lo, val_hi, val_ref
+            )
+        elif method == "vector":
+            key_lo, key_hi, val_lo, val_hi, val_ref = _step2_vector(
+                key_lo, key_hi, val_lo, val_hi, val_ref
+            )
+        else:
+            raise ValueError(method)
+        sp.attrs.update(rows_in=rows_step2, rows_out=key_lo.shape[0])
 
     if stats is not None:
         stats["rows_out"] = key_lo.shape[0]

@@ -45,7 +45,6 @@ their owning queries.
 from __future__ import annotations
 
 import os
-import time
 from dataclasses import dataclass, field
 from typing import Sequence
 
@@ -58,6 +57,8 @@ from repro.kernels.autotune import (  # jax-free: geometry table + buckets
     GeometryTuner,
     shape_bucket,
 )
+
+from repro.obs.trace import maybe_span
 
 from .index import IntervalIndex, ragged_ranges
 from .intervals import coalesce_1d, lexsort_rows
@@ -823,8 +824,15 @@ class BatchedJoinExecutor:
         """
         results: list[list[QueryBox] | None] = [None] * len(requests)
         dense: list[tuple] = []
+        tr = self._trace()
         for i, req in enumerate(requests):
-            pre = _prepare_batch(req.queries, req.table, req.inverse)
+            with maybe_span(tr, "prepare", kind="prepare") as sp:
+                pre = _prepare_batch(req.queries, req.table, req.inverse)
+                if tr is not None and pre[0] == "join":
+                    sp.attrs.update(
+                        rows_in=sum(q.n_rows for q in req.queries),
+                        rows=pre[1].shape[0],
+                    )
             if pre[0] == "done":
                 results[i] = pre[1]
                 continue
@@ -833,16 +841,23 @@ class BatchedJoinExecutor:
                 u_lo, u_hi, r_lo, r_hi, index_get, req.path
             )
             if route == "index":
-                ui, ri = index_get().candidate_pairs(u_lo, u_hi, windows)
-                results[i] = _finalize_batch(
-                    req.queries, req.table, req.inverse,
-                    u_lo, u_hi, inv, ui, ri, req.merge,
-                )
+                with maybe_span(tr, "index_probe", kind="index_probe") as sp:
+                    ui, ri = index_get().candidate_pairs(u_lo, u_hi, windows)
+                    sp.attrs["pairs"] = ui.size
+                with maybe_span(tr, "finalize", kind="finalize"):
+                    results[i] = _finalize_batch(
+                        req.queries, req.table, req.inverse,
+                        u_lo, u_hi, inv, ui, ri, req.merge,
+                    )
             else:
                 dense.append((i, req, u_lo, u_hi, inv, r_lo, r_hi))
         if dense:
             self._run_dense(dense, results, workers)
         return results  # type: ignore[return-value]
+
+    def _trace(self):
+        """The owning store's active QueryTrace, or None."""
+        return self._trace_source() if self._trace_source is not None else None
 
     # ------------------------------------------------------------------ #
     def _run_dense(
@@ -880,83 +895,95 @@ class BatchedJoinExecutor:
                 and fits_int32(it[2], it[3], it[5], it[6])
             ]
 
+        tr = self._trace()
+
         def finalize(k: int, ui: np.ndarray, ri: np.ndarray) -> None:
             i, req, u_lo, u_hi, inv, _r_lo, _r_hi = items[k]
-            results[i] = _finalize_batch(
-                req.queries, req.table, req.inverse,
-                u_lo, u_hi, inv, ui, ri, req.merge,
-            )
+            with maybe_span(tr, "finalize", kind="finalize"):
+                results[i] = _finalize_batch(
+                    req.queries, req.table, req.inverse,
+                    u_lo, u_hi, inv, ui, ri, req.merge,
+                )
 
-        tr = self._trace_source() if self._trace_source is not None else None
         if kernel_idx:
             from repro.kernels.ops import segmented_range_join_pairs
 
-            t0 = time.perf_counter()
-            segs = [
-                (items[k][2], items[k][3], items[k][5], items[k][6])
-                for k in kernel_idx
-            ]
-            shapes = [(s[0].shape[0], s[2].shape[0], s[0].shape[1]) for s in segs]
-            backend = "tpu" if not interpret else "interpret"
-            bucket = shape_bucket(shapes)
-            geom = self._tuner.lookup(backend, bucket)
-            result = None
-            if geom is None:
-                if sum(nq + nr for nq, nr, _ in shapes) >= _TUNE_MIN_ROWS:
-                    # first big frontier of this shape: measure the
-                    # candidates on it (the winner's run is kept, so the
-                    # tuning dispatch does the real work) and persist the
-                    # geometry via the store's autotune table
-                    geom, result = self._tuner.pick(
-                        backend,
-                        bucket,
-                        runner=lambda g: segmented_range_join_pairs(
-                            segs, block_q=g[0], block_r=g[1],
-                            interpret=interpret,
-                        ),
-                    )
-                    if self._metrics is not None:
-                        self._metrics.inc(
-                            "autotune_decisions",
-                            backend=backend,
-                            bucket=str(bucket),
+            with maybe_span(tr, "kernel_launch", kind="kernel") as sp:
+                segs = [
+                    (items[k][2], items[k][3], items[k][5], items[k][6])
+                    for k in kernel_idx
+                ]
+                shapes = [
+                    (s[0].shape[0], s[2].shape[0], s[0].shape[1]) for s in segs
+                ]
+                backend = "tpu" if not interpret else "interpret"
+                bucket = shape_bucket(shapes)
+                geom = self._tuner.lookup(backend, bucket)
+                result = None
+                if geom is None:
+                    if sum(nq + nr for nq, nr, _ in shapes) >= _TUNE_MIN_ROWS:
+                        # first big frontier of this shape: measure the
+                        # candidates on it (the winner's run is kept, so the
+                        # tuning dispatch does the real work) and persist the
+                        # geometry via the store's autotune table
+                        geom, result = self._tuner.pick(
+                            backend,
+                            bucket,
+                            runner=lambda g: segmented_range_join_pairs(
+                                segs, block_q=g[0], block_r=g[1],
+                                interpret=interpret, trace=tr,
+                            ),
                         )
-                else:
-                    geom = DEFAULT_GEOMETRY
-            if result is None:
-                result = segmented_range_join_pairs(
-                    segs, block_q=geom[0], block_r=geom[1], interpret=interpret
+                        if self._metrics is not None:
+                            self._metrics.inc(
+                                "autotune_decisions",
+                                backend=backend,
+                                bucket=str(bucket),
+                            )
+                    else:
+                        geom = DEFAULT_GEOMETRY
+                if result is None:
+                    result = segmented_range_join_pairs(
+                        segs, block_q=geom[0], block_r=geom[1],
+                        interpret=interpret, trace=tr,
+                    )
+                seg_pairs, info = result
+                for k, (ui, ri) in zip(kernel_idx, seg_pairs):
+                    finalize(k, ui, ri)
+                self._last_geometry["kernel"] = tuple(geom)
+                self._stats("kernel_launches", info["launches"])
+                self._stats("joins_packed", len(kernel_idx))
+                self._stats("batch_rows", info["rows"])
+                self._stats("batch_rows_padded", info["rows_padded"])
+                self._stats("batch_tiles_visited", info["tiles_visited"])
+                self._stats("batch_tiles_skipped", info["tiles_skipped"])
+                self._stats("h2d_bytes", info["h2d_bytes"])
+                self._stats("d2h_bytes", info["d2h_bytes"])
+                self._stats("join_pairs", info["pairs"])
+                self._observe_occupancy(
+                    float(info["tiles_visited"]) * geom[0] * geom[1],
+                    float(sum(nq * nr for nq, nr, _ in shapes)),
                 )
-            seg_pairs, info = result
-            for k, (ui, ri) in zip(kernel_idx, seg_pairs):
-                finalize(k, ui, ri)
-            self._last_geometry["kernel"] = tuple(geom)
-            self._stats("kernel_launches", info["launches"])
-            self._stats("joins_packed", len(kernel_idx))
-            self._stats("batch_rows", info["rows"])
-            self._stats("batch_rows_padded", info["rows_padded"])
-            self._stats("batch_tiles_visited", info["tiles_visited"])
-            self._stats("batch_tiles_skipped", info["tiles_skipped"])
-            self._observe_occupancy(
-                float(info["tiles_visited"]) * geom[0] * geom[1],
-                float(sum(nq * nr for nq, nr, _ in shapes)),
-            )
-            if tr is not None:
-                tr.event(
-                    "kernel_launch",
-                    kind="kernel",
-                    backend=backend,
-                    segments=len(kernel_idx),
-                    geometry=f"{geom[0]}x{geom[1]}",
-                    launches=info["launches"],
-                    rows=info["rows"],
-                    duration=time.perf_counter() - t0,
-                )
+                if tr is not None:
+                    sp.attrs.update(
+                        backend=backend,
+                        segments=len(kernel_idx),
+                        geometry=f"{geom[0]}x{geom[1]}",
+                        launches=info["launches"],
+                        rows=info["rows"],
+                        h2d_bytes=info["h2d_bytes"],
+                        d2h_bytes=info["d2h_bytes"],
+                        pairs=info["pairs"],
+                    )
         done = set(kernel_idx)
         rest = [k for k in range(len(items)) if k not in done]
-        if not rest:
-            return
-        t0 = time.perf_counter()
+        if rest:
+            with maybe_span(tr, "twin", kind="kernel") as sp:
+                self._run_twin(items, rest, workers, finalize, tr, sp)
+
+    def _run_twin(self, items, rest, workers, finalize, tr, sp) -> None:
+        """The numpy twin's evaluation of the segments the kernel does not
+        take, finalized; its counts land on the ``twin`` span ``sp``."""
         rows = sum(items[k][2].shape[0] + items[k][5].shape[0] for k in rest)
         pairs: dict[int, tuple[np.ndarray, np.ndarray]] = {}
 
@@ -1068,19 +1095,19 @@ class BatchedJoinExecutor:
         self._stats("batch_rows_padded", rows)
         self._stats("batch_tiles_visited", visited)
         self._stats("batch_tiles_skipped", skipped)
+        n_pairs = sum(pairs[k][0].size for k in rest)
+        self._stats("join_pairs", n_pairs)
         # per-segment evaluation has no tile padding: cells-exact occupancy
         useful = float(sum(nq * nr for nq, nr, _ in twin_shapes))
         self._observe_occupancy(useful, useful)
         if tr is not None:
-            tr.event(
-                "twin",
-                kind="kernel",
+            sp.attrs.update(
                 backend="np",
                 segments=len(rest),
                 rows=rows,
                 block_cells=block_cells,
                 workers=width,
-                duration=time.perf_counter() - t0,
+                pairs=n_pairs,
             )
 
 

@@ -606,6 +606,7 @@ class ShardedDSLog:
     _check_shapes = DSLog._check_shapes
     prov_query = DSLog.prov_query
     prov_query_batch = DSLog.prov_query_batch
+    trace_scope = DSLog.trace_scope
     _query_batch_impl = DSLog._query_batch_impl
     _as_boxes = DSLog._as_boxes
     _parse_query_args = staticmethod(DSLog._parse_query_args)

@@ -52,6 +52,7 @@ import zlib
 from typing import Iterator
 
 from repro.obs.metrics import MetricsRegistry, StatsView
+from repro.obs.trace import maybe_span
 
 from . import _locks
 
@@ -117,8 +118,13 @@ class WriteAheadLog:
     ``flock`` so several processes can interleave whole records.
     """
 
-    def __init__(self, path: str, shared: bool = False, metrics=None):
+    def __init__(
+        self, path: str, shared: bool = False, metrics=None, trace_source=None
+    ):
         self.path = path
+        # callable yielding the owning store's active QueryTrace (or None):
+        # an fsync under a traced scope records an ``fsync`` span
+        self._trace_source = trace_source
         self.shared = bool(shared)
         self._lock = _locks.new_lock("wal._lock")
         self._pending: list[bytes] = []  # shared mode: unwritten records
@@ -325,10 +331,12 @@ class WriteAheadLog:
             fd = self._f.fileno()
         self.metrics.inc("wal_flushes")
         if sync:
-            t0 = time.perf_counter()
-            os.fsync(fd)
-            self.metrics.inc("wal_syncs")
-            self.metrics.observe("wal_fsync_seconds", time.perf_counter() - t0)
+            tr = self._trace_source() if self._trace_source is not None else None
+            with maybe_span(tr, "fsync", kind="fsync"):
+                t0 = time.perf_counter()
+                os.fsync(fd)
+                self.metrics.inc("wal_syncs")
+                self.metrics.observe("wal_fsync_seconds", time.perf_counter() - t0)
 
     # ------------------------------------------------------------------ #
     def recover(self, min_lsn: int = 0, truncate: bool = False) -> list[WalRecord]:

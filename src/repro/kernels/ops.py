@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import maybe_span
+
 from .range_join import (
     LANES,
     check_lane_capacity,
@@ -168,13 +170,20 @@ def _pad_packed_rows(p: np.ndarray, mult: int, n_attrs: int) -> np.ndarray:
     return np.concatenate([p, np.tile(row, (pad, 1))], axis=0)
 
 
+def _upload(*arrays: np.ndarray) -> tuple[list, int]:
+    """``jnp.asarray`` each host array; returns the device arrays and the
+    bytes they moved host to device."""
+    return [jnp.asarray(a) for a in arrays], sum(a.nbytes for a in arrays)
+
+
 def _blockdiag_pairs(
     segments: "list[tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]]",
     n_attrs: int,
     block_q: int,
     block_r: int,
     interpret: bool,
-) -> tuple[list[tuple[np.ndarray, np.ndarray]], int, int]:
+    trace=None,
+) -> tuple[list[tuple[np.ndarray, np.ndarray]], dict]:
     """Per-segment pairs via the tile-scheduled (block-diagonal) kernel.
 
     Each segment is packed and padded to block multiples *independently*
@@ -182,74 +191,96 @@ def _blockdiag_pairs(
     diagonal tile schedule is enumerated on the host, and pair extraction
     runs on the ``[T, block_q, block_r]`` tile stack — host transfer scales
     with the diagonal, not the cross product.  Returns the per-segment
-    pair lists plus (padded rows, tiles visited).
+    pair lists plus ``rows_padded``, ``tiles_visited``, ``h2d_bytes``,
+    ``d2h_bytes`` and ``pairs``.
     """
     n_segs = len(segments)
-    q_parts = [
-        _pad_packed_rows(_pack_boxes(s[0], s[1], n_attrs), block_q, n_attrs)
-        for s in segments
-    ]
-    r_parts = [
-        _pad_packed_rows(_pack_boxes(s[2], s[3], n_attrs), block_r, n_attrs)
-        for s in segments
-    ]
-    nqb = np.array([p.shape[0] // block_q for p in q_parts], np.int64)
-    nrb = np.array([p.shape[0] // block_r for p in r_parts], np.int64)
-    q_blk_off = np.concatenate([[0], np.cumsum(nqb)])
-    r_blk_off = np.concatenate([[0], np.cumsum(nrb)])
-    tile_start = np.concatenate([[0], np.cumsum(nqb * nrb)])
-    n_tiles = int(tile_start[-1])
-    empty = (np.zeros(0, np.int64), np.zeros(0, np.int64))
-    if n_tiles == 0:
-        return [empty for _ in segments], 0, 0
-    # the diagonal schedule: segment-major, q-block outer / r-block inner
-    tile_q = np.concatenate(
-        [q_blk_off[s] + np.repeat(np.arange(nqb[s]), nrb[s]) for s in range(n_segs)]
-    )
-    tile_r = np.concatenate(
-        [r_blk_off[s] + np.tile(np.arange(nrb[s]), int(nqb[s])) for s in range(n_segs)]
-    )
-    masks = range_join_tile_masks(
-        jnp.asarray(np.concatenate(q_parts, axis=0)),
-        jnp.asarray(np.concatenate(r_parts, axis=0)),
-        # dslint: ignore[int32-cast] block indices, bounded by row count/block
-        jnp.asarray(tile_q.astype(np.int32)),
-        # dslint: ignore[int32-cast] block indices, bounded by row count/block
-        jnp.asarray(tile_r.astype(np.int32)),
-        n_attrs=n_attrs,
-        block_q=block_q,
-        block_r=block_r,
-        interpret=interpret,
-    )
-    flat = np.flatnonzero(np.asarray(masks))
-    t, rem = np.divmod(flat, block_q * block_r)
-    lq, lr = np.divmod(rem, block_r)
-    qi_pad = tile_q[t] * block_q + lq  # global padded-row coordinates
-    ri_pad = tile_r[t] * block_r + lr
-    # tiles are segment-grouped and flatnonzero is tile-major, so one cut
-    # per segment recovers the per-join slices
-    cuts = np.searchsorted(t, tile_start[1:-1])
-    out = []
-    for s, (qs, rs) in enumerate(
-        zip(np.split(qi_pad, cuts), np.split(ri_pad, cuts))
-    ):
-        qi = qs - q_blk_off[s] * block_q
-        ri = rs - r_blk_off[s] * block_r
-        keep = (qi < segments[s][0].shape[0]) & (ri < segments[s][2].shape[0])
-        if not keep.all():
-            qi, ri = qi[keep], ri[keep]
-        if nrb[s] > 1:
-            # tiles run r-block inner, so segments spanning several r blocks
-            # need a row-major resort to match the dense oracle's pair order
-            order = np.lexsort((ri, qi))
-            qi, ri = qi[order], ri[order]
-        out.append(
-            (qi.astype(np.int64, copy=False), ri.astype(np.int64, copy=False))
+    with maybe_span(trace, "pack", kind="pack"):
+        q_parts = [
+            _pad_packed_rows(_pack_boxes(s[0], s[1], n_attrs), block_q, n_attrs)
+            for s in segments
+        ]
+        r_parts = [
+            _pad_packed_rows(_pack_boxes(s[2], s[3], n_attrs), block_r, n_attrs)
+            for s in segments
+        ]
+        nqb = np.array([p.shape[0] // block_q for p in q_parts], np.int64)
+        nrb = np.array([p.shape[0] // block_r for p in r_parts], np.int64)
+        q_blk_off = np.concatenate([[0], np.cumsum(nqb)])
+        r_blk_off = np.concatenate([[0], np.cumsum(nrb)])
+        tile_start = np.concatenate([[0], np.cumsum(nqb * nrb)])
+        n_tiles = int(tile_start[-1])
+        rows_padded = int(
+            sum(p.shape[0] for p in q_parts) + sum(p.shape[0] for p in r_parts)
         )
-    rows_padded = int(
-        sum(p.shape[0] for p in q_parts) + sum(p.shape[0] for p in r_parts)
-    )
-    return out, rows_padded, n_tiles
+        empty = (np.zeros(0, np.int64), np.zeros(0, np.int64))
+        if n_tiles == 0:
+            moved = {"rows_padded": 0, "tiles_visited": 0,
+                     "h2d_bytes": 0, "d2h_bytes": 0, "pairs": 0}
+            return [empty for _ in segments], moved
+        # the diagonal schedule: segment-major, q-block outer / r-block inner
+        tile_q = np.concatenate(
+            [q_blk_off[s] + np.repeat(np.arange(nqb[s]), nrb[s])
+             for s in range(n_segs)]
+        )
+        tile_r = np.concatenate(
+            [r_blk_off[s] + np.tile(np.arange(nrb[s]), int(nqb[s]))
+             for s in range(n_segs)]
+        )
+        q_packed = np.concatenate(q_parts, axis=0)
+        r_packed = np.concatenate(r_parts, axis=0)
+    with maybe_span(trace, "dispatch", kind="dispatch") as sp:
+        (qd, rd, tqd, trd), h2d = _upload(
+            q_packed,
+            r_packed,
+            # dslint: ignore[int32-cast] block indices, bounded by row count/block
+            tile_q.astype(np.int32),
+            # dslint: ignore[int32-cast] block indices, bounded by row count/block
+            tile_r.astype(np.int32),
+        )
+        masks = range_join_tile_masks(
+            qd, rd, tqd, trd,
+            n_attrs=n_attrs,
+            block_q=block_q,
+            block_r=block_r,
+            interpret=interpret,
+        )
+        sp.attrs["h2d_bytes"] = h2d
+    with maybe_span(trace, "readback", kind="readback") as sp:
+        host_masks = np.asarray(masks)
+        sp.attrs["d2h_bytes"] = host_masks.nbytes
+    with maybe_span(trace, "pair_extract", kind="pair_extract") as sp:
+        flat = np.flatnonzero(host_masks)
+        t, rem = np.divmod(flat, block_q * block_r)
+        lq, lr = np.divmod(rem, block_r)
+        qi_pad = tile_q[t] * block_q + lq  # global padded-row coordinates
+        ri_pad = tile_r[t] * block_r + lr
+        # tiles are segment-grouped and flatnonzero is tile-major, so one cut
+        # per segment recovers the per-join slices
+        cuts = np.searchsorted(t, tile_start[1:-1])
+        out = []
+        for s, (qs, rs) in enumerate(
+            zip(np.split(qi_pad, cuts), np.split(ri_pad, cuts))
+        ):
+            qi = qs - q_blk_off[s] * block_q
+            ri = rs - r_blk_off[s] * block_r
+            keep = (qi < segments[s][0].shape[0]) & (ri < segments[s][2].shape[0])
+            if not keep.all():
+                qi, ri = qi[keep], ri[keep]
+            if nrb[s] > 1:
+                # tiles run r-block inner, so segments spanning several r
+                # blocks need a row-major resort to match the dense oracle's
+                # pair order
+                order = np.lexsort((ri, qi))
+                qi, ri = qi[order], ri[order]
+            out.append(
+                (qi.astype(np.int64, copy=False), ri.astype(np.int64, copy=False))
+            )
+        n_pairs = sum(qi.size for qi, _ in out)
+        sp.attrs["pairs"] = n_pairs
+    moved = {"rows_padded": rows_padded, "tiles_visited": n_tiles,
+             "h2d_bytes": h2d, "d2h_bytes": host_masks.nbytes, "pairs": n_pairs}
+    return out, moved
 
 
 def segmented_range_join_pairs(
@@ -258,6 +289,7 @@ def segmented_range_join_pairs(
     block_r: int = 256,
     interpret: bool | None = None,
     layout: str = "auto",
+    trace=None,
 ) -> tuple[list[tuple[np.ndarray, np.ndarray]], dict]:
     """Many independent range joins in **one** kernel launch.
 
@@ -281,7 +313,14 @@ def segmented_range_join_pairs(
     order, bit-identical between layouts and to a per-segment dense
     evaluation) plus occupancy info for ``io_stats``: ``tiles_visited`` is
     the executed schedule, ``tiles_skipped`` the cross-product tiles the
-    block-diagonal schedule avoided.
+    block-diagonal schedule avoided, ``h2d_bytes`` the packed operands and
+    tile schedule uploaded, ``d2h_bytes`` the mask or tile stack read
+    back, ``pairs`` the pairs returned.
+
+    ``trace`` (a :class:`~repro.obs.trace.QueryTrace`, or None) records the
+    host stages as ``pack``, ``dispatch`` (uploads plus the kernel call),
+    ``readback`` (the device wait and the copy to the host) and
+    ``pair_extract`` spans.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -290,6 +329,7 @@ def segmented_range_join_pairs(
         return [], {
             "rows": 0, "rows_padded": 0, "launches": 0, "layout": "dense",
             "geometry": geometry, "tiles_visited": 0, "tiles_skipped": 0,
+            "h2d_bytes": 0, "d2h_bytes": 0, "pairs": 0,
         }
     if layout not in ("auto", "dense", "blockdiag"):
         raise ValueError(f"unknown launch layout {layout!r}")
@@ -316,17 +356,16 @@ def segmented_range_join_pairs(
         )
     if layout == "blockdiag":
         check_lane_capacity(l_max)  # no segment lane: tiles never cross segments
-        out, rows_padded, visited = _blockdiag_pairs(
-            segments, l_max, block_q, block_r, interpret
+        out, moved = _blockdiag_pairs(
+            segments, l_max, block_q, block_r, interpret, trace
         )
         return out, {
             "rows": rows,
-            "rows_padded": rows_padded,
             "launches": 1,
             "layout": "blockdiag",
             "geometry": geometry,
-            "tiles_visited": visited,
-            "tiles_skipped": max(0, int(cross_tiles - visited)),
+            "tiles_skipped": max(0, int(cross_tiles - moved["tiles_visited"])),
+            **moved,
         }
 
     # masked dense cross-product launch
@@ -344,32 +383,42 @@ def segmented_range_join_pairs(
             parts.append(p)
         return np.concatenate(parts, axis=0)
 
-    qp = pack_side([(s[0], s[1]) for s in segments])
-    rp = pack_side([(s[2], s[3]) for s in segments])
-    q_off = np.cumsum([0] + [s[0].shape[0] for s in segments])
-    r_off = np.cumsum([0] + [s[2].shape[0] for s in segments])
-    mask = range_join_mask(
-        jnp.asarray(qp),
-        jnp.asarray(rp),
-        n_attrs=n_attrs,
-        block_q=block_q,
-        block_r=block_r,
-        interpret=interpret,
-    )
-    qi, ri = np.nonzero(np.asarray(mask))
-    # pairs are qi-major and the segment lane confines ri to the segment's
-    # own column range, so one cut per segment recovers the per-join lists
-    cuts = np.searchsorted(qi, q_off[1:-1])
-    out = []
-    for seg, (qs, rs) in enumerate(
-        zip(np.split(qi, cuts), np.split(ri, cuts))
-    ):
-        out.append(
-            (
-                (qs - q_off[seg]).astype(np.int64),
-                (rs - r_off[seg]).astype(np.int64),
-            )
+    with maybe_span(trace, "pack", kind="pack"):
+        qp = pack_side([(s[0], s[1]) for s in segments])
+        rp = pack_side([(s[2], s[3]) for s in segments])
+        q_off = np.cumsum([0] + [s[0].shape[0] for s in segments])
+        r_off = np.cumsum([0] + [s[2].shape[0] for s in segments])
+    with maybe_span(trace, "dispatch", kind="dispatch") as sp:
+        (qd, rd), h2d = _upload(qp, rp)
+        mask = range_join_mask(
+            qd,
+            rd,
+            n_attrs=n_attrs,
+            block_q=block_q,
+            block_r=block_r,
+            interpret=interpret,
         )
+        sp.attrs["h2d_bytes"] = h2d
+    with maybe_span(trace, "readback", kind="readback") as sp:
+        host_mask = np.asarray(mask)
+        sp.attrs["d2h_bytes"] = host_mask.nbytes
+    with maybe_span(trace, "pair_extract", kind="pair_extract") as sp:
+        qi, ri = np.nonzero(host_mask)
+        # pairs are qi-major and the segment lane confines ri to the
+        # segment's own column range, so one cut per segment recovers the
+        # per-join lists
+        cuts = np.searchsorted(qi, q_off[1:-1])
+        out = []
+        for seg, (qs, rs) in enumerate(
+            zip(np.split(qi, cuts), np.split(ri, cuts))
+        ):
+            out.append(
+                (
+                    (qs - q_off[seg]).astype(np.int64),
+                    (rs - r_off[seg]).astype(np.int64),
+                )
+            )
+        sp.attrs["pairs"] = qi.size
     rows_padded = int(
         -(-qp.shape[0] // block_q) * block_q + -(-rp.shape[0] // block_r) * block_r
     )
@@ -381,4 +430,7 @@ def segmented_range_join_pairs(
         "geometry": geometry,
         "tiles_visited": int(cross_tiles),
         "tiles_skipped": 0,
+        "h2d_bytes": h2d,
+        "d2h_bytes": host_mask.nbytes,
+        "pairs": int(qi.size),
     }

@@ -7,9 +7,9 @@ pieces:
 - :mod:`repro.obs.metrics` — typed counters/gauges/log-bucketed
   histograms behind a single internally-locked :class:`MetricsRegistry`;
   ``DSLog.io_stats`` is a live read-only view over it.
-- :mod:`repro.obs.trace` — off-by-default per-query span trees
-  (``plan -> hop -> kernel launch / twin / exchange / cache probe /
-  view race``) with wall time and instrument deltas per span.
+- :mod:`repro.obs.trace` — off-by-default span trees over a query's
+  and an ingest's host stages, with wall time and per-stage counts,
+  each span also a ``dslog.<name>`` profiler annotation.
 - :mod:`repro.obs.export` — ``telemetry.json`` snapshot schema,
   Prometheus text exposition, and the combined ``health()`` report.
 """

@@ -1,17 +1,22 @@
-"""Structured per-query tracing: span trees with instrument deltas.
+"""Structured tracing: span trees on the profiler's clock.
 
-A :class:`QueryTrace` is created by ``prov_query(..., trace=True)`` and
-installed as ``log._active_trace`` for the duration of the query.  Hot
-paths check ``self._active_trace is not None`` — a single attribute load
-— so the tracing-off cost is effectively zero and is bounded by a
-microbenchmark in ``tests/test_obs.py``.
+A :class:`QueryTrace` is installed as ``log._active_trace`` by
+:meth:`DSLog.trace_scope` (``prov_query(..., trace=True)`` runs under
+one).  Hot paths check ``self._active_trace is not None`` — a single
+attribute load — so the tracing-off cost is effectively zero and is
+bounded by a microbenchmark in ``tests/test_obs.py``.
 
-Spans form a tree rooted at the ``query`` span.  Each span records wall
-time (``perf_counter`` deltas) and, when a registry is attached, the
-delta of every unlabeled counter that moved while the span was open.
-Worker threads (``prov_query(..., parallel=N)``) have no span stack of
-their own; their spans attach to the root, which keeps the tree
-race-free without cross-thread coordination.
+Spans form a tree rooted at the scope's label (``query`` for
+``prov_query``).  Each span records wall time (``perf_counter``) and the
+counts its stage produced, as ``attrs``.  While a span is open it also
+holds a ``jax.profiler.TraceAnnotation("dslog.<name>")``: under a
+profiler session every span lands on the host plane of the device
+trace, on the device events' clock, so device-idle gaps can be charged
+to the host stage that was running.  Outside a session an annotation
+costs about a microsecond and records nothing.  Worker threads
+(``prov_query(..., parallel=N)``, the group-commit flusher) have no span
+stack of their own; their spans attach to the root, which keeps the
+tree race-free without cross-thread coordination.
 
 The span-stack lock is minted through ``repro.core._locks`` (name
 ``trace._lock``, rank 90 — a leaf above ``metrics._lock``) so the
@@ -20,16 +25,20 @@ dynamic race detector watches it too.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from contextlib import contextmanager
 from typing import Iterator
 
-__all__ = ["QueryTrace", "Span", "maybe_span"]
+__all__ = ["ANNOTATION_PREFIX", "QueryTrace", "Span", "maybe_span"]
+
+# profiler annotations of program spans: "dslog.<span name>"
+ANNOTATION_PREFIX = "dslog."
 
 
 class Span:
-    __slots__ = ("name", "kind", "attrs", "start", "duration", "delta", "children")
+    __slots__ = ("name", "kind", "attrs", "start", "duration", "children")
 
     def __init__(self, name: str, kind: str = "", attrs: dict | None = None) -> None:
         self.name = name
@@ -37,7 +46,6 @@ class Span:
         self.attrs = attrs or {}
         self.start = 0.0
         self.duration: float | None = None
-        self.delta: dict[str, int] = {}
         self.children: list[Span] = []
 
     def to_dict(self) -> dict:
@@ -46,7 +54,6 @@ class Span:
             "kind": self.kind,
             "attrs": self.attrs,
             "duration_ms": None if self.duration is None else self.duration * 1e3,
-            "delta": self.delta,
             "children": [c.to_dict() for c in self.children],
         }
 
@@ -56,20 +63,36 @@ class Span:
             yield from child.walk()
 
 
+class _Discard(dict):
+    """A mapping that keeps nothing written to it."""
+
+    __slots__ = ()
+
+    def __setitem__(self, key, value) -> None:
+        pass
+
+    def update(self, *args, **kwargs) -> None:
+        pass
+
+
 class _NullSpan:
-    """Throwaway span stand-in so untraced code can set ``sp.attrs``."""
+    """Span stand-in so untraced code can set ``sp.attrs``; one shared
+    instance, so the untraced path allocates nothing per stage."""
 
     __slots__ = ("attrs",)
 
     def __init__(self) -> None:
-        self.attrs: dict = {}
+        self.attrs: dict = _Discard()
+
+
+_NULL_SPAN = _NullSpan()
 
 
 class _NullSpanCtx:
     __slots__ = ()
 
     def __enter__(self) -> _NullSpan:
-        return _NullSpan()
+        return _NULL_SPAN
 
     def __exit__(self, *exc) -> bool:
         return False
@@ -85,11 +108,22 @@ def maybe_span(trace: "QueryTrace | None", name: str, kind: str = "", **attrs):
     return trace.span(name, kind=kind, **attrs)
 
 
-class QueryTrace:
-    """Span tree for one query, with optional counter-delta capture."""
+def _annotation_type():
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:  # pragma: no cover - standalone use without jax
+        return lambda name: contextlib.nullcontext()
+    return TraceAnnotation
 
-    def __init__(self, registry=None, label: str = "query") -> None:
-        self._registry = registry
+
+class QueryTrace:
+    """Span tree for one query or one traced block of work.
+
+    Used as a context manager, the root span holds its own annotation
+    (``dslog.<label>``) from ``__enter__`` to ``__exit__``.
+    """
+
+    def __init__(self, label: str = "query") -> None:
         try:
             from repro.core import _locks
 
@@ -97,8 +131,20 @@ class QueryTrace:
         except ImportError:  # pragma: no cover - standalone use
             self._lock = threading.Lock()
         self._tls = threading.local()
-        self.root = Span(label, kind="query")
+        self._annotation = _annotation_type()
+        self._root_annotation = None
+        self.root = Span(label, kind=label)
         self.root.start = time.perf_counter()
+
+    def __enter__(self) -> "QueryTrace":
+        self._root_annotation = self._annotation(ANNOTATION_PREFIX + self.root.name)
+        self._root_annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.finish()
+        self._root_annotation.__exit__(*exc)
+        return False
 
     # -- span stack (per thread) -----------------------------------------
 
@@ -120,31 +166,23 @@ class QueryTrace:
 
     @contextmanager
     def span(self, name: str, kind: str = "", **attrs):
-        """Open a child span; on exit record duration + counter deltas."""
+        """Open a child span, annotated on the profiler's timeline."""
         sp = Span(name, kind=kind, attrs=attrs)
         parent = self.current()
         stack = self._stack()
         stack.append(sp)
-        before = self._registry.counters_flat() if self._registry is not None else None
-        sp.start = time.perf_counter()
-        try:
-            yield sp
-        finally:
-            sp.duration = time.perf_counter() - sp.start
-            if before is not None:
-                after = self._registry.counters_flat()
-                sp.delta = {
-                    k: after[k] - before.get(k, 0)
-                    for k in after
-                    if after[k] != before.get(k, 0)
-                }
-            stack.pop()
-            self._attach(parent, sp)
+        with self._annotation(ANNOTATION_PREFIX + name):
+            sp.start = time.perf_counter()
+            try:
+                yield sp
+            finally:
+                sp.duration = time.perf_counter() - sp.start
+                stack.pop()
+                self._attach(parent, sp)
 
-    def event(self, name: str, kind: str = "", duration: float | None = None, **attrs) -> Span:
-        """Record a leaf span without opening a scope (for inline sites)."""
+    def event(self, name: str, kind: str = "", **attrs) -> Span:
+        """Record an instant leaf (no duration) under the current span."""
         sp = Span(name, kind=kind, attrs=attrs)
-        sp.duration = duration
         self._attach(self.current(), sp)
         return sp
 
@@ -176,11 +214,7 @@ class QueryTrace:
             attrs = ""
             if span.attrs:
                 attrs = " " + " ".join(f"{k}={v}" for k, v in span.attrs.items())
-            delta = ""
-            if span.delta:
-                moved = ", ".join(f"{k}+{v}" for k, v in sorted(span.delta.items()))
-                delta = f" [{moved}]"
-            lines.append(f"{'  ' * depth}{span.name}{dur}{attrs}{delta}")
+            lines.append(f"{'  ' * depth}{span.name}{dur}{attrs}")
             for child in span.children:
                 fmt(child, depth + 1)
 

@@ -36,9 +36,10 @@ Rank order (outermost → innermost):
     counter/histogram update may fire while any of the locks above is
     held (WAL appends, commit flushes, stats bookkeeping), so the
     registry lock is a leaf below all of them and takes no other lock.
-11. ``trace._lock`` — a ``QueryTrace``'s span-attach lock.  Span exit
-    reads counter deltas (``metrics._lock``) *before* attaching, so the
-    trace lock nests innermost of all.
+11. ``trace._lock`` — a ``QueryTrace``'s span-attach lock.  A span
+    closes inside any of the locks above (an ``fsync`` span under the
+    commit pipeline's flush mutex) and takes no other lock while
+    attaching, so the trace lock nests innermost of all.
 
 Lock names are ``"<module stem>.<attribute>"``; every lock constructed via
 ``repro.core._locks`` carries one.

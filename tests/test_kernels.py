@@ -220,6 +220,32 @@ def test_blockdiag_layout_property(data):
             np.testing.assert_array_equal(got[1], wr)
 
 
+@pytest.mark.parametrize("layout", ["dense", "blockdiag"])
+def test_segmented_launch_counts_bytes_moved_and_pairs(layout):
+    """``h2d_bytes`` is every array uploaded (packed ``[rows, 128]`` int32
+    operands, plus the int32 tile schedule of a block-diagonal launch);
+    ``d2h_bytes`` the int32 mask or tile stack read back."""
+    from repro.kernels.ops import LANES, segmented_range_join_pairs
+
+    segs = _random_segments(np.random.default_rng(5), 3, max_rows=50)
+    out, info = segmented_range_join_pairs(
+        segs, block_q=32, block_r=32, interpret=True, layout=layout
+    )
+    nq = [s[0].shape[0] for s in segs]
+    nr = [s[2].shape[0] for s in segs]
+    if layout == "dense":
+        h2d = (sum(nq) + sum(nr)) * LANES * 4
+        d2h = sum(nq) * sum(nr) * 4
+    else:
+        tiles = sum(-(-q // 32) * -(-r // 32) for q, r in zip(nq, nr))
+        assert info["tiles_visited"] == tiles
+        h2d = info["rows_padded"] * LANES * 4 + 2 * tiles * 4
+        d2h = tiles * 32 * 32 * 4
+    assert info["h2d_bytes"] == h2d
+    assert info["d2h_bytes"] == d2h
+    assert info["pairs"] == sum(qi.size for qi, _ in out) > 0
+
+
 def test_blockdiag_padding_rows_never_match():
     """Per-segment padding rows carry (lo=1, hi=0); boxes spanning [<=0, >=1]
     can graze them, so the extractor's bounds filter must drop any pair

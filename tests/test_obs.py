@@ -2,7 +2,9 @@
 
 Covers the observability invariants end to end: instrument math
 (log-bucketed percentiles, snapshot merge), the live ``io_stats`` facade,
-the per-query span tree (plan / hop / kernel / exchange / cache / view),
+the per-query span tree (plan / hop / kernel / exchange / cache / view and
+the stages inside a dispatch), the ingest spans under ``trace_scope``, the
+dispatch byte and pair counters, profiler annotations only while tracing,
 ``describe(analyze=True)``, the ``telemetry.json`` sidecar + Prometheus
 exposition + ``dstat`` CLI, health red-flags, the sharded aggregation
 union fix, tracing on/off bit-identity under the race detector, and a
@@ -21,9 +23,12 @@ from repro.core.capture import (
     flip_lineage,
     identity_lineage,
     roll_lineage,
+    sort_lineage,
     transpose_lineage,
 )
 from repro.core.catalog import DSLog
+from repro.core.provrc import compress
+from repro.core.query import BatchedJoinExecutor, JoinRequest, QueryBox
 from repro.core.shard import ShardedDSLog
 from repro.obs.export import (
     TELEMETRY_SCHEMA,
@@ -161,14 +166,20 @@ def test_trace_covers_plan_hop_kernel_cache_view(race_detector):
     res, tr = log.prov_query("B", "A", np.array([[2, 2]]), trace=True)
     assert res.cell_set() == {(2, 2)}
     kinds = tr.kinds()
-    for kind in ("query", "cache", "plan", "view", "execute", "kernel", "hop"):
+    for kind in ("query", "cache", "plan", "view", "execute", "kernel", "hop",
+                 "boxes_in", "wave", "prepare", "finalize", "merge"):
         assert kind in kinds, f"missing span kind {kind!r} in {sorted(kinds)}"
     hop = tr.spans(kind="hop")[0]
     assert hop.attrs["u"] == "B" and hop.attrs["v"] == "A"
     assert hop.attrs["qrows"] >= 1 and hop.attrs["pairs"] >= 1
-    # the root aggregates instrument deltas from the whole query
+    # the root spans the whole query; the stages carry their own counts
     root = tr.root
     assert root.duration > 0
+    assert tr.spans(kind="boxes_in")[0].attrs == {"queries": 1, "rows": 1}
+    wave = tr.spans(kind="wave")[0]
+    assert wave.attrs == {"requests": 1, "rows": 1}
+    assert {m.attrs["at"] for m in tr.spans(kind="merge")} == {
+        "init", "hop", "canonical"}
     rendered = tr.render()
     assert "plan" in rendered and "hop" in rendered
 
@@ -216,6 +227,157 @@ def test_maybe_span_null_path_and_real_path():
         sp.attrs["est"] = 4
     tr.finish()
     assert tr.spans(kind="plan")[0].attrs["est"] == 4
+
+
+def _dense_engine(log, engine: str):
+    """Pin the store's dense joins to one engine: ``"kernel"`` runs the
+    Pallas kernels (interpreted on the CPU), as a chip runs them."""
+    log.planner._executor = BatchedJoinExecutor(
+        stats=log._bump,
+        tuner=log.autotune,
+        metrics=log.metrics,
+        trace_source=log.planner._trace,
+        engine=engine,
+    )
+    return log
+
+
+@pytest.mark.parametrize("engine,stages", [
+    ("kernel", ["pack", "dispatch", "readback", "pair_extract", "finalize"]),
+    ("twin", ["finalize"]),
+])
+def test_trace_dense_join_stages_nest_in_the_launch(engine, stages, race_detector):
+    log = _dense_engine(_one_hop(DSLog()), engine)
+    res, tr = log.prov_query("B", "A", np.array([[2, 2], [5, 6]]), trace=True)
+    assert res.cell_set() == {(2, 2), (5, 6)}
+    (launch,) = tr.spans(kind="kernel")
+    assert launch.name == ("kernel_launch" if engine == "kernel" else "twin")
+    # the launch still runs from the first stage (pack) to the last finalize
+    assert [c.kind for c in launch.children] == stages
+    end = launch.start + launch.duration
+    for child in launch.children:
+        assert launch.start <= child.start
+        assert child.start + child.duration <= end
+    assert sum(c.duration for c in launch.children) <= launch.duration
+    assert launch.attrs["pairs"] == 2
+    if engine == "kernel":
+        pack, dispatch, readback, extract, _ = launch.children
+        assert dispatch.attrs["h2d_bytes"] == launch.attrs["h2d_bytes"] > 0
+        assert readback.attrs["d2h_bytes"] == launch.attrs["d2h_bytes"] > 0
+        assert extract.attrs["pairs"] == 2
+
+
+@pytest.mark.parametrize("batched", [True, False], ids=["wave", "per_hop"])
+def test_trace_hop_record_per_engine(batched, race_detector):
+    """The per-hop loop times each join as a scoped ``hop`` span; batched
+    engines join a wave at once and record the hop as an instant."""
+    log = _one_hop(DSLog())
+    _, tr = log.prov_query("B", "A", np.array([[2, 2]]), batched=batched, trace=True)
+    (hop,) = tr.spans(kind="hop")
+    assert hop.attrs["u"] == "B" and hop.attrs["pairs"] >= 1
+    assert (hop.duration is None) is batched
+    assert ("wave" in tr.kinds()) is batched
+
+
+def test_trace_index_route_records_the_probe(race_detector):
+    """A sort hop scatters the frontier: the planner routes it through the
+    table's interval index, which the ``index_probe`` span times."""
+    side = 64
+    log = DSLog()
+    log.define_array("A", (side, side))
+    log.define_array("B", (side, side))
+    values = np.random.default_rng(0).random((side, side))
+    log.add_lineage("A", "B", sort_lineage(values))
+    cells = np.stack([np.arange(0, side, 2), np.arange(0, side, 2)[::-1]], 1)
+    res, tr = log.prov_query("B", "A", cells, trace=True)
+    assert res.n_cells() == len(cells)
+    (wave,) = tr.spans(kind="wave")
+    assert [c.kind for c in wave.children] == ["prepare", "index_probe", "finalize"]
+    assert wave.children[1].attrs["pairs"] >= len(cells)
+    assert "kernel" not in tr.kinds()
+
+
+def test_kernel_dispatch_counters_on_a_two_segment_join():
+    """``h2d_bytes``, ``d2h_bytes`` and ``join_pairs`` against a hand count:
+    two small segments share one masked launch, each side packed as
+    ``[rows, 128]`` int32 and the mask read back as int32 per (q, r)."""
+    reg = MetricsRegistry("t")
+    ex = BatchedJoinExecutor(stats=lambda k, n=1: reg.inc(k, n), engine="kernel")
+    tables = [compress(roll_lineage(SHAPE, 3, 0), "backward"),
+              compress(transpose_lineage(SHAPE, (1, 0)), "backward")]
+    queries = [QueryBox.from_cells(SHAPE, np.array([[0, 0], [6, 6], [3, 1]])),
+               QueryBox.from_cells(SHAPE, np.array([[1, 2], [7, 7]]))]
+    ex.run([JoinRequest([q], t, merge=False, path="batched")
+            for q, t in zip(queries, tables)])
+    nq = sum(q.n_rows for q in queries)
+    nr = sum(t.n_rows for t in tables)
+    pairs = sum(
+        int(np.all((q.lo[:, None] <= t.key_hi[None])
+                   & (t.key_lo[None] <= q.hi[:, None]), axis=2).sum())
+        for q, t in zip(queries, tables)
+    )
+    assert reg.counter_value("kernel_launches") == 1
+    assert reg.counter_value("h2d_bytes") == (nq + nr) * 128 * 4
+    assert reg.counter_value("d2h_bytes") == nq * nr * 4
+    assert reg.counter_value("join_pairs") == pairs == 5
+
+
+def test_tracing_off_enters_no_annotation(monkeypatch, tmp_path):
+    """Untraced queries and ingest enter no profiler annotation; traced
+    ones annotate every span as ``dslog.<name>``."""
+    import jax.profiler
+
+    entered = []
+
+    class Counting:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    with DSLog.open(str(tmp_path / "store"), durability="manual") as log:
+        _dense_engine(log, "kernel")
+        for name in ("A", "B", "C"):
+            log.define_array(name, SHAPE)
+        log.add_lineage("A", "B", identity_lineage(SHAPE))
+        log.commit()
+        log.prov_query("B", "A", np.array([[2, 2]]))
+        assert entered == []
+        with log.trace_scope("ingest"):
+            log.add_lineage("B", "C", flip_lineage(SHAPE, 0))
+            log.commit()
+        log.prov_query("C", "A", np.array([[2, 2]]), trace=True)
+    for name in ("ingest", "canonical", "fsync", "query", "pack", "readback"):
+        assert f"dslog.{name}" in entered
+
+
+def test_trace_scope_records_the_ingest_stages(tmp_path):
+    with DSLog.open(str(tmp_path / "store"), durability="manual") as log:
+        log.define_array("A", SHAPE)
+        log.define_array("B", SHAPE)
+        with log.trace_scope("ingest") as tr:
+            assert log._active_trace is tr
+            log.add_lineage("A", "B", roll_lineage(SHAPE, 3, 0))
+            log.commit()
+        assert log._active_trace is None
+    assert tr.root.kind == "ingest" and tr.root.duration > 0
+    assert {"canonical", "provrc.step1", "provrc.step2", "wal.record",
+            "wal.append", "commit", "fsync"} <= tr.kinds()
+    for kind in ("canonical", "provrc.step1", "provrc.step2"):
+        spans = tr.spans(kind)
+        assert sorted(s.attrs["direction"] for s in spans) == ["backward", "forward"]
+        assert all(s.attrs["rows_in"] >= s.attrs["rows_out"] > 0 for s in spans)
+    assert tr.spans("canonical")[0].attrs["rows_in"] == SIDE * SIDE
+    (record,) = tr.spans("wal.record")
+    assert record.attrs["bytes"] > 0
+    (commit,) = tr.spans("commit")
+    assert [c.kind for c in commit.children] == ["fsync"]
 
 
 # --------------------------------------------------------------------------- #
