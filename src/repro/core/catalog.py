@@ -57,7 +57,7 @@ from .commit import CommitPipeline, WriterLease
 from .graph import CycleError, LineageGraph
 from .index import IntervalIndex
 from .planner import QueryPlanner
-from .provrc import compress
+from .provrc import compress, compress_both
 from .query import QueryBox
 from .relation import LineageRelation
 from .reuse import (
@@ -846,12 +846,11 @@ class DSLog:
             bwd, fwd = tables
         else:
             tr = self._active_trace
-            bwd = compress(relation, "backward", self.compress_method, trace=tr)
-            fwd = (
-                compress(relation, "forward", self.compress_method, trace=tr)
-                if self.store_forward
-                else None
-            )
+            if self.store_forward:
+                bwd, fwd = compress_both(relation, self.compress_method, trace=tr)
+            else:
+                bwd = compress(relation, "backward", self.compress_method, trace=tr)
+                fwd = None
         return self._insert_entry(src, dst, bwd, fwd, op_name, reused_from)
 
     def _insert_entry(

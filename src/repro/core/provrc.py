@@ -68,14 +68,54 @@ def compress(
 
     ``trace`` (a :class:`~repro.obs.trace.QueryTrace`, or None) records
     ``canonical``, ``provrc.step1`` and ``provrc.step2`` spans, each with
-    the direction and its rows in and out.
+    the direction and its rows in and out; ``canonical`` also holds the
+    ``route`` its dedup took (:meth:`LineageRelation.canonical_route`).
     """
+    rel = _canonical(rel, direction, trace)
+    return _encode(rel, direction, method, stats, trace)
+
+
+def compress_both(
+    rel: LineageRelation, method: str = "auto", trace=None
+) -> tuple[CompressedTable, CompressedTable]:
+    """Backward + forward materializations (paper §IV.C).
+
+    The relation is deduplicated once; the forward direction's
+    ``canonical`` span reads ``route="reused"``.
+    """
+    canon = _canonical(rel, "backward", trace)
+    bwd = _encode(canon, "backward", method, None, trace)
+    canon = _canonical(rel, "forward", trace, reuse=canon)
+    return bwd, _encode(canon, "forward", method, None, trace)
+
+
+def _canonical(
+    rel: LineageRelation,
+    direction: str,
+    trace,
+    reuse: LineageRelation | None = None,
+) -> LineageRelation:
+    """``rel.canonical()`` inside a ``canonical`` span; ``reuse`` is that
+    result already computed by this call for the other direction."""
     with maybe_span(
         trace, "canonical", kind="canonical", direction=direction
     ) as sp:
-        rows_in = rel.out_idx.shape[0]
-        rel = rel.canonical()
-        sp.attrs.update(rows_in=rows_in, rows_out=rel.out_idx.shape[0])
+        if reuse is None:
+            canon, route = rel.canonical_route()
+        else:
+            canon, route = reuse, "reused"
+        sp.attrs.update(route=route, rows_in=rel.n_rows, rows_out=canon.n_rows)
+    return canon
+
+
+def _encode(
+    rel: LineageRelation,
+    direction: str,
+    method: str,
+    stats: CompressStats | None,
+    trace,
+) -> CompressedTable:
+    """ProvRC steps 1 and 2 over an already canonical relation."""
     if direction == "backward":
         keys, vals = rel.out_idx, rel.in_idx
         key_shape, val_shape = rel.out_shape, rel.in_shape
@@ -135,16 +175,6 @@ def compress(
 
     return CompressedTable(
         key_shape, val_shape, key_lo, key_hi, val_lo, val_hi, val_ref, direction
-    )
-
-
-def compress_both(
-    rel: LineageRelation, method: str = "auto"
-) -> tuple[CompressedTable, CompressedTable]:
-    """Backward + forward materializations (paper §IV.C)."""
-    return (
-        compress(rel, "backward", method),
-        compress(rel, "forward", method),
     )
 
 

@@ -61,7 +61,7 @@ from repro.kernels.autotune import (  # jax-free: geometry table + buckets
 from repro.obs.trace import maybe_span
 
 from .index import IntervalIndex, ragged_ranges
-from .intervals import coalesce_1d, lexsort_rows
+from .intervals import coalesce_1d, lexsort_rows, unique_rows
 from .provrc import _group_ids
 from .table import CompressedTable
 
@@ -425,32 +425,6 @@ def theta_join_inverse(
 # --------------------------------------------------------------------------- #
 # Batched multi-query θ-join
 # --------------------------------------------------------------------------- #
-def _unique_rows(
-    a: np.ndarray, return_inverse: bool = False
-) -> "np.ndarray | tuple[np.ndarray, np.ndarray]":
-    """``np.unique(a, axis=0[, return_inverse])`` for 2-D integer arrays.
-
-    Bit-identical output (same lexicographic row order, same inverse), but
-    via ``lexsort`` over the integer columns — ``np.unique(axis=0)`` pays
-    ~4x more for its void-dtype view sort, and these row dedups run on
-    every hop of every query.
-    """
-    n = a.shape[0]
-    if n == 0:
-        return (a, np.zeros(0, np.int64)) if return_inverse else a
-    order = np.lexsort(a.T[::-1])  # first column most significant
-    s = a[order]
-    flag = np.empty(n, bool)
-    flag[0] = True
-    np.any(s[1:] != s[:-1], axis=1, out=flag[1:])
-    uniq = s[flag]
-    if not return_inverse:
-        return uniq
-    inv = np.empty(n, np.int64)
-    inv[order] = np.cumsum(flag) - 1
-    return uniq, inv
-
-
 def _pool_boxes(
     queries: Sequence[QueryBox],
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -458,7 +432,7 @@ def _pool_boxes(
     maps each original row (queries concatenated) to its distinct box."""
     all_lo = np.concatenate([q.lo for q in queries], axis=0)
     all_hi = np.concatenate([q.hi for q in queries], axis=0)
-    uniq, inv = _unique_rows(
+    uniq, inv = unique_rows(
         np.concatenate([all_lo, all_hi], axis=1), return_inverse=True
     )
     nd = all_lo.shape[1]
@@ -1125,7 +1099,7 @@ def merge_boxes(q: QueryBox) -> QueryBox:
         return q
     # exact duplicate removal first
     both = np.concatenate([lo, hi], axis=1)
-    both = _unique_rows(both)
+    both = unique_rows(both)
     nd = len(q.shape)
     lo, hi = both[:, :nd], both[:, nd:]
     changed = True

@@ -9,9 +9,12 @@ correctness proof go through.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 
 import numpy as np
+
+from .intervals import unique_rows
 
 __all__ = ["LineageRelation", "axis_names"]
 
@@ -33,12 +36,8 @@ class LineageRelation:
     in_attrs: tuple[str, ...] = ()
 
     def __post_init__(self) -> None:
-        self.out_idx = np.asarray(self.out_idx, dtype=np.int64).reshape(
-            -1, len(self.out_shape)
-        )
-        self.in_idx = np.asarray(self.in_idx, dtype=np.int64).reshape(
-            -1, len(self.in_shape)
-        )
+        self.out_idx = _as_rows(self.out_idx, len(self.out_shape))
+        self.in_idx = _as_rows(self.in_idx, len(self.in_shape))
         if self.out_idx.shape[0] != self.in_idx.shape[0]:
             raise ValueError("out_idx and in_idx row counts differ")
         if not self.out_attrs:
@@ -69,18 +68,34 @@ class LineageRelation:
 
     # ------------------------------------------------------------------ #
     def canonical(self) -> "LineageRelation":
-        """Sorted + deduplicated copy (set semantics)."""
-        rows = self.rows()
-        rows = np.unique(rows, axis=0)
-        l = self.ndim_out
-        return LineageRelation(
+        """Sorted + deduplicated copy (set semantics): the rows of
+        ``np.unique(self.rows(), axis=0)``, byte for byte."""
+        return self.canonical_route()[0]
+
+    def canonical_route(self) -> tuple["LineageRelation", str]:
+        """:meth:`canonical` and the route the dedup took.
+
+        Each row is packed into one int64 key, the C-order ravel of
+        ``(out..., in...)`` over ``out_shape + in_shape``, whose order is
+        the rows' lexicographic order.  ``presorted``: the keys were already
+        strictly increasing (capture emits rows in output order), so the
+        rows are copied as they are.  ``packed``: the unique keys are
+        unravelled back into rows.  ``lexsort``: an index outside its dim,
+        or a shape of 2**63 cells or more, leaves no key; the rows are
+        deduplicated by a lexsort of their columns instead.
+        """
+        out_idx, in_idx, route = _canonical_rows(
+            self.out_idx, self.in_idx, self.out_shape + self.in_shape
+        )
+        rel = LineageRelation(
             self.out_shape,
             self.in_shape,
-            rows[:, :l],
-            rows[:, l:],
+            out_idx,
+            in_idx,
             self.out_attrs,
             self.in_attrs,
         )
+        return rel, route
 
     def as_set(self) -> set[tuple[int, ...]]:
         return {tuple(int(v) for v in row) for row in self.rows()}
@@ -90,9 +105,11 @@ class LineageRelation:
             return NotImplemented
         if self.out_shape != other.out_shape or self.in_shape != other.in_shape:
             return False
-        a = np.unique(self.rows(), axis=0)
-        b = np.unique(other.rows(), axis=0)
-        return a.shape == b.shape and bool(np.array_equal(a, b))
+        a, b = self.canonical(), other.canonical()
+        return bool(
+            np.array_equal(a.out_idx, b.out_idx)
+            and np.array_equal(a.in_idx, b.in_idx)
+        )
 
     # ------------------------------------------------------------------ #
     @staticmethod
@@ -128,3 +145,42 @@ class LineageRelation:
             np.unravel_index(np.asarray(in_flat, dtype=np.int64), in_shape), axis=1
         )
         return LineageRelation(out_shape, in_shape, out_idx, in_idx)
+
+
+def _as_rows(idx, ndim: int) -> np.ndarray:
+    """``idx`` as int64 ``[N, ndim]``; an ``[N, 0]`` array (a 0-d side)
+    keeps its N, which no reshape can infer."""
+    idx = np.asarray(idx, dtype=np.int64)
+    if idx.ndim == 2 and idx.shape[1] == ndim:
+        return idx
+    return idx.reshape(-1, ndim)
+
+
+def _canonical_rows(
+    out_idx: np.ndarray, in_idx: np.ndarray, dims: tuple[int, ...]
+) -> tuple[np.ndarray, np.ndarray, str]:
+    """``(out, in, route)``: the rows of ``[out_idx | in_idx]`` sorted and
+    deduplicated as ``np.unique(axis=0)`` gives them (see
+    :meth:`LineageRelation.canonical_route`)."""
+    n, l = out_idx.shape
+    cols = [out_idx[:, j] for j in range(l)]
+    cols += [in_idx[:, j] for j in range(in_idx.shape[1])]
+    if n <= 1:
+        return out_idx.copy(), in_idx.copy(), "presorted"
+    if not cols:  # 0-d on both sides: every row is the one empty row
+        return out_idx[:1].copy(), in_idx[:1].copy(), "packed"
+    packable = math.prod(int(d) for d in dims) < 2**63 and all(
+        c.min() >= 0 and c.max() < d for c, d in zip(cols, dims)
+    )
+    if not packable:
+        rows = unique_rows(np.concatenate([out_idx, in_idx], axis=1))
+        return rows[:, :l], rows[:, l:], "lexsort"
+    # mixed-radix ravel: in bounds, so no step leaves [0, prod(dims))
+    key = cols[0].copy()
+    for c, d in zip(cols[1:], dims[1:]):
+        key *= d
+        key += c
+    if bool(np.all(key[1:] > key[:-1])):
+        return out_idx.copy(), in_idx.copy(), "presorted"
+    rows = np.stack(np.unravel_index(np.unique(key), dims), axis=1)
+    return rows[:, :l], rows[:, l:], "packed"

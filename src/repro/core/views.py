@@ -27,7 +27,8 @@ from __future__ import annotations
 import numpy as np
 
 from . import _locks
-from .query import QueryBox, _route_pairs, _unique_rows, merge_boxes
+from .intervals import unique_rows
+from .query import QueryBox, _route_pairs, merge_boxes
 from .table import CompressedTable, TableHandle
 
 __all__ = [
@@ -188,7 +189,7 @@ def compose_tables(
         [kl[valid], kh[valid], out_lo[valid], out_hi[valid], out_ref[valid]],
         axis=1,
     )
-    packed = _unique_rows(packed)
+    packed = unique_rows(packed)
     if max_rows is not None and packed.shape[0] > max_rows:
         raise CompositionError(
             f"composed relation has {packed.shape[0]} rows > budget {max_rows}"
@@ -247,7 +248,7 @@ def _concat_tables(tables: list[CompressedTable]) -> CompressedTable:
 def _dedup_table(t: CompressedTable) -> CompressedTable:
     if t.n_rows <= 1:
         return t
-    packed = _unique_rows(
+    packed = unique_rows(
         np.concatenate(
             [t.key_lo, t.key_hi, t.val_lo, t.val_hi,
              np.asarray(t.val_ref, np.int64)],
