@@ -38,6 +38,15 @@ def steady_allocator() -> None:
     mallopt(-1, 64 << 20)  # M_TRIM_THRESHOLD
 
 
+def add_span_seconds(into: dict, tr) -> dict:
+    """Add the seconds of every timed span of the program trace ``tr`` to
+    ``into`` by span kind, the root (``query``, ``ingest``) included."""
+    for s in tr.spans():
+        if s.kind and s.duration is not None:
+            into[s.kind] = into.get(s.kind, 0.0) + s.duration
+    return into
+
+
 @dataclass
 class RunContext:
     """Everything a metric reader may read about one run."""
@@ -48,11 +57,28 @@ class RunContext:
     window_programs: int = 0
     latencies_s: list = field(default_factory=list)
     counters: dict = field(default_factory=dict)
-    spans: list = field(default_factory=list)  # per query: {"plan": s, "join": s}
+    spans: list = field(default_factory=list)  # per traced query: {span kind: s}
     device: object = None  # devtrace.Reduced of the traced window
-    ingest: dict | None = None  # {"rows", "add_s", "commit_s"}
+    # {"rows", "add_s", "commit_s"}, traced also {"spans": {kind: s},
+    # "canonical_routes": {route: n}}
+    ingest: dict | None = None
     raw_bytes: int = 0
     stored_bytes: int = 0
+
+    def span_ms(self, kind: str) -> float | None:
+        """Mean milliseconds per traced query in spans of ``kind``; None
+        where no query's trace holds one."""
+        if not any(kind in q for q in self.spans):
+            return None
+        return 1e3 * sum(q.get(kind, 0.0) for q in self.spans) / len(self.spans)
+
+    def ingest_share_pct(self, *kinds: str) -> float | None:
+        """Percent of the ingest window spent in program spans of ``kinds``;
+        None where the traced hops hold none of them."""
+        spans = (self.ingest or {}).get("spans", {})
+        if self.window_s <= 0 or not any(k in spans for k in kinds):
+            return None
+        return 100.0 * sum(spans.get(k, 0.0) for k in kinds) / self.window_s
 
 
 class CompileMeter:
@@ -158,7 +184,7 @@ class Run:
             return
         from .devtrace import find_xplane, reduce_file
 
-        self.ctx.device = reduce_file(find_xplane(self.trace_dir))
+        self.ctx.device = reduce_file(find_xplane(self.trace_dir), by_span=True)
         store.remove(self.trace_dir)
 
     def cleanup(self) -> None:

@@ -19,7 +19,7 @@ import traceback
 import numpy as np
 
 from .. import oracle, store
-from ..context import memory_peak, note
+from ..context import add_span_seconds, memory_peak, note
 from ..mix import QueryStream
 
 
@@ -34,12 +34,6 @@ def _open_resident(root: str):
         e.backward
         e.forward
     return log
-
-
-def _span_times(tr) -> dict:
-    plan = sum(s.duration or 0.0 for s in tr.spans("plan"))
-    join = sum(s.duration or 0.0 for s in tr.spans("kernel"))
-    return {"plan": plan, "join": join}
 
 
 def run(r) -> tuple:
@@ -93,7 +87,7 @@ def run(r) -> tuple:
             res, tr = out if r.args.trace and out is not None else (out, None)
             answers.append(None if res is None else (res.lo, res.hi, res.shape))
             if tr is not None:
-                ctx.spans.append(_span_times(tr))
+                ctx.spans.append(add_span_seconds({}, tr))
     r.close_window()
     ctx.counters = {k: log.io_stats[k] - base.get(k, 0) for k in log.io_stats}
     mem = memory_peak()

@@ -13,6 +13,7 @@ preceded by an ``fsync`` since the one before.
 
 from __future__ import annotations
 
+import contextlib
 import gc
 import os
 import shutil
@@ -24,7 +25,7 @@ import traceback
 import numpy as np
 
 from .. import oracle, store
-from ..context import memory_peak, note
+from ..context import add_span_seconds, memory_peak, note
 from ..workflows import pipeline_hops
 
 
@@ -57,6 +58,15 @@ class FsyncCounter:
 
     def __exit__(self, *exc):
         os.fsync = self._real
+
+
+def _add_spans(spans: dict, routes: dict, tr) -> None:
+    """Add one hop's program trace: seconds by span kind, and ``canonical``
+    spans counted by route."""
+    add_span_seconds(spans, tr)
+    for s in tr.spans("canonical"):
+        route = s.attrs.get("route")
+        routes[route] = routes.get(route, 0) + 1
 
 
 def _query(log, src, dst, cells):
@@ -110,6 +120,11 @@ def run(r) -> tuple:
     rows = 0
     add_s = commit_s = 0.0
     unsynced = 0  # commits with no fsync since the one before
+    # traced, each hop runs in a program trace of its own; untraced, in none
+    scope = ((lambda: log.trace_scope("ingest")) if r.args.trace
+             else contextlib.nullcontext)
+    spans: dict = {}  # span kind -> seconds, over the window's hops
+    routes: dict = {}  # canonical route -> spans
     with FsyncCounter() as fsyncs:
         t_start = r.open_window()
         with jax.profiler.TraceAnnotation("bench.window"):
@@ -123,14 +138,17 @@ def run(r) -> tuple:
                         if name not in log.arrays:
                             log.define_array(name, shape)
                     synced = fsyncs.n
-                    with jax.profiler.TraceAnnotation("bench.add_lineage"):
-                        t = time.perf_counter()
-                        log.add_lineage(src, dst, rel, op_name=hop.op)
-                        add_s += time.perf_counter() - t
-                    with jax.profiler.TraceAnnotation("bench.commit"):
-                        t = time.perf_counter()
-                        log.commit()
-                        commit_s += time.perf_counter() - t
+                    with scope() as tr:
+                        with jax.profiler.TraceAnnotation("bench.add_lineage"):
+                            t = time.perf_counter()
+                            log.add_lineage(src, dst, rel, op_name=hop.op)
+                            add_s += time.perf_counter() - t
+                        with jax.profiler.TraceAnnotation("bench.commit"):
+                            t = time.perf_counter()
+                            log.commit()
+                            commit_s += time.perf_counter() - t
+                    if tr is not None:
+                        _add_spans(spans, routes, tr)
                     unsynced += fsyncs.n == synced
                     rows += hop.n_rows
                     committed.append((rnd, j))
@@ -139,6 +157,8 @@ def run(r) -> tuple:
                 got = _query(log, f"r0_{first.dst}", f"r0_{first.src}", rb_cells)
         r.close_window()
     ctx.ingest = {"rows": rows, "add_s": add_s, "commit_s": commit_s}
+    if r.args.trace:
+        ctx.ingest.update(spans=spans, canonical_routes=routes)
     mem = memory_peak()
 
     # what a crash right after the last acknowledged commit() leaves on disk:

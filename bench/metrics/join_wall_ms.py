@@ -8,4 +8,4 @@ pack, upload, kernel, readback and pair extraction, not kernel time.
 def read(ctx):
     if not ctx.spans:
         return None
-    return 1e3 * sum(s["join"] for s in ctx.spans) / len(ctx.spans)
+    return 1e3 * sum(s.get("kernel", 0.0) for s in ctx.spans) / len(ctx.spans)

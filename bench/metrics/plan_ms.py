@@ -4,4 +4,4 @@
 def read(ctx):
     if not ctx.spans:
         return None
-    return 1e3 * sum(s["plan"] for s in ctx.spans) / len(ctx.spans)
+    return 1e3 * sum(s.get("plan", 0.0) for s in ctx.spans) / len(ctx.spans)

@@ -15,7 +15,12 @@ import pytest
 
 from bench import run, store, workflows
 
-SMALL_SIDE = {"fig89_numpy": 32, "fig89_conv": 48}
+
+def declared_test_side(cfg: dict) -> int:
+    """The side a configuration declares for CPU tests (``test_side``)."""
+    if "test_side" not in cfg:
+        raise AssertionError(f"config {cfg['name']} declares no test_side")
+    return int(cfg["test_side"])
 
 
 class FakeTPU:
@@ -27,6 +32,8 @@ class FakeTPU:
 def small_bench(tmp_path, monkeypatch):
     """Shrunk copies of the configurations, stores under tmp_path.
 
+    Each configuration runs at the side it declares as ``test_side``.
+
     Returns ``run_cell(workload, seed, seconds=0.5, trace=0)``, which gives
     ``(exit code, result dict or None, stderr text)``.
     """
@@ -35,7 +42,7 @@ def small_bench(tmp_path, monkeypatch):
     for name in os.listdir(workflows.CONFIG_DIR):
         with open(os.path.join(workflows.CONFIG_DIR, name)) as f:
             cfg = json.load(f)
-        cfg["side"] = SMALL_SIDE[cfg["name"]]
+        cfg["side"] = declared_test_side(cfg)
         (cfg_dir / name).write_text(json.dumps(cfg))
     monkeypatch.setattr(workflows, "CONFIG_DIR", str(cfg_dir))
     monkeypatch.setattr(store, "STORES", str(tmp_path / "stores"))

@@ -1,8 +1,13 @@
-"""The harness refuses to run without a chip."""
+"""The harness refuses to run without a chip, and finds a cell's files by name."""
 
 import json
+import os
+import shutil
 
-from bench import run
+import pytest
+
+from bench import run, store, workflows
+from bench.tests.conftest import declared_test_side
 
 
 def test_refuses_without_a_tpu(capsys, monkeypatch, tmp_path):
@@ -17,8 +22,6 @@ def test_refuses_without_a_tpu(capsys, monkeypatch, tmp_path):
 
 
 def test_every_cell_names_files_that_exist():
-    import os
-
     with open(run.BENCHMARK_FILE) as f:
         bench = json.load(f)
     from bench import layouts, loops, mix, ops, workflows
@@ -39,8 +42,6 @@ def test_every_cell_names_files_that_exist():
 def test_refuses_without_the_program(tmp_path):
     """A checkout that holds only BENCHMARK.json and bench/ exits non-zero
     and prints no result."""
-    import os
-    import shutil
     import subprocess
     import sys
 
@@ -54,3 +55,48 @@ def test_refuses_without_the_program(tmp_path):
         cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120)
     assert proc.returncode != 0
     assert not [ln for ln in proc.stdout.splitlines() if ln.startswith("{")]
+
+
+CONFIG_FILES = sorted(f for f in os.listdir(workflows.CONFIG_DIR) if f.endswith(".json"))
+
+
+@pytest.mark.parametrize("name", CONFIG_FILES)
+def test_every_config_declares_its_test_side(name):
+    """The CPU tests run each configuration at the side it declares."""
+    with open(os.path.join(workflows.CONFIG_DIR, name)) as f:
+        assert declared_test_side(json.load(f)) > 0
+
+
+def test_a_config_without_test_side_is_named():
+    with pytest.raises(AssertionError, match="config bare declares no test_side"):
+        declared_test_side({"name": "bare", "side": 1024})
+
+
+@pytest.fixture
+def one_more_config(tmp_path, monkeypatch):
+    """The committed configurations plus one new file, ``added``, and a
+    benchmark that gives it a cell: nothing else under ``bench/`` changes."""
+    src = tmp_path / "configs_committed"
+    shutil.copytree(workflows.CONFIG_DIR, src)
+    with open(src / "fig89_numpy.json") as f:
+        cfg = json.load(f)
+    cfg.update(name="added", test_side=24, pipelines=cfg["pipelines"][:1])
+    (src / "added.json").write_text(json.dumps(cfg))
+    monkeypatch.setattr(workflows, "CONFIG_DIR", str(src))
+    with open(run.BENCHMARK_FILE) as f:
+        bench = json.load(f)
+    bench["configs"].append({"name": "added", "source": cfg["source"],
+                             "file": "bench/configs/added.json", "reduced": [],
+                             "why": "a configuration added as a file alone"})
+    bench["workloads"].append({"name": "added.fig89_forward", "config": "added",
+                               "traffic": "fig89_forward", "chips": 1,
+                               "why": "the Figs 8/9 mix on the added configuration"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    monkeypatch.setattr(run, "BENCHMARK_FILE", str(tmp_path / "BENCHMARK.json"))
+
+
+def test_a_new_config_file_runs_with_no_other_edit(one_more_config, small_bench, capsys):
+    rc, res, _ = small_bench("added.fig89_forward", 2**31 + 21, capsys=capsys)
+    assert rc == 0 and res["correct"], res and res["checks"]
+    assert workflows.load_config("added")["side"] == 24
+    assert [n for n in os.listdir(store.STORES) if n.startswith("added-")]
