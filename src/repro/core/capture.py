@@ -44,6 +44,7 @@ __all__ = [
     "take_lineage",
     "conv1d_lineage",
     "conv2d_lineage",
+    "upsample2d_lineage",
     "cumulative_lineage",
     "triangular_lineage",
     "sort_lineage",
@@ -266,6 +267,14 @@ def conv2d_lineage(h: int, w: int, kh: int, kw: int, stride: int = 1) -> Lineage
         [grid[:, 0] * stride + grid[:, 2], grid[:, 1] * stride + grid[:, 3]], axis=1
     )
     return LineageRelation((h_out, w_out), (h, w), out, inn)
+
+
+def upsample2d_lineage(h: int, w: int, s: int) -> LineageRelation:
+    """2-D up-convolution with kernel and stride ``s`` (U-Net's up-conv):
+    each output cell reads the one input cell under it,
+    ``out[i, j] <- in[i // s, j // s]``."""
+    out = all_indices((h * s, w * s))
+    return LineageRelation((h * s, w * s), (h, w), out, out // s)
 
 
 def cumulative_lineage(n: int) -> LineageRelation:

@@ -104,6 +104,13 @@ SEED_COUNTERS = (
     # cross-product tiles the block-diagonal layout skipped
     "batch_tiles_visited",
     "batch_tiles_skipped",
+    # what launching on ladder shapes costs: rows padded past the frontier
+    # to reach the launch's shape, the mask cells the kernels wrote, and
+    # launches on an unseen shape bucket that borrowed the geometry of the
+    # nearest tuned one instead of measuring candidates
+    "ladder_pad_rows",
+    "mask_cells_written",
+    "tuner_fallbacks",
     # bytes those kernel dispatches moved (packed operands and tile
     # schedules up, masks or tile stacks back) and the pairs the dense
     # joins emitted, kernel and twin alike

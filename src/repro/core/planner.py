@@ -839,7 +839,9 @@ class QueryPlanner:
                 acc_hi[k].append(res.hi)
         boxes = []
         tr = self._trace()
-        with maybe_span(tr, "merge", kind="merge", at="hop") as sp:
+        # a node that branches converge on merges them in a span of its own
+        kind = "fanin_merge" if len(plan.steps.get(key, [])) >= 2 else "merge"
+        with maybe_span(tr, kind, kind=kind, at="hop") as sp:
             for k in range(nB):
                 lo = (
                     np.concatenate(acc_lo[k])

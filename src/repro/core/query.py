@@ -895,35 +895,46 @@ class BatchedJoinExecutor:
                 geom = self._tuner.lookup(backend, bucket)
                 result = None
                 if geom is None:
-                    if sum(nq + nr for nq, nr, _ in shapes) >= _TUNE_MIN_ROWS:
-                        # first big frontier of this shape: measure the
-                        # candidates on it (the winner's run is kept, so the
-                        # tuning dispatch does the real work) and persist the
-                        # geometry via the store's autotune table
-                        geom, result = self._tuner.pick(
-                            backend,
-                            bucket,
-                            runner=lambda g: segmented_range_join_pairs(
-                                segs, block_q=g[0], block_r=g[1],
-                                interpret=interpret, trace=tr,
-                            ),
-                        )
-                        if self._metrics is not None:
-                            self._metrics.inc(
-                                "autotune_decisions",
-                                backend=backend,
-                                bucket=str(bucket),
-                            )
-                    else:
+                    if sum(nq + nr for nq, nr, _ in shapes) < _TUNE_MIN_ROWS:
                         geom = DEFAULT_GEOMETRY
+                    else:
+                        # a frontier a few ladder steps from a tuned one
+                        # borrows its geometry: measuring builds a program
+                        # per candidate, which a frontier that wanders from
+                        # query to query would otherwise do mid-traffic
+                        geom = self._tuner.nearest(backend, bucket)
+                        if geom is not None:
+                            self._stats("tuner_fallbacks")
+                if geom is None:
+                    # first big frontier of its kind: measure the candidates
+                    # on it, each on exact shapes (a ladder launch may run
+                    # another geometry's program), keep the winner's run, so
+                    # the tuning dispatch does the real work, and persist
+                    # the geometry via the store's autotune table
+                    geom, result = self._tuner.pick(
+                        backend,
+                        bucket,
+                        runner=lambda g: segmented_range_join_pairs(
+                            segs, block_q=g[0], block_r=g[1],
+                            interpret=interpret, trace=tr,
+                        ),
+                    )
+                    if self._metrics is not None:
+                        self._metrics.inc(
+                            "autotune_decisions",
+                            backend=backend,
+                            bucket=str(bucket),
+                        )
                 if result is None:
                     result = segmented_range_join_pairs(
                         segs, block_q=geom[0], block_r=geom[1],
-                        interpret=interpret, trace=tr,
+                        interpret=interpret, trace=tr, stable=True,
                     )
                 seg_pairs, info = result
                 for k, (ui, ri) in zip(kernel_idx, seg_pairs):
                     finalize(k, ui, ri)
+                # a ladder launch may have run another geometry's program
+                geom = info["geometry"]
                 self._last_geometry["kernel"] = tuple(geom)
                 self._stats("kernel_launches", info["launches"])
                 self._stats("joins_packed", len(kernel_idx))
@@ -931,6 +942,8 @@ class BatchedJoinExecutor:
                 self._stats("batch_rows_padded", info["rows_padded"])
                 self._stats("batch_tiles_visited", info["tiles_visited"])
                 self._stats("batch_tiles_skipped", info["tiles_skipped"])
+                self._stats("ladder_pad_rows", info["ladder_pad_rows"])
+                self._stats("mask_cells_written", info["mask_cells"])
                 self._stats("h2d_bytes", info["h2d_bytes"])
                 self._stats("d2h_bytes", info["d2h_bytes"])
                 self._stats("join_pairs", info["pairs"])

@@ -20,7 +20,9 @@ invalidates the cache when a store moves machines — a table tuned under
 
 from __future__ import annotations
 
+import bisect
 import math
+import re
 import threading
 import time
 from typing import Callable, Iterable, Sequence
@@ -28,6 +30,7 @@ from typing import Callable, Iterable, Sequence
 __all__ = [
     "GeometryTuner",
     "shape_bucket",
+    "ladder_step",
     "DEFAULT_GEOMETRY",
     "CANDIDATE_GEOMETRIES",
     "DEFAULT_TWIN_CELLS",
@@ -52,7 +55,41 @@ CANDIDATE_GEOMETRIES = (
 DEFAULT_TWIN_CELLS = (4_194_304,)
 CANDIDATE_TWIN_CELLS = ((1_048_576,), (4_194_304,), (16_777_216,))
 
-_TABLE_VERSION = 1
+_TABLE_VERSION = 2  # 2: query rows bucketed by ladder step, not by power of two
+
+# the launch ladder: every multiple of a unit up to LADDER_LINEAR units, then
+# each step LADDER_RATIO times the last, rounded down to a whole unit
+LADDER_LINEAR = 8
+LADDER_RATIO = 1.25
+
+
+def _ladder_units(limit: int = 1 << 31) -> tuple[int, ...]:
+    steps = list(range(1, LADDER_LINEAR + 1))
+    while steps[-1] < limit:
+        steps.append(int(steps[-1] * LADDER_RATIO))
+    return tuple(steps)
+
+
+_STEPS = _ladder_units()
+
+
+def _ladder_index(n: int, unit: int = 1) -> int:
+    """Position on the ladder of the smallest step holding ``n`` (0 for
+    ``n <= 0``, 1 for one unit)."""
+    return 0 if n <= 0 else bisect.bisect_left(_STEPS, -(-n // unit)) + 1
+
+
+def ladder_step(n: int, unit: int, up: int = 0) -> int:
+    """The smallest ladder step that holds ``n`` rows, moved ``up`` steps
+    (down where negative, never below one unit); 0 where ``n <= 0``.
+
+    Steps are multiples of ``unit`` (a block, or 1 for a tile count), so
+    padding ``n`` to its step adds less than a quarter.
+    """
+    if n <= 0:
+        return 0
+    i = _ladder_index(n, unit) - 1 + up
+    return _STEPS[min(max(i, 0), len(_STEPS) - 1)] * unit
 
 
 def _log2_bucket(n: int) -> int:
@@ -60,18 +97,24 @@ def _log2_bucket(n: int) -> int:
     return 0 if n <= 0 else int(math.log2(n)) + 1
 
 
+_BUCKET = re.compile(r"^k(\d+)q(\d+)r(\d+)w(\d+)$")
+# an unseen bucket borrows the geometry of a tuned one this many query-row
+# ladder steps away at most (same segment count, table rows and width)
+FALLBACK_STEPS = 4
+
+
 def shape_bucket(shapes: "Sequence[tuple[int, int, int]]") -> str:
     """Bucket key for a frontier's segment shapes.
 
-    ``shapes`` is ``[(n_query_rows, n_table_rows, n_attrs), ...]``.  Buckets
-    are deliberately coarse — pow-2 segment count, pow-2 *median* row counts,
-    exact max width — so a handful of tuning runs covers a workload's whole
-    steady state without ever re-measuring near-identical frontiers.
+    ``shapes`` is ``[(n_query_rows, n_table_rows, n_attrs), ...]``.  The
+    *median* query rows take their ladder step (:func:`ladder_step`, one
+    row a unit), the segment count and median table rows a pow-2 bucket,
+    the max width itself: ``k{k}q{step}r{r}w{width}``.
     """
     if not shapes:
         return "empty"
     k = _log2_bucket(len(shapes))
-    med_q = _log2_bucket(int(sorted(s[0] for s in shapes)[len(shapes) // 2]))
+    med_q = _ladder_index(int(sorted(s[0] for s in shapes)[len(shapes) // 2]))
     med_r = _log2_bucket(int(sorted(s[1] for s in shapes)[len(shapes) // 2]))
     width = max(s[2] for s in shapes)
     return f"k{k}q{med_q}r{med_r}w{width}"
@@ -120,6 +163,37 @@ class GeometryTuner:
             return None
         try:
             return tuple(int(x) for x in rec["geometry"])
+        except (KeyError, TypeError, ValueError):
+            return None
+
+    def nearest(
+        self, backend: str, bucket: str, steps: int = FALLBACK_STEPS
+    ) -> "tuple[int, ...] | None":
+        """The geometry of the tuned bucket nearest to an unseen ``bucket``:
+        same backend, segment count, table rows and width, query rows at
+        most ``steps`` ladder steps away (the larger on a tie); None where
+        there is none."""
+        m = _BUCKET.match(bucket)
+        if m is None:
+            return None
+        k, q, r, w = (int(x) for x in m.groups())
+        with self._lock:
+            recs = list(self._table.values())
+        best = None
+        for rec in recs:
+            other = _BUCKET.match(str(rec.get("bucket", "")))
+            if rec.get("backend") != backend or other is None:
+                continue
+            k2, q2, r2, w2 = (int(x) for x in other.groups())
+            if (k2, r2, w2) != (k, r, w) or abs(q2 - q) > steps:
+                continue
+            rank = (abs(q2 - q), -q2)
+            if best is None or rank < best[0]:
+                best = (rank, rec)
+        if best is None:
+            return None
+        try:
+            return tuple(int(x) for x in best[1]["geometry"])
         except (KeyError, TypeError, ValueError):
             return None
 
@@ -184,14 +258,15 @@ class GeometryTuner:
     def load_manifest(self, chunk: "dict | None") -> None:
         """Restore a persisted table, dropping anything malformed.
 
-        Tolerant by design (the sidecar may be torn or from a future
-        version): a bad chunk loads as a cold table, and entries whose
+        Tolerant by design (the sidecar may be torn or from another
+        version, whose buckets mean other shapes): a bad chunk loads as a
+        cold table, and entries whose
         recorded backend disagrees with their key are discarded — they
         could only mislead a lookup.
         """
         self._table.clear()
         self.dirty = False
-        if not isinstance(chunk, dict):
+        if not isinstance(chunk, dict) or chunk.get("version") != _TABLE_VERSION:
             return
         entries = chunk.get("entries")
         if not isinstance(entries, dict):

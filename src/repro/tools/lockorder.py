@@ -32,6 +32,9 @@ Rank order (outermost → innermost):
     runs *outside* it (runners execute real workloads that take stats
     locks); the lock only guards table reads/writes, so it is a leaf that
     callers holding any stats lock may still take.
+    ``ops._lock`` — the launch-program table of the range-join kernels
+    (``kernels/ops.py``); compiles run *outside* it, so it too is a leaf,
+    ranked beside the tuner's.
 10. ``metrics._lock`` — a ``MetricsRegistry``'s instrument table.  Every
     counter/histogram update may fire while any of the locks above is
     held (WAL appends, commit flushes, stats bookkeeping), so the
@@ -57,6 +60,7 @@ LOCK_ORDER: dict[str, int] = {
     "shard._stats_lock": 60,
     "catalog._stats_lock": 70,
     "autotune._lock": 75,
+    "ops._lock": 76,
     "metrics._lock": 80,
     "trace._lock": 90,
 }
@@ -72,6 +76,7 @@ LOCK_ROLES: dict[str, str] = {
     "shard._stats_lock": "`ShardedDSLog` I/O + hop-stats meters",
     "catalog._stats_lock": "`DSLog` I/O + hop-stats meters",
     "autotune._lock": "`GeometryTuner` winner table (measurement runs outside it)",
+    "ops._lock": "range-join launch-program table (compiles run outside it)",
     "metrics._lock": "a `MetricsRegistry`'s instrument table (leaf)",
     "trace._lock": "a `QueryTrace`'s span-attach lock (innermost)",
 }
@@ -92,6 +97,7 @@ STATIC_LOCKS: dict[tuple[str, str], str] = {
     ("commit", "_lock"): "commit._lock",
     ("commit", "_flush_mutex"): "commit._flush_mutex",
     ("autotune", "_lock"): "autotune._lock",
+    ("ops", "_lock"): "ops._lock",
     ("metrics", "_lock"): "metrics._lock",
     ("trace", "_lock"): "trace._lock",
 }

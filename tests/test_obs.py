@@ -297,10 +297,16 @@ def test_trace_index_route_records_the_probe(race_detector):
     assert "kernel" not in tr.kinds()
 
 
-def test_kernel_dispatch_counters_on_a_two_segment_join():
+def test_kernel_dispatch_counters_on_a_two_segment_join(monkeypatch):
     """``h2d_bytes``, ``d2h_bytes`` and ``join_pairs`` against a hand count:
     two small segments share one masked launch, each side packed as
-    ``[rows, 128]`` int32 and the mask read back as int32 per (q, r)."""
+    ``[rows, 128]`` int32, the query side padded to a ladder step (8-row
+    units), and the mask read back as int32 per (q, r)."""
+    from repro.kernels import ops
+    from repro.kernels.autotune import ladder_step
+    from repro.kernels.range_join import SUBLANES
+
+    monkeypatch.setattr(ops, "PROGRAMS", ops.LaunchPrograms())  # no shape built yet
     reg = MetricsRegistry("t")
     ex = BatchedJoinExecutor(stats=lambda k, n=1: reg.inc(k, n), engine="kernel")
     tables = [compress(roll_lineage(SHAPE, 3, 0), "backward"),
@@ -316,9 +322,15 @@ def test_kernel_dispatch_counters_on_a_two_segment_join():
                    & (t.key_lo[None] <= q.hi[:, None]), axis=2).sum())
         for q, t in zip(queries, tables)
     )
+    # the query side runs on a ladder step, widened by no more padding than
+    # costs a launch nothing (ops.PAD_BUDGET) past its own step
+    q_launch = nq + reg.counter_value("ladder_pad_rows")
+    own = ladder_step(nq, SUBLANES)
+    assert q_launch == ladder_step(q_launch, SUBLANES) >= own
+    assert 0 < (q_launch - own) * (128 + nr) * 4 <= ops.PAD_BUDGET
     assert reg.counter_value("kernel_launches") == 1
-    assert reg.counter_value("h2d_bytes") == (nq + nr) * 128 * 4
-    assert reg.counter_value("d2h_bytes") == nq * nr * 4
+    assert reg.counter_value("h2d_bytes") == (q_launch + nr) * 128 * 4
+    assert reg.counter_value("d2h_bytes") == q_launch * nr * 4
     assert reg.counter_value("join_pairs") == pairs == 5
 
 
