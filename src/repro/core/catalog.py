@@ -117,6 +117,8 @@ SEED_COUNTERS = (
     "h2d_bytes",
     "d2h_bytes",
     "join_pairs",
+    # index-route probes a full-lattice table answered by grid arithmetic
+    "index_lattice_probes",
     # materialized views + answer cache (repro/core/views.py)
     "view_hits",
     "view_misses",

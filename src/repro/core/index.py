@@ -21,19 +21,30 @@ query row we probe every attribute, take the window sizes as a selectivity
 estimate, and enumerate only the most selective attribute's window — a
 one-attribute cost model that needs no statistics beyond the index itself.
 
+Tables whose rows ProvRC could not make relative (stride-2 hops: max-pool
+backward, up-conv forward, strided slices) keep one row per cell, and their
+intervals form a *full regular lattice*: on every attribute ``j`` each row is
+``[origin_j + stride_j·k_j, … + width_j]``, one row per grid point.  On such a
+table a window is a whole strip of the grid, so the index answers a probe by
+arithmetic instead (:class:`Lattice`): each query row's grid range per
+attribute, their cartesian product, and a grid-to-row map.  The pairs and
+their order are the same as the window path's.
+
 The index is pure numpy, serializable (only the sort permutations are stored;
-the gathered/sorted copies are rebuilt in O(n) on attach), and is cached on
-:class:`~repro.core.table.CompressedTable` / persisted by the catalog.
+the gathered/sorted copies and the lattice are rebuilt in O(n) on attach), and
+is cached on :class:`~repro.core.table.CompressedTable` / persisted by the
+catalog.
 """
 
 from __future__ import annotations
 
 import io
 import json
+from dataclasses import dataclass
 
 import numpy as np
 
-__all__ = ["IntervalIndex", "interval_stats", "ragged_ranges"]
+__all__ = ["IntervalIndex", "Lattice", "interval_stats", "ragged_ranges"]
 
 _IDX_MAGIC = b"PRVCIDX1\n"
 
@@ -81,6 +92,93 @@ def interval_stats(
     return mean_len.astype(float), span.astype(float)
 
 
+@dataclass(frozen=True, eq=False)
+class Lattice:
+    """Rows that tile a full regular grid: row ``rows[g]`` (row ``g`` when
+    ``rows`` is None, the row-major case) holds, on every attribute ``j``,
+    ``[origin[j] + stride[j]·k_j, origin[j] + stride[j]·k_j + width[j]]``,
+    where ``(k_0, …, k_{d-1})`` is grid point ``g`` in C order over
+    ``counts``."""
+
+    origin: np.ndarray
+    stride: np.ndarray
+    width: np.ndarray
+    counts: np.ndarray
+    rows: np.ndarray | None
+
+    @staticmethod
+    def detect(
+        lo: np.ndarray, hi: np.ndarray, sorted_lo: list[np.ndarray]
+    ) -> "Lattice | None":
+        """The lattice ``lo``/``hi`` form, or None; O(n) given each
+        attribute's sorted ``lo``."""
+        n, d = lo.shape
+        if n < 2 or d == 0:
+            return None
+        width = hi[0] - lo[0]
+        if ((hi - lo) != width).any():
+            return None
+        origin = np.empty(d, np.int64)
+        stride = np.ones(d, np.int64)
+        counts = np.empty(d, np.int64)
+        size = 1
+        for j in range(d):
+            steps = np.diff(sorted_lo[j])
+            steps = steps[steps != 0]
+            if steps.size and (steps != steps[0]).any():
+                return None
+            origin[j] = sorted_lo[j][0]
+            if steps.size:
+                stride[j] = steps[0]
+            counts[j] = steps.size + 1
+            size *= int(counts[j])
+            if size > n:
+                return None
+        if size != n:
+            return None
+        grid = np.zeros(n, np.int64)
+        for j in range(d):
+            grid *= counts[j]
+            grid += (lo[:, j] - origin[j]) // stride[j]
+        rows = None
+        if not np.array_equal(grid, np.arange(n)):
+            if np.bincount(grid, minlength=n).max() > 1:
+                return None  # some grid point held twice, another not at all
+            rows = np.empty(n, np.int64)
+            rows[grid] = np.arange(n)
+        return Lattice(origin, stride, width, counts, rows)
+
+    def pairs(
+        self, q_lo: np.ndarray, q_hi: np.ndarray
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """Every overlap pair ``(qi, ri)``, lexsorted: per query row and
+        attribute the grid range ``[k_lo, k_hi)`` of the rows it meets,
+        enumerated as their cartesian product in C order."""
+        n_rows = int(self.counts.prod())
+        # ceil((q_lo - origin - width) / stride) and floor((q_hi - origin) / stride)
+        k_lo = np.maximum(-((self.origin + self.width - q_lo) // self.stride), 0)
+        k_hi = np.minimum((q_hi - self.origin) // self.stride + 1, self.counts)
+        owner = np.flatnonzero((k_hi > k_lo).all(axis=1))
+        # the leading attributes pick runs along the last, contiguous in grid
+        base = np.zeros(owner.size, np.int64)
+        for j in range(self.counts.size - 1):
+            o, k = ragged_ranges(k_lo[owner, j], k_hi[owner, j])
+            owner = owner[o]
+            base = base[o] * self.counts[j] + k
+        base *= self.counts[-1]
+        o, grid = ragged_ranges(base + k_lo[owner, -1], base + k_hi[owner, -1])
+        qi = owner[o]
+        if self.rows is None:
+            return qi, grid  # row-major: grid order is row order
+        ri = self.rows[grid]
+        # qi is already non-decreasing, so sorting qi·n + ri orders ri within
+        # each qi; nq·n stays far inside int64 for any frontier and table
+        # that fit in memory
+        key = qi * n_rows + ri
+        key.sort()
+        return qi, key - qi * n_rows
+
+
 class IntervalIndex:
     """Per-attribute sorted interval index over ``[lo, hi]`` columns.
 
@@ -114,6 +212,7 @@ class IntervalIndex:
         self.run_max_hi = [
             np.maximum.accumulate(hi[self.order[j], j]) for j in range(self.n_attrs)
         ]
+        self.lattice = Lattice.detect(lo, hi, self.sorted_lo)
 
     def _validate_order(self) -> None:
         """Reject a supplied permutation that does not fit these bounds.
@@ -177,7 +276,9 @@ class IntervalIndex:
         Equivalent to ``np.nonzero`` of the dense overlap matrix, but the
         work is proportional to the most selective attribute's candidate
         window per query row, not ``nq × nr``.  Pass ``windows`` (from
-        :meth:`probe_windows`) to reuse a probe pass already paid for.
+        :meth:`probe_windows`) to reuse a probe pass already paid for.  A
+        lattice table ignores ``windows``: its pairs come from grid
+        arithmetic, with nothing to verify.
         """
         nq = q_lo.shape[0]
         if nq == 0 or self.n_rows == 0:
@@ -186,6 +287,8 @@ class IntervalIndex:
             qi = np.repeat(np.arange(nq, dtype=np.int64), self.n_rows)
             ri = np.tile(np.arange(self.n_rows, dtype=np.int64), nq)
             return qi, ri
+        if self.lattice is not None:
+            return self.lattice.pairs(q_lo, q_hi)
         starts, ends = windows if windows is not None else self.probe_windows(q_lo, q_hi)
         best = np.argmin(ends - starts, axis=1)  # most selective attr per row
         qi_parts, ri_parts = [], []

@@ -816,8 +816,12 @@ class BatchedJoinExecutor:
             )
             if route == "index":
                 with maybe_span(tr, "index_probe", kind="index_probe") as sp:
-                    ui, ri = index_get().candidate_pairs(u_lo, u_hi, windows)
+                    index = index_get()
+                    ui, ri = index.candidate_pairs(u_lo, u_hi, windows)
                     sp.attrs["pairs"] = ui.size
+                    sp.attrs["route"] = "sorted" if index.lattice is None else "lattice"
+                if index.lattice is not None:
+                    self._stats("index_lattice_probes", 1)
                 with maybe_span(tr, "finalize", kind="finalize"):
                     results[i] = _finalize_batch(
                         req.queries, req.table, req.inverse,

@@ -1,5 +1,9 @@
-"""Interval-index query path: indexed == dense, batch == loop, invalidation."""
+"""Interval-index query path: indexed == dense, batch == loop, invalidation,
+and the lattice path == the sorted-window path."""
 
+import copy
+import itertools
+import json
 import os
 import tempfile
 
@@ -8,7 +12,7 @@ import pytest
 
 from repro.core import capture as C
 from repro.core.catalog import DSLog
-from repro.core.index import IntervalIndex, ragged_ranges
+from repro.core.index import IntervalIndex, Lattice, ragged_ranges
 from repro.core.provrc import compress, compress_both
 from repro.core.query import (
     QueryBox,
@@ -92,6 +96,145 @@ def test_index_rejects_stale_or_corrupt_permutation():
         IntervalIndex(lo, hi, order=np.full((1, 64), 9999))
     with pytest.raises(ValueError):
         IntervalIndex(lo, hi, order=np.zeros((1, 64), np.int64))  # not a perm
+
+
+# --------------------------------------------------------------------------- #
+# Full-lattice tables: grid arithmetic == sorted windows
+# --------------------------------------------------------------------------- #
+def _lattice_rows(counts, stride, width, origin, shuffle_seed=None):
+    """One row per grid point, ``[origin + stride·k, … + width]`` per attr,
+    in row-major order or shuffled."""
+    ks = np.array(list(itertools.product(*[range(c) for c in counts])), np.int64)
+    lo = np.asarray(origin, np.int64) + np.asarray(stride, np.int64) * ks
+    if shuffle_seed is not None:
+        lo = lo[np.random.default_rng(shuffle_seed).permutation(len(lo))]
+    return lo, lo + np.asarray(width, np.int64)
+
+
+def _probe_boxes(lo, hi, seed):
+    """Query boxes inside the grid, straddling its edge, fully outside it,
+    and inverted (``q_hi < q_lo`` on an attribute)."""
+    rng = np.random.default_rng(seed)
+    n_attrs = lo.shape[1]
+    g_lo, g_hi = lo.min(axis=0), hi.max(axis=0)
+    inside = rng.integers(g_lo, g_hi + 1, (12, n_attrs))
+    edge = np.repeat(np.stack([g_lo - 2, g_hi - 1]), 3, axis=0)
+    outside = np.stack([g_hi + 3, g_lo - 9])
+    q_lo = np.concatenate([inside, edge, outside]).astype(np.int64)
+    q_hi = q_lo + rng.integers(0, 5, q_lo.shape)
+    q_hi[-1] = g_lo - 4  # the last box ends before the grid starts
+    q_hi[0] = q_lo[0] - 1  # inverted: the conjunctive test decides, as ever
+    return q_lo, q_hi
+
+
+def _window_twin(idx):
+    """The same index with its lattice dropped: the sorted-window path."""
+    twin = copy.copy(idx)
+    twin.lattice = None
+    return twin
+
+
+def _assert_same_pairs(idx, q_lo, q_hi):
+    got = idx.candidate_pairs(q_lo, q_hi)
+    want = _window_twin(idx).candidate_pairs(q_lo, q_hi)
+    np.testing.assert_array_equal(got[0], want[0])  # same pairs, same order
+    np.testing.assert_array_equal(got[1], want[1])
+    return got
+
+
+@pytest.mark.parametrize("shuffled", [False, True], ids=["row_major", "shuffled"])
+@pytest.mark.parametrize(
+    "counts,stride,width,origin",
+    [
+        ((37,), (1,), (0,), (0,)),
+        ((29,), (3,), (2,), (-4,)),
+        ((13, 11), (2, 2), (1, 1), (0, 0)),
+        ((9, 14), (1, 3), (0, 2), (5, -1)),
+        ((16, 16), (2, 1), (1, 0), (0, 3)),
+        ((5, 4, 6), (1, 2, 3), (0, 1, 2), (0, 0, 0)),
+        ((4, 7, 3), (3, 1, 2), (2, 0, 1), (-2, 1, 7)),
+        ((1, 23), (1, 2), (0, 1), (4, 0)),
+    ],
+)
+def test_lattice_pairs_equal_sorted_window_pairs(counts, stride, width, origin, shuffled):
+    lo, hi = _lattice_rows(counts, stride, width, origin, 5 if shuffled else None)
+    idx = IntervalIndex(lo, hi)
+    lat = idx.lattice
+    assert lat is not None
+    assert (lat.rows is None) == (not shuffled)
+    np.testing.assert_array_equal(lat.counts, counts)
+    np.testing.assert_array_equal(lat.width, width)
+    np.testing.assert_array_equal(lat.origin, origin)
+    q_lo, q_hi = _probe_boxes(lo, hi, seed=len(lo))
+    qi, ri = _assert_same_pairs(idx, q_lo, q_hi)
+    assert qi.size > 0
+    assert not (qi == q_lo.shape[0] - 1).any()  # the box outside meets nothing
+    empty = np.zeros((0, lo.shape[1]), np.int64)
+    got = idx.candidate_pairs(empty, empty)
+    assert got[0].size == got[1].size == 0
+
+
+def _break(kind):
+    """Tables that are not full lattices, each next to a lattice it nearly is."""
+    lo, hi = _lattice_rows((6, 5), (2, 1), (1, 0), (0, 0))
+    if kind == "missing_cell":
+        return lo[1:], hi[1:]
+    if kind == "duplicated_row":
+        return np.concatenate([lo[:-1], lo[:1]]), np.concatenate([hi[:-1], hi[:1]])
+    if kind == "uneven_stride":
+        lo = lo.copy()
+        lo[lo[:, 0] == 10, 0] = 11
+        return lo, lo + (1, 0)
+    if kind == "mixed_widths":
+        hi = hi.copy()
+        hi[7, 1] += 1
+        return lo, hi
+    if kind == "one_row":
+        return lo[:1], hi[:1]
+    raise AssertionError(kind)
+
+
+@pytest.mark.parametrize(
+    "kind", ["missing_cell", "duplicated_row", "uneven_stride", "mixed_widths", "one_row"]
+)
+def test_near_lattices_keep_the_sorted_window_path(kind):
+    lo, hi = _break(kind)
+    idx = IntervalIndex(lo, hi)
+    assert idx.lattice is None
+    assert Lattice.detect(lo, hi, idx.sorted_lo) is None
+    q_lo, q_hi = _probe_boxes(lo, hi, seed=3)
+    qi, ri = idx.candidate_pairs(q_lo, q_hi)
+    ov = np.ones((len(q_lo), len(lo)), bool)
+    for j in range(lo.shape[1]):
+        ov &= (q_lo[:, j : j + 1] <= hi[None, :, j]) & (lo[None, :, j] <= q_hi[:, j : j + 1])
+    wq, wr = np.nonzero(ov)
+    np.testing.assert_array_equal(qi, wq)
+    np.testing.assert_array_equal(ri, wr)
+
+
+@pytest.mark.parametrize("shuffled", [False, True], ids=["row_major", "shuffled"])
+def test_lattice_rederived_on_attach_and_sidecar_unchanged(shuffled):
+    lo, hi = _lattice_rows((12, 9), (2, 2), (1, 1), (0, 0), 8 if shuffled else None)
+    idx = IntervalIndex(lo, hi)
+    blob = idx.to_bytes()
+    # the sidecar holds the header and the int32 sort permutations alone
+    header = json.dumps({"n_rows": len(lo), "n_attrs": 2, "dtype": "<i4"}).encode()
+    order = np.stack([np.argsort(lo[:, j], kind="stable") for j in range(2)])
+    assert blob == (
+        b"PRVCIDX1\n" + len(header).to_bytes(4, "little") + header
+        + order.astype(np.int32).tobytes()
+    )
+    again = IntervalIndex.from_bytes(blob, lo, hi)
+    assert again.to_bytes() == blob
+    for name in ("origin", "stride", "width", "counts"):
+        np.testing.assert_array_equal(getattr(again.lattice, name), getattr(idx.lattice, name))
+    if shuffled:
+        np.testing.assert_array_equal(again.lattice.rows, idx.lattice.rows)
+    else:
+        assert again.lattice.rows is None
+    q_lo, q_hi = _probe_boxes(lo, hi, seed=9)
+    for a, b in zip(again.candidate_pairs(q_lo, q_hi), _assert_same_pairs(idx, q_lo, q_hi)):
+        np.testing.assert_array_equal(a, b)
 
 
 # --------------------------------------------------------------------------- #

@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 from repro.core.capture import (
+    conv2d_lineage,
     flip_lineage,
     identity_lineage,
     roll_lineage,
@@ -28,7 +29,7 @@ from repro.core.capture import (
 )
 from repro.core.catalog import DSLog
 from repro.core.provrc import compress
-from repro.core.query import BatchedJoinExecutor, JoinRequest, QueryBox
+from repro.core.query import INDEX_MIN_ROWS, BatchedJoinExecutor, JoinRequest, QueryBox
 from repro.core.shard import ShardedDSLog
 from repro.obs.export import (
     TELEMETRY_SCHEMA,
@@ -279,21 +280,36 @@ def test_trace_hop_record_per_engine(batched, race_detector):
     assert ("wave" in tr.kinds()) is batched
 
 
-def test_trace_index_route_records_the_probe(race_detector):
-    """A sort hop scatters the frontier: the planner routes it through the
-    table's interval index, which the ``index_probe`` span times."""
-    side = 64
+@pytest.mark.parametrize("hop,route", [("sort", "sorted"), ("maxpool", "lattice")])
+def test_trace_index_route_records_the_probe(hop, route, race_detector):
+    """A sort hop scatters the frontier, and a few cells of a 2x2 stride-2
+    max-pool's 48x48 output are a sparse one: the planner routes either
+    through the table's interval index, which the ``index_probe`` span
+    times.  The max-pool's backward table holds one row per output cell, a
+    full lattice, so the index answers by grid arithmetic and counts the
+    probe; the sort table's rows merge where neighbouring rows sort alike,
+    so it probes sorted windows."""
+    side = 64 if hop == "sort" else 96
     log = DSLog()
     log.define_array("A", (side, side))
-    log.define_array("B", (side, side))
-    values = np.random.default_rng(0).random((side, side))
-    log.add_lineage("A", "B", sort_lineage(values))
-    cells = np.stack([np.arange(0, side, 2), np.arange(0, side, 2)[::-1]], 1)
+    if hop == "sort":
+        rel = sort_lineage(np.random.default_rng(0).random((side, side)))
+    else:
+        rel = conv2d_lineage(side, side, 2, 2, stride=2)
+    log.define_array("B", rel.out_shape)
+    log.add_lineage("A", "B", rel)
+    assert log.lineage[0].backward.n_rows >= INDEX_MIN_ROWS
+    n = rel.out_shape[0]
+    cells = np.stack([np.arange(0, n, 2), np.arange(0, n, 2)[::-1]], 1)
     res, tr = log.prov_query("B", "A", cells, trace=True)
-    assert res.n_cells() == len(cells)
+    picked = (rel.out_idx[:, None, :] == cells[None]).all(axis=2).any(axis=1)
+    assert res.cell_set() == {tuple(c) for c in rel.in_idx[picked].tolist()}
     (wave,) = tr.spans(kind="wave")
     assert [c.kind for c in wave.children] == ["prepare", "index_probe", "finalize"]
-    assert wave.children[1].attrs["pairs"] >= len(cells)
+    probe = wave.children[1]
+    assert probe.attrs["pairs"] >= len(cells)
+    assert probe.attrs["route"] == route
+    assert log.io_stats["index_lattice_probes"] == (route == "lattice")
     assert "kernel" not in tr.kinds()
 
 
