@@ -824,7 +824,7 @@ def _permutation_lineage(shape, rng) -> LineageRelation:
     Poorly compressible on purpose (≈ one table row per cell): each hop of
     the accel DAG is then a *small dense* θ-join — under
     ``INDEX_MIN_ROWS`` the router always evaluates the all-pairs mask, the
-    exact per-hop inner loop batched frontier execution packs.
+    exact joins batched frontier execution packs.
     """
     n = int(np.prod(shape))
     cells = np.stack(
@@ -858,8 +858,7 @@ def _build_accel_dag(shape, branches: int, hops: int, seed: int = 0):
 
     Every hop's table is a fresh random bijection, so each plan wave holds
     ``branches`` small dense joins — the workload the batched executor
-    packs into one blocked evaluation and the per-hop loop dispatches one
-    at a time.
+    packs into one blocked evaluation.
     """
     log = DSLog(store_forward=True)
     log.define_array("src", shape)
@@ -901,22 +900,15 @@ def run_accel_ablation(
     smoke: bool = False,
     verbose: bool = True,
 ) -> list[dict]:
-    """Batched frontier execution vs the per-hop join loop (ISSUE 5 + 8).
+    """Batched frontier execution on a wide DAG (ISSUE 5 + 8).
 
     The DAG's hops are small dense joins (permutation tables under the
-    index threshold) — the regime where dispatching one tiny mask
-    evaluation per hop loses to packing a whole plan frontier into one
-    blocked int32 evaluation.  Measures, over the same query batch
-    (median of ``repeats`` runs — this box's timing noise is large):
-
-    * ``perhop_s``   — serial per-hop loop (``batched=False``),
-    * ``batched_s``  — serial packed frontier execution,
-    * ``parallel_s`` — packed execution with ``parallel=4`` (the wave's
-      mask evaluations split across workers, clamped to real cores; the
-      twin's numpy inner loops release the GIL, so they overlap on CPU),
-
-    asserts all three produce bit-identical results, and reports the
-    io_stats batching meters (including the block-diagonal tile meters).
+    index threshold) — the regime where the executor packs a whole plan
+    frontier into one blocked int32 evaluation.  Measures ``batched_s``,
+    the median of ``repeats`` query runs over the same batch (this box's
+    timing noise is large), asserts the answer equals an uncompressed
+    hash-join oracle, and reports the io_stats batching meters (including
+    the block-diagonal tile meters).
 
     A second record (``kind="layout"``, ISSUE 8) measures the kernel
     launch layouts head-to-head on a large ragged frontier: one masked
@@ -927,7 +919,7 @@ def run_accel_ablation(
     if smoke:
         shape, branches, hops, n_cells, repeats = (24, 22), 10, 2, 192, 5
     log = _build_accel_dag(shape, branches, hops)
-    # this ablation measures the join *engines* — disable the view/answer
+    # this ablation measures the join engine — disable the view/answer
     # cache layer, which would otherwise serve every repeat after the first
     # warmup query and time nothing but cache lookups
     log.views.enabled = False
@@ -936,27 +928,24 @@ def run_accel_ablation(
     flat = rng.choice(n, size=n_cells, replace=False)
     cells = np.stack(np.unravel_index(flat, shape), axis=1)
 
-    def run(label, **kw):
-        res = log.prov_query("src", "out", cells, **kw)
-        ts = []
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            res = log.prov_query("src", "out", cells, **kw)
-            ts.append(time.perf_counter() - t0)
-        return sorted(ts)[len(ts) // 2], res
+    got = log.prov_query("src", "out", cells)
+    edges = _accel_dag_edges(shape, branches, hops)
+    want: set[int] = set()
+    per_branch = hops + 1
+    for b in range(branches):
+        chain = edges[b * per_branch : (b + 1) * per_branch]
+        want.update(_forward_join_rows([r for *_, r in chain], cells).tolist())
+    assert set(_ravel(got.cells(), shape).tolist()) == want, "oracle mismatch"
 
-    perhop_s, want = run("perhop", batched=False)
     base = dict(log.io_stats)
-    batched_s, got_b = run("batched", batched=True)
-    # run() issues one warmup query before the `repeats` timed ones, and
-    # every call dispatches the same launches
-    queries_run = repeats + 1
+    ts = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        log.prov_query("src", "out", cells)
+        ts.append(time.perf_counter() - t0)
+    batched_s = sorted(ts)[len(ts) // 2]
     launches = log.io_stats["kernel_launches"] - base["kernel_launches"]
     packed = log.io_stats["joins_packed"] - base["joins_packed"]
-    parallel_s, got_p = run("parallel", batched=True, parallel=4)
-    for got in (got_b, got_p):
-        assert got.lo.tobytes() == want.lo.tobytes(), "engine results differ"
-        assert got.hi.tobytes() == want.hi.tobytes(), "engine results differ"
 
     total_hops = branches * (hops + 1)
     rec = {
@@ -965,12 +954,8 @@ def run_accel_ablation(
         "branches": branches,
         "hops": total_hops,
         "n_cells": n_cells,
-        "perhop_s": perhop_s,
         "batched_s": batched_s,
-        "parallel_s": parallel_s,
-        "batched_speedup": perhop_s / batched_s,
-        "parallel_speedup": batched_s / parallel_s,
-        "launches_per_query": launches / queries_run,
+        "launches_per_query": launches / repeats,
         "joins_per_launch": packed / max(launches, 1),
         "batch_tiles_visited": log.io_stats["batch_tiles_visited"],
         "batch_tiles_skipped": log.io_stats["batch_tiles_skipped"],
@@ -978,10 +963,7 @@ def run_accel_ablation(
     if verbose:
         print(
             f"  accel_ablation {branches}x{hops + 1} hops "
-            f"perhop={perhop_s * 1e3:7.1f}ms batched={batched_s * 1e3:7.1f}ms "
-            f"parallel4={parallel_s * 1e3:7.1f}ms "
-            f"batched={rec['batched_speedup']:4.2f}x "
-            f"par={rec['parallel_speedup']:4.2f}x "
+            f"query={batched_s * 1e3:7.1f}ms "
             f"joins/launch={rec['joins_per_launch']:4.1f}",
             flush=True,
         )

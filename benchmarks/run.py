@@ -150,31 +150,20 @@ def bench_wal(quick: bool) -> None:
 def bench_accel(quick: bool) -> None:
     from .fig89_query import run_accel_ablation
 
-    print("# Accelerator batched execution — per-hop join loop vs packed "
-          "frontiers, serial vs parallel=4, launch layouts", flush=True)
+    print("# Accelerator batched execution — packed frontiers, launch "
+          "layouts", flush=True)
     rows = run_accel_ablation(smoke=_SMOKE)
     for r in rows:
         if r["kind"] == "exec":
             tag = f"accel/b{r['branches']}/h{r['hops']}/q{r['n_cells']}"
-            _emit(f"{tag}/perhop", r["perhop_s"] * 1e6, "")
             _emit(
                 f"{tag}/batched", r["batched_s"] * 1e6,
-                f"speedup_x={r['batched_speedup']:.2f};"
                 f"joins_per_launch={r['joins_per_launch']:.1f}",
             )
-            _emit(
-                f"{tag}/parallel4", r["parallel_s"] * 1e6,
-                f"scaling_x={r['parallel_speedup']:.2f}",
-            )
             if _SMOKE:
-                # CI gate: packed frontier execution must not lose to the
-                # per-hop loop (results are asserted bit-identical inside
-                # the ablation itself), and the tile meters must show the
-                # block-diagonal schedule skipping cross-product tiles
-                assert r["batched_speedup"] >= 1.0, (
-                    f"batched execution slower than the per-hop loop: "
-                    f"{r['batched_speedup']:.2f}x"
-                )
+                # CI gate: the tile meters must show the block-diagonal
+                # schedule skipping cross-product tiles (the answer is
+                # asserted against an oracle inside the ablation itself)
                 assert r["batch_tiles_skipped"] > 0, (
                     "batched execution never skipped a cross-product tile "
                     "— block-diagonal accounting is not engaged"

@@ -394,7 +394,7 @@ class DSLog:
         self.predictor = ReusePredictor(m=reuse_m)
         self.planner = QueryPlanner(self)
         self.views = ViewManager(self)
-        # measured launch geometries for the batched join engines, persisted
+        # measured launch geometries for the join kernels, persisted
         # as an autotune.json sidecar and consulted by planner.executor
         self.autotune = GeometryTuner()
         self._next_id = 0
@@ -940,8 +940,8 @@ class DSLog:
         measured selectivity — an exponential moving average (each new
         measurement decays the accumulated mass by ``hop_decay``) with a
         sample cap, so the feedback tracks workload shifts instead of
-        averaging over all history.  Thread-safe (parallel execution calls
-        this from worker threads)."""
+        averaging over all history.  Thread-safe (concurrent queries call
+        this from their own threads)."""
         if lineage_id < 0:  # view hop: the ViewManager keeps its own EMA
             return self.views.record_hop(
                 lineage_id, stored, frontier_on, pairs, qrows
@@ -1117,7 +1117,6 @@ class DSLog:
         *args,
         merge: bool = True,
         parallel: int | None = None,
-        batched: bool | None = None,
         trace: bool = False,
     ) -> "QueryBox | dict | tuple":
         """Lineage between cells of two arrays.
@@ -1131,12 +1130,10 @@ class DSLog:
         downstream of ``src``), merges converging branches at fan-in arrays,
         and picks the cheapest stored materialization per hop.  ``dst`` may
         be a sequence of array names — the result is then a dict
-        ``{name: QueryBox}``.  ``parallel=N`` executes independent plan
-        branches (and, on a sharded store, per-shard sub-plans) on an
-        N-thread pool.  ``batched`` picks the join engine (default
-        ``planner.batched``): packed frontier execution through the
-        :class:`~repro.core.query.BatchedJoinExecutor` vs the per-hop join
-        loop — results are bit-identical either way.
+        ``{name: QueryBox}``.  Each plan wave's joins run as one packed
+        dispatch of the :class:`~repro.core.query.BatchedJoinExecutor`;
+        ``parallel=N`` splits a wave's numpy-twin segments across an
+        N-thread pool (results are bit-identical for any N).
 
         ``trace=True`` returns ``(result, QueryTrace)`` instead: a span
         tree of the query's stages (see :meth:`trace_scope`) with per-span
@@ -1152,7 +1149,6 @@ class DSLog:
                 [cells],
                 merge=merge,
                 parallel=parallel,
-                batched=batched,
                 trace=trace,
             )
             if trace:
@@ -1166,7 +1162,6 @@ class DSLog:
             [cells],
             merge=merge,
             parallel=parallel,
-            batched=batched,
             trace=trace,
         )
         tr = None
@@ -1183,7 +1178,6 @@ class DSLog:
         *args,
         merge: bool = True,
         parallel: int | None = None,
-        batched: bool | None = None,
         trace: bool = False,
     ) -> "list[QueryBox] | dict[str, list[QueryBox]] | tuple":
         """Answer many independent queries in one pass (both call forms).
@@ -1192,19 +1186,11 @@ class DSLog:
         (shared index probes, deduplicated boxes across in-flight queries).
         ``trace=True`` returns ``(result, QueryTrace)``.
         """
-        workers = parallel if parallel is not None else 0
-        use_batched = (
-            getattr(self.planner, "batched", True) if batched is None else batched
-        )
-        engine = (
-            "parallel"
-            if workers and workers > 1
-            else ("batched" if use_batched else "serial")
-        )
+        engine = "parallel" if (parallel or 0) > 1 else "batched"
         with self.trace_scope() if trace else contextlib.nullcontext() as tr:
             t0 = time.perf_counter()
             out, path_label = self._query_batch_impl(
-                args, merge, parallel, batched, tr, engine
+                args, merge, parallel, tr, engine
             )
         # per-path query latency: cache hit / view shortcut / full plan,
         # split by execution engine
@@ -1233,9 +1219,7 @@ class DSLog:
             finally:
                 self._active_trace = prev
 
-    def _query_batch_impl(
-        self, args, merge, parallel, batched, tr, engine
-    ) -> tuple:
+    def _query_batch_impl(self, args, merge, parallel, tr, engine) -> tuple:
         """Body of :meth:`prov_query_batch`; returns ``(result, path)``
         where ``path`` labels how the answer was produced (``"cache"`` /
         ``"view"`` / ``"planned"`` / explicit-``"path"`` form)."""
@@ -1250,11 +1234,11 @@ class DSLog:
                 return [], "path"
             boxes = self._as_boxes(path[0], queries)
             with maybe_span(tr, "plan", kind="plan", form="path") as sp:
-                plan = self.planner.plan_path(path, frontier=boxes, batched=batched)
+                plan = self.planner.plan_path(path, frontier=boxes)
                 sp.attrs["est_cost"] = round(plan.est_cost, 3)
             with maybe_span(tr, "execute", kind="execute", engine=engine):
                 out = self.planner.execute(
-                    plan, boxes, merge=merge, parallel=parallel, batched=batched
+                    plan, boxes, merge=merge, parallel=parallel
                 )[path[-1]]
             return out, "path"
         _, src, dst, queries = form
@@ -1281,13 +1265,11 @@ class DSLog:
         # plans are cell-independent: a hot route replans only after an
         # invalidation, admission, or demotion changes the shortcut race
         with maybe_span(tr, "plan", kind="plan", form="graph") as sp:
-            plan = self.views.plan_get(src, targets, batched)
+            plan = self.views.plan_get(src, targets)
             sp.attrs["memo"] = plan is not None
             if plan is None:
-                plan = self.planner.plan(
-                    src, targets, frontier=boxes, batched=batched
-                )
-                self.views.plan_put(src, targets, batched, plan)
+                plan = self.planner.plan(src, targets, frontier=boxes)
+                self.views.plan_put(src, targets, plan)
             sp.attrs["est_cost"] = round(plan.est_cost, 3)
         path_label = (
             "view"
@@ -1301,7 +1283,7 @@ class DSLog:
         )
         with maybe_span(tr, "execute", kind="execute", engine=engine):
             out = self.planner.execute(
-                plan, boxes, merge=merge, parallel=parallel, batched=batched
+                plan, boxes, merge=merge, parallel=parallel
             )
         if ckey is not None:
             self.views.cache_put(ckey, out, src, targets, plan)
@@ -1541,8 +1523,8 @@ class DSLog:
             return t
 
         def on_load() -> None:
-            # fired from TableHandle.get under arbitrary threads (parallel
-            # plan execution) — must take the stats lock like every meter
+            # fired from TableHandle.get under arbitrary threads (concurrent
+            # queries) — must take the stats lock like every meter
             self._bump("tables_loaded")
 
         return TableHandle(load, None if rows is None else int(rows), on_load)

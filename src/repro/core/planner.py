@@ -54,8 +54,6 @@ from .query import (
     canonical_boxes,
     dense_backend,
     merge_boxes,
-    theta_join_batch,
-    theta_join_inverse_batch,
 )
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
@@ -75,10 +73,10 @@ _INVERSE_OVERHEAD = 2.0  # inverse join does strictly more per-pair work
 _INDEX_BUILD_WEIGHT = 0.25  # amortized first-build cost of an uncached index
 _POINT_ROW_COVER = 4.0  # unloaded-table fallback: rows a point probe hits
 _MERGE_SHRINK = 0.5  # expected box-count shrink from merge_boxes
-# measured per-pair advantage of the packed batched-dense engine over the
-# per-hop blocked loop (contiguous int32 columns + one dispatch per
-# frontier); makes "batched" competitive where "dense" would lose to the
-# index by less than ~2x.  This is the *prior* at perfect tile occupancy —
+# per-pair discount of the packed dense engine against the nominal nq*nr
+# dense cost (contiguous int32 columns + one dispatch per frontier); makes
+# the dense route competitive where it would lose to the index by less
+# than ~2x.  This is the *prior* at perfect tile occupancy —
 # the effective discount scales by the executor's measured tile waste
 # (scheduled tile cells / useful pair cells), so frontiers whose shape pads
 # badly stop looking artificially cheap to the batched route.
@@ -92,11 +90,12 @@ class HopChoice:
     lineage_id: int
     stored: str  # "backward" | "forward": which materialization to read
     frontier_on: str  # "key" (natural join) | "value" (inverse join)
-    route: str  # "index" | "dense" | "batched" (packed frontier execution)
+    route: str  # "index" | "batched" (packed dense frontier execution)
     est_pairs: float
     est_cost: float
-    # dense-route backend annotation ("tpu", "np:cpu", "np:wide", "np:i64")
-    # — why a dense hop will or won't ride the kernel; shown by describe()
+    # dense-route backend annotation ("tpu:256x256", "np:cpu:4m", "np:wide",
+    # "np:i64", ...) — why a dense hop will or won't ride the kernel, and
+    # its launch geometry; shown by describe()
     note: str = ""
 
     def describe_route(self) -> str:
@@ -159,8 +158,6 @@ class QueryPlan:
             f"measured pairs={rec['pairs']} qrows={rec['qrows']} "
             f"calls={rec['calls']}"
         )
-        if rec["timed"]:
-            measured += f" time={rec['ms']:.3f}ms"
         return f"      {_fmt_lid(choice.lineage_id)}: {est} | {measured}"
 
     def describe(self, analyze: bool = False) -> str:
@@ -168,8 +165,8 @@ class QueryPlan:
 
         ``analyze=True`` is EXPLAIN ANALYZE: each hop choice gains a
         sub-line comparing the cost model's estimates against measured
-        pair counts (and per-hop wall time where the serial engine timed
-        individual joins) accumulated over the plan's executions.
+        pair counts accumulated over the plan's executions, and the header
+        the packed dispatches' wall time.
         """
         header = (
             f"{self.direction} plan, {len(self.order)} nodes, "
@@ -206,16 +203,14 @@ class QueryPlanner:
 
     def __init__(self, log: "DSLog"):
         self.log = log
-        # default thread-pool width for execute(); None/1 = serial
+        # default worker count for execute()'s numpy-twin segments;
+        # None/1 = serial
         self.parallel: int | None = None
-        # pack each frontier's dense joins into one blocked evaluation
-        # (the BatchedJoinExecutor); False = the per-hop join loop
-        self.batched: bool = True
         self._executor: BatchedJoinExecutor | None = None
 
     @property
     def executor(self) -> BatchedJoinExecutor:
-        """The (lazily created) batched join engine, metering io_stats.
+        """The (lazily created) join engine, metering io_stats.
 
         Launch geometry comes from the store's persisted autotune table
         (``log.autotune``), so a reopened store starts on its measured
@@ -235,9 +230,8 @@ class QueryPlanner:
 
         The flat prior sharpened by the executor's measured tile occupancy:
         before any dispatch this is exactly ``_BATCHED_PAIR_DISCOUNT``;
-        once frontiers run, padding-heavy shapes raise it toward (and past)
-        parity with the per-hop dense cost, capped at 1.0 so measurement
-        never makes batched look *worse* than the engine it replaces wholesale.
+        once frontiers run, padding-heavy shapes raise it toward parity
+        with the nominal dense cost, capped at 1.0.
         """
         return min(1.0, _BATCHED_PAIR_DISCOUNT * self.executor.measured_waste)
 
@@ -256,7 +250,6 @@ class QueryPlanner:
         sources: str | Iterable[str],
         targets: str | Iterable[str],
         frontier: Sequence[QueryBox] | None = None,
-        batched: bool | None = None,
     ) -> QueryPlan:
         """Plan between endpoint sets; query cells live on ``sources``.
 
@@ -264,11 +257,7 @@ class QueryPlanner:
         targets are downstream of the sources, *backward* when upstream.
         ``frontier`` (the actual initial boxes, when already known) sharpens
         the first hop's cost estimates; the plan is valid without it.
-        ``batched`` (default ``planner.batched``) selects the engine the
-        cost model targets, so routes always match the engine that will
-        execute them.
         """
-        batched = self.batched if batched is None else batched
         g = self.log.graph
         src_set = {sources} if isinstance(sources, str) else set(sources)
         dst_set = {targets} if isinstance(targets, str) else set(targets)
@@ -329,7 +318,6 @@ class QueryPlanner:
                     traverse="forward" if direction == "forward" else "backward",
                     nq=max(est_boxes.get(u, 1.0), 1.0),
                     frontier=frontier if u in plan.starts else None,
-                    batched=batched,
                 )
                 plan.steps.setdefault(key, []).append(step)
                 plan.est_cost += sum(c.est_cost for c in step.choices)
@@ -341,7 +329,7 @@ class QueryPlanner:
         # plan — the view wins exactly when the cost model says it should.
         if len(src_set) == 1 and len(dst_set) == 1:
             vplan = self._view_plan(
-                next(iter(src_set)), next(iter(dst_set)), frontier, nq0, batched
+                next(iter(src_set)), next(iter(dst_set)), frontier, nq0
             )
             tr = self._trace()
             if vplan is not None and vplan.est_cost < plan.est_cost:
@@ -374,7 +362,6 @@ class QueryPlanner:
         dst: str,
         frontier: Sequence[QueryBox] | None,
         nq0: float,
-        batched: bool,
     ) -> QueryPlan | None:
         """One-hop plan over a materialized view covering ``src -> dst``
         (either orientation), or None when no live view matches."""
@@ -396,8 +383,7 @@ class QueryPlanner:
             node_array={src: src, dst: dst},
         )
         step = self._build_step(
-            src, dst, [pid], traverse=direction, nq=nq0,
-            frontier=frontier, batched=batched,
+            src, dst, [pid], traverse=direction, nq=nq0, frontier=frontier
         )
         vplan.steps[dst] = [step]
         vplan.est_cost = sum(c.est_cost for c in step.choices)
@@ -410,7 +396,6 @@ class QueryPlanner:
         self,
         path: Sequence[str],
         frontier: Sequence[QueryBox] | None = None,
-        batched: bool | None = None,
     ) -> QueryPlan:
         """Plan the paper's explicit-path query form on the same executor.
 
@@ -418,7 +403,6 @@ class QueryPlanner:
         contributes, whichever dataflow direction it was registered in.
         Node keys carry the position so a path may legally revisit an array.
         """
-        batched = self.batched if batched is None else batched
         if len(path) < 2:
             raise ValueError("path needs at least two arrays")
         keys = [f"{k}:{name}" for k, name in enumerate(path)]
@@ -442,11 +426,11 @@ class QueryPlanner:
             hop_frontier = frontier if k == 0 else None
             for lid in ids_down:
                 choices.append(
-                    self._best_choice(lid, "backward", nq, hop_frontier, batched)
+                    self._best_choice(lid, "backward", nq, hop_frontier)
                 )
             for lid in ids_up:
                 choices.append(
-                    self._best_choice(lid, "forward", nq, hop_frontier, batched)
+                    self._best_choice(lid, "forward", nq, hop_frontier)
                 )
             step = EdgeStep(keys[k], keys[k + 1], choices)
             plan.steps[keys[k + 1]] = [step]
@@ -464,10 +448,9 @@ class QueryPlanner:
         traverse: str,
         nq: float,
         frontier: Sequence[QueryBox] | None,
-        batched: bool = True,
     ) -> EdgeStep:
         choices = [
-            self._best_choice(lid, traverse, nq, frontier, batched)
+            self._best_choice(lid, traverse, nq, frontier)
             for lid in lineage_ids
         ]
         return EdgeStep(u, v, choices)
@@ -478,7 +461,6 @@ class QueryPlanner:
         traverse: str,
         nq: float,
         frontier: Sequence[QueryBox] | None,
-        batched: bool = True,
     ) -> HopChoice:
         """Cheapest (materialization, route) for one entry on one hop.
 
@@ -491,28 +473,25 @@ class QueryPlanner:
         if traverse == "backward":
             options.append(
                 self._cost_option(
-                    entry, lineage_id, "backward", "key", nq, frontier, batched
+                    entry, lineage_id, "backward", "key", nq, frontier
                 )
             )
             if entry.has_forward:
                 options.append(
                     self._cost_option(
-                        entry, lineage_id, "forward", "value", nq, frontier,
-                        batched,
+                        entry, lineage_id, "forward", "value", nq, frontier
                     )
                 )
         else:
             if entry.has_forward:
                 options.append(
                     self._cost_option(
-                        entry, lineage_id, "forward", "key", nq, frontier,
-                        batched,
+                        entry, lineage_id, "forward", "key", nq, frontier
                     )
                 )
             options.append(
                 self._cost_option(
-                    entry, lineage_id, "backward", "value", nq, frontier,
-                    batched,
+                    entry, lineage_id, "backward", "value", nq, frontier
                 )
             )
         return min(options, key=lambda c: c.est_cost)
@@ -525,7 +504,6 @@ class QueryPlanner:
         frontier_on: str,
         nq: float,
         frontier: Sequence[QueryBox] | None,
-        batched: bool = True,
     ) -> HopChoice:
         nr = entry.backward_rows if stored == "backward" else entry.forward_rows
         nr = max(int(nr), 1)
@@ -534,10 +512,10 @@ class QueryPlanner:
         est_pairs = self._estimate_pairs(
             table, nr, frontier_on, nq, frontier, measured
         )
-        dense_cost = nq * nr * (self._batched_discount() if batched else 1.0)
+        dense_cost = nq * nr * self._batched_discount()
         # route: small tables and unselective frontiers go dense
         if nr < INDEX_MIN_ROWS or est_pairs > DENSE_FRACTION * nq * nr:
-            route = "batched" if batched else "dense"
+            route = "batched"
             join_cost = dense_cost
         else:
             route = "index"
@@ -549,17 +527,15 @@ class QueryPlanner:
             )
             if not has_index:
                 join_cost += _INDEX_BUILD_WEIGHT * nr * math.log2(nr + 1)
-            # the batched-route option: with packed frontier execution the
-            # dense engine is cheap enough to beat a selective index on
-            # some hops the per-hop model would never route dense
-            if batched and dense_cost < join_cost:
+            # packed frontier execution makes the dense engine cheap enough
+            # to beat a selective index on some hops
+            if dense_cost < join_cost:
                 route, join_cost = "batched", dense_cost
-        if route != "index":
-            choice_note = self._dense_note(
-                entry, stored, frontier_on, table, segmented=route == "batched"
-            )
-        else:
-            choice_note = ""
+        choice_note = (
+            self._dense_note(entry, stored, frontier_on, table)
+            if route == "batched"
+            else ""
+        )
         if frontier_on == "value":
             join_cost *= _INVERSE_OVERHEAD
         return HopChoice(
@@ -573,9 +549,10 @@ class QueryPlanner:
         stored: str,
         frontier_on: str,
         table,
-        segmented: bool = True,
     ) -> str:
-        """Backend annotation for a dense/batched hop (see ``dense_backend``).
+        """Backend annotation for a dense hop (see ``dense_backend``), with
+        the launch geometry the executor will use, e.g.
+        ``tpu:64x256`` / ``np:cpu:4m``.
 
         Attribute width comes from the array shapes (known without loading
         the blob); the int32-overflow check needs the actual bounds, so it
@@ -591,12 +568,8 @@ class QueryPlanner:
             int32_ok = table.int32_safe(
                 "key" if frontier_on == "key" else "value"
             )
-        note = dense_backend(n_attrs, int32_ok, segmented=segmented)
-        if segmented:
-            # batched hops also show the launch geometry the executor will
-            # use, e.g. "batched(tpu:64x256)" / "batched(np:cpu:4m)"
-            note = f"{note}:{self.executor.geometry_label(note)}"
-        return note
+        note = dense_backend(n_attrs, int32_ok)
+        return f"{note}:{self.executor.geometry_label(note)}"
 
     def _estimate_pairs(
         self,
@@ -664,7 +637,6 @@ class QueryPlanner:
         merge: bool = True,
         collect: str = "targets",
         parallel: int | None = None,
-        batched: bool | None = None,
     ) -> dict[str, list[QueryBox]]:
         """Run ``plan`` for a batch of queries rooted at its start node(s).
 
@@ -677,19 +649,12 @@ class QueryPlanner:
         batches the same length).  Returns ``{array name: [QueryBox per
         query]}`` for the targets (or every node with ``collect="all"``).
 
-        ``parallel=N`` (or setting ``planner.parallel``) runs *independent*
-        plan nodes — parallel branches of the DAG and, on a sharded store,
-        per-shard sub-plans with no pending exchange between them — on an
-        N-thread pool.  Each node still accumulates its incoming steps in
-        plan order, so results are identical to serial execution.
-
-        ``batched`` (default ``planner.batched``) picks the join engine:
-        ``True`` packs every dense join ready in a plan frontier — across
-        branches and sub-plans — into one blocked evaluation through the
-        :class:`~repro.core.query.BatchedJoinExecutor` (in parallel mode,
-        one packed evaluation per node, with the GIL-releasing twin letting
-        workers overlap); ``False`` is the serial per-hop join loop.  Both
-        engines return bit-identical results.
+        Execution runs in waves (``_execute_waves``): every dense join
+        ready in a plan frontier — across branches and sub-plans — is
+        packed into one dispatch of the
+        :class:`~repro.core.query.BatchedJoinExecutor`.  ``parallel=N`` (or
+        setting ``planner.parallel``) splits each wave's numpy-twin
+        segments across N threads; results are bit-identical for any N.
         """
         if isinstance(queries, dict):
             start_by_array = {plan.node_array[k]: k for k in plan.starts}
@@ -733,17 +698,7 @@ class QueryPlanner:
         nB = lengths.pop() if lengths else 0
 
         workers = parallel if parallel is not None else self.parallel
-        use_batched = self.batched if batched is None else batched
-        if use_batched and plan.steps:
-            frontier = self._execute_waves(plan, init, nB, merge, workers)
-        elif workers is not None and workers > 1 and len(plan.order) > 1:
-            frontier = self._execute_parallel(plan, init, nB, merge, workers)
-        else:
-            frontier = {}
-            for key in plan.order:
-                frontier[key] = self._compute_node(
-                    plan, key, init, frontier, nB, merge, use_batched
-                )
+        frontier = self._execute_waves(plan, init, nB, merge, workers)
         if collect == "all":
             return {plan.node_array[k]: v for k, v in frontier.items()}
         out = {
@@ -810,7 +765,6 @@ class QueryPlanner:
         res_lists: list[list[QueryBox]],
         nB: int,
         merge: bool,
-        timings: list[float] | None = None,
     ) -> list[QueryBox]:
         """One node's frontier: its init share plus every step's results."""
         shape = self.log.arrays[plan.node_array[key]].shape
@@ -822,18 +776,9 @@ class QueryPlanner:
         for k, q in enumerate(init.get(key, [])):
             acc_lo[k].append(q.lo)
             acc_hi[k].append(q.hi)
-        for i, ((step, choice, qs), res_list) in enumerate(
-            zip(gathered, res_lists)
-        ):
+        for (step, choice, qs), res_list in zip(gathered, res_lists):
             self._record_step_output(plan, step, res_list)
-            self._record_choice(
-                choice,
-                qs,
-                res_list,
-                plan=plan,
-                step=step,
-                elapsed=None if timings is None else timings[i],
-            )
+            self._record_choice(plan, step, choice, qs, res_list)
             for k, res in enumerate(res_list):
                 acc_lo[k].append(res.lo)
                 acc_hi[k].append(res.hi)
@@ -859,48 +804,6 @@ class QueryPlanner:
                 sp.attrs["rows"] = sum(b.n_rows for b in boxes)
         return boxes
 
-    def _compute_node(
-        self,
-        plan: QueryPlan,
-        key: str,
-        init: dict[str, list[QueryBox]],
-        frontier: dict[str, list[QueryBox]],
-        nB: int,
-        merge: bool,
-        use_batched: bool = False,
-    ) -> list[QueryBox]:
-        """One node's frontier: its init share plus every incoming step.
-
-        With ``use_batched`` the node's joins — every choice of every
-        incoming step — run as one packed executor batch; this is the
-        per-node granularity parallel mode uses (each worker packs the node
-        it owns).  Results are identical either way.
-        """
-        gathered = self._gather_requests(plan, key, frontier)
-        timings: list[float] | None = None
-        if use_batched and gathered:
-            res_lists = self._run_wave(self._requests_for(gathered))
-        else:
-            # the per-hop loop is the one engine that can time individual
-            # joins — EXPLAIN ANALYZE shows true per-hop wall time here
-            res_lists = []
-            timings = []
-            tr = self._trace()
-            for step, choice, qs in gathered:
-                with maybe_span(tr, "hop", kind="hop") as sp:
-                    t0 = time.perf_counter()
-                    res_lists.append(self._join_choice(choice, qs))
-                    timings.append(time.perf_counter() - t0)
-                    if tr is not None:
-                        sp.attrs.update(
-                            qrows=sum(q.n_rows for q in qs),
-                            pairs=sum(r.n_rows for r in res_lists[-1]),
-                            **self._hop_attrs(plan, step, choice),
-                        )
-        return self._assemble_node(
-            plan, key, init, gathered, res_lists, nB, merge, timings=timings
-        )
-
     def _execute_waves(
         self,
         plan: QueryPlan,
@@ -916,15 +819,12 @@ class QueryPlanner:
         across plan branches and, on sharded plans, across exchange-free
         per-shard sub-plans — are packed into one
         :meth:`BatchedJoinExecutor.run` dispatch.  Per-node assembly then
-        proceeds in plan order, so results are bit-identical to the serial
-        per-hop loop.
+        proceeds in plan order.
 
         ``workers=N`` hands each wave's packed dense segments to an
         N-thread pool inside the executor: the segment tasks are almost
         entirely GIL-releasing blocked numpy, which is what makes thread
-        parallelism actually pay on CPU (node-granularity threading — the
-        non-batched engine's mode — loses its win to GIL hand-offs between
-        the small Python-held assembly steps).
+        parallelism pay on CPU.
         """
         deps = {
             key: {s.u for s in plan.steps.get(key, [])} for key in plan.order
@@ -977,77 +877,6 @@ class QueryPlanner:
                 )
             return self.executor.run(reqs, workers=workers)
 
-    def _execute_parallel(
-        self,
-        plan: QueryPlan,
-        init: dict[str, list[QueryBox]],
-        nB: int,
-        merge: bool,
-        workers: int,
-    ) -> dict[str, list[QueryBox]]:
-        """Dependency-driven node-level execution on a thread pool.
-
-        The non-batched engine's parallel mode (PR 4): a node is *ready*
-        once every node feeding one of its steps has a computed frontier,
-        so non-dependent branches — and, through the sharded planner's
-        step ownership, exchange-free per-shard sub-plans — run
-        concurrently.  Within a node, incoming steps still execute in plan
-        order: per-node results are bit-identical to serial execution.
-        (With batching enabled, ``execute`` uses wave execution with
-        worker-split dense segments instead — see ``_execute_waves``.)
-        """
-        import concurrent.futures as cf
-        import threading
-
-        deps = {
-            key: {s.u for s in plan.steps.get(key, [])} for key in plan.order
-        }
-        frontier: dict[str, list[QueryBox]] = {}
-        done: set[str] = set()
-        scheduled: set[str] = set()
-        errors: list[BaseException] = []
-        cond = threading.Condition()
-        pool = cf.ThreadPoolExecutor(
-            max_workers=workers, thread_name_prefix="dslog-exec"
-        )
-
-        def schedule_ready_locked() -> None:
-            for key in plan.order:
-                if key not in scheduled and deps[key] <= done:
-                    scheduled.add(key)
-                    fut = pool.submit(
-                        self._compute_node, plan, key, init, frontier,
-                        nB, merge,
-                    )
-                    fut.add_done_callback(
-                        lambda f, key=key: on_done(key, f)
-                    )
-
-        def on_done(key: str, fut: "cf.Future") -> None:
-            # runs on the worker that finished the node: successors are
-            # submitted here, without a round trip through the main thread
-            with cond:
-                exc = fut.exception()
-                if exc is not None:
-                    errors.append(exc)
-                else:
-                    frontier[key] = fut.result()
-                    done.add(key)
-                    if not errors:
-                        schedule_ready_locked()
-                cond.notify_all()
-
-        try:
-            with cond:
-                schedule_ready_locked()
-                while len(done) < len(plan.order) and not errors:
-                    cond.wait()
-            if errors:
-                raise errors[0]
-        finally:
-            pool.shutdown(wait=True)
-        return frontier
-
     def _incoming_frontier(
         self, plan: QueryPlan, step: EdgeStep, qs: list[QueryBox]
     ) -> list[QueryBox]:
@@ -1065,26 +894,13 @@ class QueryPlanner:
         """Hook: observe one choice's per-query results (sharded planner
         uses it to meter output-side boundary exchanges)."""
 
-    def _join_choice(
-        self, choice: HopChoice, qs: list[QueryBox]
-    ) -> list[QueryBox]:
-        """The per-hop join loop: one choice, one ``theta_join_batch``."""
-        entry = self._entry(choice.lineage_id)
-        table = entry.backward if choice.stored == "backward" else entry.forward
-        if choice.frontier_on == "key":
-            return theta_join_batch(qs, table, merge=False, path=choice.route)
-        return theta_join_inverse_batch(
-            qs, table, merge=False, path=choice.route
-        )
-
     def _record_choice(
         self,
+        plan: QueryPlan,
+        step: EdgeStep,
         choice: HopChoice,
         qs: list[QueryBox],
         res: list[QueryBox],
-        plan: QueryPlan | None = None,
-        step: EdgeStep | None = None,
-        elapsed: float | None = None,
     ) -> None:
         # cost-model feedback: the true pair counts this hop produced, keyed
         # by (entry, materialization, join side) — replanning the same
@@ -1099,36 +915,21 @@ class QueryPlanner:
                 pairs=pairs,
                 qrows=qrows,
             )
-        if plan is not None and step is not None:
-            # EXPLAIN ANALYZE: accumulate the measured side against the
-            # plan's estimates (plans are memoized — totals over runs)
-            mkey = (
-                step.u,
-                step.v,
-                choice.lineage_id,
-                choice.stored,
-                choice.frontier_on,
-            )
-            with self.log._stats_lock:
-                rec = plan.measured.get(mkey)
-                if rec is None:
-                    rec = plan.measured[mkey] = {
-                        "pairs": 0,
-                        "qrows": 0,
-                        "calls": 0,
-                        "ms": 0.0,
-                        "timed": 0,
-                    }
-                rec["pairs"] += pairs
-                rec["qrows"] += qrows
-                rec["calls"] += 1
-                if elapsed is not None:
-                    rec["ms"] += elapsed * 1e3
-                    rec["timed"] += 1
+        # EXPLAIN ANALYZE: accumulate the measured side against the plan's
+        # estimates (plans are memoized — totals over runs)
+        mkey = (
+            step.u, step.v, choice.lineage_id, choice.stored, choice.frontier_on
+        )
+        with self.log._stats_lock:
+            rec = plan.measured.get(mkey)
+            if rec is None:
+                rec = plan.measured[mkey] = {"pairs": 0, "qrows": 0, "calls": 0}
+            rec["pairs"] += pairs
+            rec["qrows"] += qrows
+            rec["calls"] += 1
         tr = self._trace()
-        if tr is not None and step is not None and elapsed is None:
-            # batched engines join a whole wave at once: the hop is an
-            # instant record (the per-hop loop scopes it in _compute_node)
+        if tr is not None:
+            # a wave joins all its hops at once: the hop is an instant record
             tr.event(
                 "hop",
                 kind="hop",
@@ -1138,21 +939,11 @@ class QueryPlanner:
             )
 
     @staticmethod
-    def _hop_attrs(
-        plan: QueryPlan | None, step: EdgeStep, choice: HopChoice
-    ) -> dict:
+    def _hop_attrs(plan: QueryPlan, step: EdgeStep, choice: HopChoice) -> dict:
         return {
-            "u": plan.node_array[step.u] if plan is not None else step.u,
-            "v": plan.node_array[step.v] if plan is not None else step.v,
+            "u": plan.node_array[step.u],
+            "v": plan.node_array[step.v],
             "lid": choice.lineage_id,
             "stored": choice.stored,
             "route": choice.describe_route(),
         }
-
-    def _run_choice(
-        self, choice: HopChoice, qs: list[QueryBox]
-    ) -> list[QueryBox]:
-        """One choice's join plus its cost feedback (per-hop loop form)."""
-        res = self._join_choice(choice, qs)
-        self._record_choice(choice, qs, res)
-        return res

@@ -24,16 +24,21 @@ deployment that stores only backward tables can still serve forward queries.
 
 Join execution (``path`` parameter, default ``"auto"``)
 -------------------------------------------------------
+Every θ-join — a planned hop or a call of the functions below — runs
+through one engine, :meth:`BatchedJoinExecutor.run`, which routes each join
+with :func:`_route_decision`:
+
 * ``"index"`` — sorted candidate pruning via the per-table
   :class:`~repro.core.index.IntervalIndex` (lazily built, cached on the
   table, persisted by the catalog).  Work is proportional to the most
   selective attribute's candidate window, not ``nq × nr``.
-* ``"dense"`` — the all-pairs overlap matrix, evaluated in blocks (numpy),
-  or on TPU via the Pallas ``range_join_mask`` kernel.  Right for small
-  tables and unselective queries, where index probes buy nothing.
-* ``"auto"`` — dense for tables under ``_INDEX_MIN_ROWS`` rows; otherwise
+* ``"dense"`` (or the planner's ``"batched"``) — the all-pairs overlap
+  matrix: the segmented Pallas kernel on an accelerator, the blocked numpy
+  twin otherwise.  Right for small tables and unselective queries, where
+  index probes buy nothing.
+* ``"auto"`` — dense for tables under ``INDEX_MIN_ROWS`` rows; otherwise
   probe the index for a candidate estimate and fall back to dense when the
-  estimated candidate fraction exceeds ``_DENSE_FRACTION`` (the probe work
+  estimated candidate fraction exceeds ``DENSE_FRACTION`` (the probe work
   is two binary searches per query row per attribute — negligible).
 
 ``theta_join_batch`` answers many :class:`QueryBox`es against one table in a
@@ -51,7 +56,6 @@ from typing import Sequence
 import numpy as np
 
 from repro.kernels.autotune import (  # jax-free: geometry table + buckets
-    CANDIDATE_TWIN_CELLS,
     DEFAULT_GEOMETRY,
     DEFAULT_TWIN_CELLS,
     GeometryTuner,
@@ -88,9 +92,6 @@ DENSE_FRACTION = 0.25
 # back-compat aliases (pre-planner private names)
 _INDEX_MIN_ROWS = INDEX_MIN_ROWS
 _DENSE_FRACTION = DENSE_FRACTION
-# Hand the dense path to the Pallas kernel only when a real accelerator is
-# attached; in interpret mode the blocked numpy evaluation is faster.
-_KERNEL_MIN_PAIRS = 1 << 20
 
 
 @dataclass
@@ -166,79 +167,20 @@ class QueryBox:
 # --------------------------------------------------------------------------- #
 # Range-join pair enumeration (indexed / dense routing)
 # --------------------------------------------------------------------------- #
-def _dense_pairs(
-    q_lo: np.ndarray,
-    q_hi: np.ndarray,
-    r_lo: np.ndarray,
-    r_hi: np.ndarray,
-) -> tuple[np.ndarray, np.ndarray]:
-    """All-pairs overlap join, blocked to bound the pair matrix."""
-    nq, l = q_lo.shape
-    nr = r_lo.shape[0]
-    if nq * nr >= _KERNEL_MIN_PAIRS:
-        pairs = _kernel_pairs(q_lo, q_hi, r_lo, r_hi)
-        if pairs is not None:
-            return pairs
-    qi_list, ri_list = [], []
-    block = max(1, int(4_000_000 // max(nr, 1)))
-    for s in range(0, nq, block):
-        e = min(nq, s + block)
-        ov = np.ones((e - s, nr), dtype=bool)
-        for j in range(l):
-            ov &= (q_lo[s:e, j : j + 1] <= r_hi[None, :, j]) & (
-                r_lo[None, :, j] <= q_hi[s:e, j : j + 1]
-            )
-        qi, ri = np.nonzero(ov)
-        qi_list.append(qi + s)
-        ri_list.append(ri)
-    qi = np.concatenate(qi_list) if qi_list else np.zeros(0, np.int64)
-    ri = np.concatenate(ri_list) if ri_list else np.zeros(0, np.int64)
-    return qi, ri
-
-
-def _kernel_pairs(q_lo, q_hi, r_lo, r_hi):
-    """Pallas ``range_join_mask`` dense fallback — only off interpret mode.
-
-    Returns ``None`` when the kernel path is unavailable or cannot express
-    the join faithfully (no accelerator, too many attributes for one tile,
-    coordinates outside the int32 pack range — they would silently wrap —
-    or jax missing), so the caller falls through to blocked numpy.  Genuine
-    kernel failures on an accelerator propagate — silently degrading to
-    numpy would hide them.
-    """
-    try:
-        from repro.kernels.ops import (
-            LANES,
-            default_interpret,
-            fits_int32,
-            range_join_pairs,
-        )
-    except ImportError:
-        return None
-    if default_interpret() or 2 * q_lo.shape[1] > LANES:
-        return None
-    if not fits_int32(q_lo, q_hi, r_lo, r_hi):
-        return None
-    return range_join_pairs(q_lo, q_hi, r_lo, r_hi)
-
-
-def dense_backend(
-    n_attrs: int, int32_ok: bool = True, segmented: bool = True
-) -> str:
+def dense_backend(n_attrs: int, int32_ok: bool = True) -> str:
     """Which engine a dense join of ``n_attrs`` attributes would run on.
 
     ``"tpu"`` when the Pallas kernel applies, else a ``"np:*"`` reason
-    (``np:cpu`` interpret mode, ``np:wide`` lane capacity — for
-    ``segmented`` joins the batched pack's segment lane counts too,
-    ``np:i64`` int32 overflow, ``np:nojax``).  Rendered into
-    ``plan.describe()`` so dense-route fallbacks are visible instead of
-    silent.
+    (``np:cpu`` interpret mode, ``np:wide`` lane capacity — the packed
+    segment id's lane counts too, ``np:i64`` int32 overflow,
+    ``np:nojax``).  Rendered into ``plan.describe()`` so dense-route
+    fallbacks are visible instead of silent.
     """
     try:
         from repro.kernels.ops import LANES, default_interpret
     except ImportError:
         return "np:nojax"
-    if 2 * (n_attrs + (1 if segmented else 0)) > LANES:
+    if 2 * (n_attrs + 1) > LANES:
         return "np:wide"
     if not int32_ok:
         return "np:i64"
@@ -258,9 +200,8 @@ def _route_decision(
     """Shared indexed-vs-dense routing: ``("dense", None)`` or
     ``("index", windows)``.
 
-    ``path="batched"`` is the planner's batched-dense route: the same dense
-    decision, executed through the packed :class:`BatchedJoinExecutor`
-    engine when one is driving the joins.  ``index_get`` is a zero-arg
+    ``path="batched"`` is the planner's name for the dense route.
+    ``index_get`` is a zero-arg
     callable returning the (cached) :class:`IntervalIndex` — deferred so
     the dense route never builds one.
     """
@@ -281,26 +222,6 @@ def _route_decision(
     return "index", windows
 
 
-def _route_pairs(
-    q_lo: np.ndarray,
-    q_hi: np.ndarray,
-    r_lo: np.ndarray,
-    r_hi: np.ndarray,
-    index_get,
-    path: str,
-) -> tuple[np.ndarray, np.ndarray]:
-    """Pick indexed vs dense execution for one range join."""
-    nq, nr = q_lo.shape[0], r_lo.shape[0]
-    if nq == 0 or nr == 0:
-        if path not in ("auto", "index", "dense", "batched"):
-            raise ValueError(f"unknown join path {path!r}")
-        return np.zeros(0, np.int64), np.zeros(0, np.int64)
-    route, windows = _route_decision(q_lo, q_hi, r_lo, r_hi, index_get, path)
-    if route == "dense":
-        return _dense_pairs(q_lo, q_hi, r_lo, r_hi)
-    return index_get().candidate_pairs(q_lo, q_hi, windows)
-
-
 def _derelativize(
     table: CompressedTable,
     qi: np.ndarray,
@@ -318,46 +239,6 @@ def _derelativize(
             out_lo[sel] += np.broadcast_to(inter_lo[:, j : j + 1], sel.shape)[sel]
             out_hi[sel] += np.broadcast_to(inter_hi[:, j : j + 1], sel.shape)[sel]
     return out_lo, out_hi
-
-
-# --------------------------------------------------------------------------- #
-# θ-join
-# --------------------------------------------------------------------------- #
-def theta_join(
-    q: QueryBox,
-    table: CompressedTable,
-    merge: bool = True,
-    max_rows: int | None = None,
-    path: str = "auto",
-) -> QueryBox:
-    """One hop: query over the table's *key* side, returning value-side boxes."""
-    if q.shape != table.key_shape:
-        raise ValueError(
-            f"query shape {q.shape} does not match table key shape {table.key_shape}"
-        )
-    if table.is_symbolic:
-        raise ValueError("instantiate symbolic table before querying")
-    m = table.n_val
-    nq, nr = q.n_rows, table.n_rows
-    if nq == 0 or nr == 0:
-        return QueryBox(table.val_shape, np.zeros((0, m)), np.zeros((0, m)))
-
-    # ---- Step 1: range join --------------------------------------------- #
-    qi, ri = _route_pairs(
-        q.lo, q.hi, table.key_lo, table.key_hi, table.key_index, path
-    )
-    if max_rows is not None and qi.size > max_rows:
-        raise RuntimeError(f"θ-join intermediate exceeded max_rows={max_rows}")
-    if qi.size == 0:
-        return QueryBox(table.val_shape, np.zeros((0, m)), np.zeros((0, m)))
-
-    inter_lo = np.maximum(q.lo[qi], table.key_lo[ri])  # [P, l]
-    inter_hi = np.minimum(q.hi[qi], table.key_hi[ri])
-
-    # ---- Step 2: de-relativize ------------------------------------------ #
-    out_lo, out_hi = _derelativize(table, qi, ri, inter_lo, inter_hi)
-    res = QueryBox(table.val_shape, out_lo, out_hi)
-    return merge_boxes(res) if merge else res
 
 
 def _inverse_key_boxes(
@@ -384,42 +265,6 @@ def _inverse_key_boxes(
             key_hi[jm, j] = np.minimum(key_hi[jm, j], cand_hi)
     valid = np.all(key_lo <= key_hi, axis=1)
     return key_lo, key_hi, valid
-
-
-def theta_join_inverse(
-    q: QueryBox, table: CompressedTable, merge: bool = True, path: str = "auto"
-) -> QueryBox:
-    """Query over the table's *value* side, returning key-side boxes.
-
-    This is the paper's ``rel_for`` path: for a value attr relative to key
-    ``j`` the constraint ``val = key_j + δ, δ ∈ [dlo, dhi]`` inverts to
-    ``key_j ∈ [q_lo − dhi, q_hi − dlo]``, clamped by the stored key interval
-    (the ``r.x`` term in the paper's formula).
-
-    Candidate pruning runs over the table's *achievable value bounds*
-    (``[key_lo_j + dlo, key_hi_j + dhi]`` for relative attrs, the stored
-    interval for absolute ones): a row can contribute iff the query box
-    overlaps those bounds on every value attribute, which is exactly the
-    range-join predicate — so the same index machinery applies.
-    """
-    if q.shape != table.val_shape:
-        raise ValueError(
-            f"query shape {q.shape} does not match table val shape {table.val_shape}"
-        )
-    if table.is_symbolic:
-        raise ValueError("instantiate symbolic table before querying")
-    l = table.n_key
-    nq, nr = q.n_rows, table.n_rows
-    if nq == 0 or nr == 0:
-        return QueryBox(table.key_shape, np.zeros((0, l)), np.zeros((0, l)))
-
-    vb_lo, vb_hi = table.value_bounds()
-    qi, ri = _route_pairs(q.lo, q.hi, vb_lo, vb_hi, table.val_index, path)
-    if qi.size == 0:
-        return QueryBox(table.key_shape, np.zeros((0, l)), np.zeros((0, l)))
-    key_lo, key_hi, valid = _inverse_key_boxes(q, table, qi, ri)
-    res = QueryBox(table.key_shape, key_lo[valid], key_hi[valid])
-    return merge_boxes(res) if merge else res
 
 
 # --------------------------------------------------------------------------- #
@@ -468,7 +313,7 @@ def _scatter_to_owners(
 def _prepare_batch(
     queries: Sequence[QueryBox], table: CompressedTable, inverse: bool
 ):
-    """Validate + pool one batched join; shared with the batched executor.
+    """Validate + pool one join request (the executor's first step).
 
     Returns ``("done", results)`` for trivially-empty joins, else
     ``("join", u_lo, u_hi, inv, r_lo, r_hi, index_get)`` where the ``r``
@@ -537,48 +382,6 @@ def _finalize_batch(
     )
 
 
-def theta_join_batch(
-    queries: Sequence[QueryBox],
-    table: CompressedTable,
-    merge: bool = True,
-    path: str = "auto",
-) -> list[QueryBox]:
-    """Answer many queries against one table in a single pass.
-
-    All query rows are pooled and deduplicated, so a box shared by several
-    queries probes the index (or the dense matrix) exactly once; the pair
-    outputs are computed once per *distinct* (box, table row) pair and then
-    scattered back to the owning queries.
-    """
-    pre = _prepare_batch(queries, table, inverse=False)
-    if pre[0] == "done":
-        return pre[1]
-    _, u_lo, u_hi, inv, r_lo, r_hi, index_get = pre
-    ui, ri = _route_pairs(u_lo, u_hi, r_lo, r_hi, index_get, path)
-    return _finalize_batch(queries, table, False, u_lo, u_hi, inv, ui, ri, merge)
-
-
-def theta_join_inverse_batch(
-    queries: Sequence[QueryBox],
-    table: CompressedTable,
-    merge: bool = True,
-    path: str = "auto",
-) -> list[QueryBox]:
-    """Batched :func:`theta_join_inverse`: many value-side queries, one pass.
-
-    Same pooling/dedup/scatter machinery as :func:`theta_join_batch`, with
-    the candidate pruning running over the table's achievable value bounds
-    and the per-pair key-interval inversion (plus its joint-validity check)
-    done once per *distinct* (box, row) pair.
-    """
-    pre = _prepare_batch(queries, table, inverse=True)
-    if pre[0] == "done":
-        return pre[1]
-    _, u_lo, u_hi, inv, r_lo, r_hi, index_get = pre
-    ui, ri = _route_pairs(u_lo, u_hi, r_lo, r_hi, index_get, path)
-    return _finalize_batch(queries, table, True, u_lo, u_hi, inv, ui, ri, merge)
-
-
 # --------------------------------------------------------------------------- #
 # Batched accelerator execution of plan steps
 # --------------------------------------------------------------------------- #
@@ -599,11 +402,10 @@ class JoinRequest:
     path: str = "auto"
 
 
-# autotuning thresholds: frontiers below these run the default geometry —
-# measuring candidates costs extra dispatches, which only amortize when the
-# workload itself is big enough to show a geometry's effect
-_TUNE_MIN_ROWS = 2048  # kernel path: packed q+r rows across the frontier
-_TWIN_TUNE_MIN_CELLS = 1 << 22  # twin: mask cells of the largest segment
+# autotuning threshold: frontiers of fewer packed q+r rows run the default
+# geometry — measuring candidates costs extra dispatches, which only
+# amortize when the workload itself is big enough to show a geometry's effect
+_TUNE_MIN_ROWS = 2048
 
 
 def _twin_pairs(
@@ -624,9 +426,8 @@ def _twin_pairs(
     in-place ufuncs.  Pair extraction runs on the raveled mask
     (``flatnonzero`` + divmod — numpy's 2-D nonzero pays an order of
     magnitude more on sparse masks).  All heavy work happens inside numpy
-    inner loops, which drop the GIL — this is what lets thread-pool plan
-    execution actually overlap on CPU.  Pair order is row-major, identical
-    to :func:`_dense_pairs`.
+    inner loops, which drop the GIL — this is what lets the executor's
+    worker fan-out overlap on CPU.  Pair order is row-major.
     """
     nq, l = q_lo.shape
     nr = rl.shape[1]
@@ -642,8 +443,7 @@ def _twin_pairs(
         qdt = np.int64
     qlt = np.ascontiguousarray(q_lo.T, dtype=qdt)  # [l, nq]
     qht = np.ascontiguousarray(q_hi.T, dtype=qdt)
-    # block_cells is the twin's launch geometry (mask cells per row block);
-    # the executor's GeometryTuner picks it per frontier-shape bucket
+    # block_cells is the twin's launch geometry (mask cells per row block)
     block = max(1, int(block_cells // max(nr, 1)))
     rows = min(block, nq)
     if scratch is None:
@@ -684,38 +484,38 @@ def _twin_pairs(
 class BatchedJoinExecutor:
     """Pack a plan frontier's dense θ-joins into one blocked evaluation.
 
-    The planner hands every :class:`JoinRequest` ready in a frontier —
-    across plan branches and, on sharded stores, across exchange-free
-    sub-plans — to :meth:`run`.  Index-routed requests execute through the
-    per-table :class:`IntervalIndex` as before; every dense-routed request
-    becomes one *segment* of a single packed ``[NQ, 128] × [NR, 128]``
-    evaluation:
+    The one θ-join engine: the planner hands every :class:`JoinRequest`
+    ready in a frontier — across plan branches and, on sharded stores,
+    across exchange-free sub-plans — to :meth:`run`, and the functional
+    API (:func:`theta_join` and kin) hands it one request per call.
+    Index-routed requests execute through the per-table
+    :class:`IntervalIndex`; every dense-routed request becomes one
+    *segment* of a single packed ``[NQ, 128] × [NR, 128]`` evaluation:
 
     * on an accelerator, one :func:`repro.kernels.ops.segmented_range_join_pairs`
       launch — segment ids in the spare lanes keep per-step masks separable,
       so the whole frontier costs one kernel dispatch instead of one per hop;
     * in interpret/CPU mode, the GIL-releasing blocked-numpy twin
       (:func:`_twin_pairs`) over the tables' cached contiguous int32
-      columns — same pair lists bit-for-bit, and thread-pool workers in
-      ``planner._execute_parallel`` finally overlap because the hot loops
-      run outside the GIL.
+      columns — same pair lists bit-for-bit, with a fixed mask-block
+      budget (``DEFAULT_TWIN_CELLS``); ``workers`` threads overlap its
+      segments because the hot loops run outside the GIL.
 
     Segments the kernel cannot express faithfully (lane capacity, int32
     overflow — see the ``np:*`` notes in ``plan.describe()``) route to the
-    twin automatically.  Results are bit-identical to the serial per-hop
-    loop; ``stats`` (an ``io_stats`` bump callable) meters launches, batch
-    occupancy, and the tile schedule (``batch_tiles_visited`` vs the
-    cross-product tiles the block-diagonal layout ``batch_tiles_skipped``).
+    twin automatically.  ``stats`` (an ``io_stats`` bump callable) meters
+    launches, batch occupancy, and the tile schedule
+    (``batch_tiles_visited`` vs the cross-product tiles the block-diagonal
+    layout ``batch_tiles_skipped``).
 
-    Launch geometry comes from a :class:`~repro.kernels.autotune.
-    GeometryTuner` (``tuner``; the store's persisted table when the planner
-    creates the executor): on the first big frontier of a new (backend,
-    shape-bucket) combination the candidates are measured in place and the
-    winner cached — ``(block_q, block_r)`` tiles for the kernel path, the
-    mask-block cell budget for the twin.  ``engine`` pins the dense engine
-    for tests/benchmarks: ``"kernel"`` forces the segmented Pallas path
-    (interpreted when no TPU is attached), ``"twin"`` the numpy path,
-    ``None`` picks by backend as before.
+    The kernel's launch geometry comes from a :class:`~repro.kernels.
+    autotune.GeometryTuner` (``tuner``; the store's persisted table when
+    the planner creates the executor): on the first big frontier of a new
+    (backend, shape-bucket) combination the ``(block_q, block_r)``
+    candidates are measured in place and the winner cached.  ``engine``
+    pins the dense engine for tests/benchmarks: ``"kernel"`` forces the
+    segmented Pallas path (interpreted when no TPU is attached), ``"twin"``
+    the numpy path, ``None`` picks by backend.
     """
 
     def __init__(
@@ -743,8 +543,8 @@ class BatchedJoinExecutor:
         # pair cells) over dense dispatches — the planner's batched-route
         # discount scales by this instead of assuming perfect packing
         self._tile_waste = 1.0
-        # most recent launch geometry per engine family, for plan notes
-        self._last_geometry: dict[str, tuple[int, ...]] = {}
+        # most recent kernel launch geometry, for plan notes
+        self._last_geometry: tuple[int, ...] = DEFAULT_GEOMETRY
 
     @property
     def measured_waste(self) -> float:
@@ -761,13 +561,14 @@ class BatchedJoinExecutor:
         """Launch-geometry annotation for ``plan.describe()`` hop notes.
 
         ``256x256``-style tile shapes for the kernel path (``backend ==
-        "tpu"``), the twin's mask-block budget (``4m`` cells) otherwise —
-        the most recently used geometry, or the default before any dispatch.
+        "tpu"``: the most recently used geometry, or the default before any
+        dispatch), the twin's fixed mask-block budget (``4m`` cells)
+        otherwise.
         """
         if backend == "tpu":
-            bq, br = self._last_geometry.get("kernel", DEFAULT_GEOMETRY)
+            bq, br = self._last_geometry
             return f"{bq}x{br}"
-        (cells,) = self._last_geometry.get("np", DEFAULT_TWIN_CELLS)
+        (cells,) = DEFAULT_TWIN_CELLS
         return f"{cells >> 20}m" if cells >= 1 << 20 else f"{cells >> 10}k"
 
     def _workers(self, width: int):
@@ -939,7 +740,7 @@ class BatchedJoinExecutor:
                     finalize(k, ui, ri)
                 # a ladder launch may have run another geometry's program
                 geom = info["geometry"]
-                self._last_geometry["kernel"] = tuple(geom)
+                self._last_geometry = tuple(geom)
                 self._stats("kernel_launches", info["launches"])
                 self._stats("joins_packed", len(kernel_idx))
                 self._stats("batch_rows", info["rows"])
@@ -988,51 +789,6 @@ class BatchedJoinExecutor:
         visited = sum(q * r for q, r in zip(seg_qb, seg_rb))
         skipped = max(0, sum(seg_qb) * sum(seg_rb) - visited)
 
-        # twin launch geometry (mask cells per row block): cached per
-        # frontier-shape bucket; an unseen bucket with a big enough lead
-        # segment measures the candidates on that segment and keeps the
-        # winner's pairs
-        block_cells = DEFAULT_TWIN_CELLS[0]
-        twin_shapes = [
-            (items[k][2].shape[0], items[k][5].shape[0], items[k][2].shape[1])
-            for k in rest
-        ]
-        twin_bucket = shape_bucket(twin_shapes)
-        twin_geom = self._tuner.lookup("np", twin_bucket)
-        if twin_geom is None:
-            k_big = max(
-                rest, key=lambda k: items[k][2].shape[0] * items[k][5].shape[0]
-            )
-            big_cells = items[k_big][2].shape[0] * items[k_big][5].shape[0]
-            if big_cells >= _TWIN_TUNE_MIN_CELLS:
-                _i, req, u_lo, u_hi, _inv, _r_lo, _r_hi = items[k_big]
-                rl_b, rh_b = req.table.dense_join_cols(
-                    "value" if req.inverse else "key"
-                )
-                twin_geom, res = self._tuner.pick(
-                    "np",
-                    twin_bucket,
-                    runner=lambda g: _twin_pairs(
-                        u_lo, u_hi, rl_b, rh_b, None, block_cells=g[0]
-                    ),
-                    candidates=CANDIDATE_TWIN_CELLS,
-                    default=DEFAULT_TWIN_CELLS,
-                    warmup=False,  # pure numpy: nothing to compile
-                )
-                if self._metrics is not None:
-                    self._metrics.inc(
-                        "autotune_decisions",
-                        backend="np",
-                        bucket=str(twin_bucket),
-                    )
-                if res is not None:
-                    pairs[k_big] = res
-            else:
-                twin_geom = DEFAULT_TWIN_CELLS
-        block_cells = twin_geom[0]
-        self._last_geometry["np"] = tuple(twin_geom)
-        todo = [k for k in rest if k not in pairs]
-
         def eval_segments(chunk: list[int]) -> None:
             scratch: dict = {}  # mask buffers shared within the chunk
             for k in chunk:
@@ -1040,14 +796,12 @@ class BatchedJoinExecutor:
                 rl, rh = req.table.dense_join_cols(
                     "value" if req.inverse else "key"
                 )
-                pairs[k] = _twin_pairs(
-                    u_lo, u_hi, rl, rh, scratch, block_cells=block_cells
-                )
+                pairs[k] = _twin_pairs(u_lo, u_hi, rl, rh, scratch)
 
         # clamp fan-out to real cores: the chunks only overlap while they
         # hold no GIL, and oversubscribing 2 cores with 4 GIL-trading
         # threads costs more in hand-offs than it buys
-        width = min(workers or 1, len(todo), os.cpu_count() or 1)
+        width = min(workers or 1, len(rest), os.cpu_count() or 1)
         if width > 1:
             # fan only the *mask evaluations* out — the twin's blocked
             # passes are almost pure released-GIL numpy, so they overlap on
@@ -1060,7 +814,7 @@ class BatchedJoinExecutor:
             chunks: list[list[int]] = [[] for _ in range(width)]
             loads = [0] * width
             for k in sorted(
-                todo,
+                rest,
                 key=lambda k: -items[k][2].shape[0] * items[k][5].shape[0],
             ):
                 w = loads.index(min(loads))
@@ -1074,7 +828,7 @@ class BatchedJoinExecutor:
             for f in futs:
                 f.result()
         else:
-            eval_segments(todo)
+            eval_segments(rest)
         for k in rest:
             finalize(k, *pairs[k])
         # the twin is one fused dispatch per frontier: count it like a
@@ -1089,17 +843,83 @@ class BatchedJoinExecutor:
         n_pairs = sum(pairs[k][0].size for k in rest)
         self._stats("join_pairs", n_pairs)
         # per-segment evaluation has no tile padding: cells-exact occupancy
-        useful = float(sum(nq * nr for nq, nr, _ in twin_shapes))
+        useful = float(
+            sum(items[k][2].shape[0] * items[k][5].shape[0] for k in rest)
+        )
         self._observe_occupancy(useful, useful)
         if tr is not None:
             sp.attrs.update(
                 backend="np",
                 segments=len(rest),
                 rows=rows,
-                block_cells=block_cells,
+                block_cells=DEFAULT_TWIN_CELLS[0],
                 workers=width,
                 pairs=n_pairs,
             )
+
+
+# --------------------------------------------------------------------------- #
+# θ-join API: one-table joins through the executor
+# --------------------------------------------------------------------------- #
+# one process-wide launch-geometry table for the functional API, so a
+# repeated call never re-measures the geometries an earlier one settled
+_TUNER = GeometryTuner()
+
+
+def theta_join_batch(
+    queries: Sequence[QueryBox],
+    table: CompressedTable,
+    merge: bool = True,
+    path: str = "auto",
+) -> list[QueryBox]:
+    """Answer many queries over the table's *key* side in a single pass.
+
+    All query rows are pooled and deduplicated, so a box shared by several
+    queries probes the index (or the dense matrix) exactly once; the pair
+    outputs are computed once per *distinct* (box, table row) pair and then
+    scattered back to the owning queries.
+    """
+    req = JoinRequest(list(queries), table, merge=merge, path=path)
+    return BatchedJoinExecutor(tuner=_TUNER).run([req])[0]
+
+
+def theta_join_inverse_batch(
+    queries: Sequence[QueryBox],
+    table: CompressedTable,
+    merge: bool = True,
+    path: str = "auto",
+) -> list[QueryBox]:
+    """Answer many queries over the table's *value* side, returning key-side
+    boxes, in a single pass.
+
+    This is the paper's ``rel_for`` path: for a value attr relative to key
+    ``j`` the constraint ``val = key_j + δ, δ ∈ [dlo, dhi]`` inverts to
+    ``key_j ∈ [q_lo − dhi, q_hi − dlo]``, clamped by the stored key interval
+    (the ``r.x`` term in the paper's formula).
+
+    Candidate pruning runs over the table's *achievable value bounds*
+    (``[key_lo_j + dlo, key_hi_j + dhi]`` for relative attrs, the stored
+    interval for absolute ones): a row can contribute iff the query box
+    overlaps those bounds on every value attribute, which is exactly the
+    range-join predicate — so the same index machinery applies.
+    """
+    req = JoinRequest(list(queries), table, inverse=True, merge=merge, path=path)
+    return BatchedJoinExecutor(tuner=_TUNER).run([req])[0]
+
+
+def theta_join(
+    q: QueryBox, table: CompressedTable, merge: bool = True, path: str = "auto"
+) -> QueryBox:
+    """One hop: query over the table's *key* side, returning value-side boxes."""
+    return theta_join_batch([q], table, merge=merge, path=path)[0]
+
+
+def theta_join_inverse(
+    q: QueryBox, table: CompressedTable, merge: bool = True, path: str = "auto"
+) -> QueryBox:
+    """One hop: query over the table's *value* side, returning key-side
+    boxes (see :func:`theta_join_inverse_batch`)."""
+    return theta_join_inverse_batch([q], table, merge=merge, path=path)[0]
 
 
 # --------------------------------------------------------------------------- #

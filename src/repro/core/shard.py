@@ -373,17 +373,13 @@ class ShardedQueryPlanner(QueryPlanner):
     actually cross boundaries at execution time.
     """
 
-    def plan(
-        self, sources, targets, frontier=None, batched=None
-    ) -> ShardedQueryPlan:
+    def plan(self, sources, targets, frontier=None) -> ShardedQueryPlan:
         return self._shardify(
-            QueryPlanner.plan(self, sources, targets, frontier, batched)
+            QueryPlanner.plan(self, sources, targets, frontier)
         )
 
-    def plan_path(self, path, frontier=None, batched=None) -> ShardedQueryPlan:
-        return self._shardify(
-            QueryPlanner.plan_path(self, path, frontier, batched)
-        )
+    def plan_path(self, path, frontier=None) -> ShardedQueryPlan:
+        return self._shardify(QueryPlanner.plan_path(self, path, frontier))
 
     # ------------------------------------------------------------------ #
     def _shardify(self, base: QueryPlan) -> ShardedQueryPlan:
@@ -453,7 +449,7 @@ class ShardedQueryPlanner(QueryPlanner):
             return qs
         shipped = [merge_boxes(q) for q in qs]  # prune before crossing
         n = sum(q.n_rows for q in shipped)
-        with self.log._stats_lock:  # parallel sub-plans meter concurrently
+        with self.log._stats_lock:  # concurrent queries meter concurrently
             ex.shipped_boxes += n
         self.log._bump("boxes_exchanged", n)
         self._meter_exchange(ex, n)
@@ -566,8 +562,8 @@ class ShardedDSLog:
         self._array_shard: dict[str, int] = {}
         self._lid_shard: dict[int, int] = {}
         self._stats_lock = _locks.new_rlock("shard._stats_lock")
-        # guards lazy shard loading: parallel plan execution may race two
-        # worker threads onto the same cold shard
+        # guards lazy shard loading: concurrent queries may race two
+        # threads onto the same cold shard
         self._shard_load_lock = _locks.new_lock("shard._shard_load_lock")
         self._shards: list[DSLog | None] = _locks.guard_sequence(
             [None] * self.n_shards, self._shard_load_lock, "ShardedDSLog._shards"
@@ -653,7 +649,7 @@ class ShardedDSLog:
         sh = self._shards[shard]
         if sh is not None:
             return sh
-        with self._shard_load_lock:  # parallel execution races cold shards
+        with self._shard_load_lock:  # concurrent queries race cold shards
             sh = self._shards[shard]
             if sh is not None:
                 return sh

@@ -28,7 +28,7 @@ import numpy as np
 
 from . import _locks
 from .intervals import unique_rows
-from .query import QueryBox, _route_pairs, merge_boxes
+from .query import QueryBox, _route_decision, _twin_pairs, merge_boxes
 from .table import CompressedTable, TableHandle
 
 __all__ = [
@@ -109,7 +109,13 @@ def compose_tables(
                 "rows with duplicate key references are not composable"
             )
     vb_lo, vb_hi = A.value_bounds()
-    ai, bi = _route_pairs(vb_lo, vb_hi, B.key_lo, B.key_hi, B.key_index, "auto")
+    route, windows = _route_decision(
+        vb_lo, vb_hi, B.key_lo, B.key_hi, B.key_index, "auto"
+    )
+    if route == "index":
+        ai, bi = B.key_index().candidate_pairs(vb_lo, vb_hi, windows)
+    else:
+        ai, bi = _twin_pairs(vb_lo, vb_hi, *B.dense_join_cols("key"))
     n_pairs = int(ai.size)
     if max_rows is not None and n_pairs > max_rows:
         raise CompositionError(
@@ -476,13 +482,13 @@ class ViewManager:
     # ------------------------------------------------------------------ #
     _PLAN_MEMO_CAP = 64
 
-    def plan_get(self, src: str, targets: list[str], batched):
+    def plan_get(self, src: str, targets: list[str]):
         """A memoized plan for this route, or None.  Replays the view-race
         stat the original planning pass recorded and touches the view's
         LRU slot, so memo hits age views exactly like planned hits."""
         if not self.enabled:
             return None
-        key = (src, tuple(targets), batched)
+        key = (src, tuple(targets))
         with self._lock:
             hit = self._plans.get(key)
             if hit is None:
@@ -496,7 +502,7 @@ class ViewManager:
             self.shortcut_for(*route)  # keeps the view warm for eviction
         return plan
 
-    def plan_put(self, src: str, targets: list[str], batched, plan) -> None:
+    def plan_put(self, src: str, targets: list[str], plan) -> None:
         if not self.enabled:
             return
         uses_view = any(
@@ -510,7 +516,7 @@ class ViewManager:
             stat, route = "view_hits", (src, targets[0])
         elif len(targets) == 1 and self.shortcut_for(src, targets[0]):
             stat = "view_misses"
-        key = (src, tuple(targets), batched)
+        key = (src, tuple(targets))
         with self._lock:
             self._plans[key] = (plan, stat, route)
             while len(self._plans) > self._PLAN_MEMO_CAP:

@@ -1,8 +1,7 @@
-"""Launch-geometry autotuner for the batched dense-join engines.
+"""Launch-geometry autotuner for the batched dense-join kernels.
 
-The segmented kernels take a ``(block_q, block_r)`` launch geometry and the
-blocked-numpy twin a mask-block budget (cells per row block); the best
-values depend on the backend (TPU Pallas vs interpret vs numpy) and on the
+The segmented kernels take a ``(block_q, block_r)`` launch geometry; the
+best values depend on the backend (TPU Pallas vs interpret) and on the
 *shape* of the frontier being joined (many tiny segments want small tiles,
 few big segments want big ones).  :class:`GeometryTuner` measures candidate
 geometries the first time a (backend, frontier-shape bucket) combination is
@@ -34,7 +33,6 @@ __all__ = [
     "DEFAULT_GEOMETRY",
     "CANDIDATE_GEOMETRIES",
     "DEFAULT_TWIN_CELLS",
-    "CANDIDATE_TWIN_CELLS",
 ]
 
 # kernel-launch geometry: (block_q, block_r) tile shapes.  Second-minor dim
@@ -50,10 +48,9 @@ CANDIDATE_GEOMETRIES = (
     (512, 256),
 )
 
-# numpy-twin geometry: mask cells evaluated per row block (the twin's only
-# launch knob — trades scratch-buffer locality against ufunc call overhead)
+# numpy-twin geometry: mask cells evaluated per row block (fixed; the twin
+# is the CPU path and never runs where a launch geometry is measured)
 DEFAULT_TWIN_CELLS = (4_194_304,)
-CANDIDATE_TWIN_CELLS = ((1_048_576,), (4_194_304,), (16_777_216,))
 
 _TABLE_VERSION = 2  # 2: query rows bucketed by ladder step, not by power of two
 
@@ -126,8 +123,8 @@ class GeometryTuner:
     ``pick`` is the one-stop API: cached winner when known, otherwise (if a
     ``runner`` is supplied) measure every candidate on the real workload and
     cache the winner.  Geometries are opaque int tuples — ``(block_q,
-    block_r)`` for the kernels, ``(block_cells,)`` for the numpy twin — so
-    one table serves both engines.
+    block_r)`` for the kernels — so the table holds any backend's entries
+    (an older store's ``np`` twin entries still load; nothing reads them).
     """
 
     def __init__(self) -> None:

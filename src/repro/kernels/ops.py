@@ -141,11 +141,10 @@ def range_join_pairs(
 ) -> tuple[np.ndarray, np.ndarray]:
     """All (query row, table row) index pairs whose boxes overlap.
 
-    Kernel-accelerated replacement for the broadcasting pass inside
-    ``repro.core.query.theta_join``.  Raises for joins the kernel cannot
-    express faithfully (lane capacity, int32 overflow) — the caller's
-    routing (``repro.core.query._kernel_pairs``) checks the same gates and
-    falls back to numpy before ever reaching this point.
+    The non-segmented form of :func:`segmented_range_join_pairs`, which
+    is what the query engine launches; this one is an oracle for tests and
+    benchmarks.  Raises for joins the kernel cannot express faithfully (lane
+    capacity, int32 overflow).
     """
     if interpret is None:
         interpret = default_interpret()

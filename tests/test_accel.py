@@ -1,10 +1,12 @@
 """Batched accelerator execution of plan steps (ISSUE 5).
 
 The contract under test: the :class:`BatchedJoinExecutor` — packing a plan
-frontier's dense joins into one blocked evaluation — returns **bit-identical**
-results to the serial per-hop join loop, for DSLog and ShardedDSLog, serial
-and ``parallel=N``; and the Pallas dense path's padding, int32-overflow, and
-lane-capacity limits are enforced instead of silently wrong.
+frontier's dense joins into one blocked evaluation — returns the answers of
+independent oracles (a brute-force overlap join per request; the recorded
+uncompressed relations composed along the DAG per query), for DSLog and
+ShardedDSLog, serial and ``parallel=N``; and the Pallas dense path's
+padding, int32-overflow, and lane-capacity limits are enforced instead of
+silently wrong.
 """
 
 import numpy as np
@@ -17,14 +19,21 @@ from repro.core.query import (
     JoinRequest,
     QueryBox,
     dense_backend,
+    merge_boxes,
     theta_join,
-    theta_join_batch,
     theta_join_inverse_batch,
 )
 from repro.core.shard import ShardedDSLog
 from repro.core.table import CompressedTable
 
-from test_shard import SHAPE, SIDE, _build_random_dag
+from test_shard import (
+    SHAPE,
+    SIDE,
+    _assert_oracle_answer,
+    _build_random_dag,
+    _oracle_cells,
+    _oracle_path_cells,
+)
 
 rng = np.random.default_rng(11)
 
@@ -64,8 +73,65 @@ def _assert_boxes_equal(a, b):
     assert a.hi.tobytes() == b.hi.tobytes()
 
 
+def _brute_join(q: QueryBox, t: CompressedTable, inverse: bool) -> QueryBox:
+    """Reference θ-join, one query row at a time against every table row.
+
+    Natural join: rows whose key box meets the query row, value intervals
+    de-relativized onto the key intersection.  Inverse join: each relative
+    value attribute ``val = key_ref + δ`` bounds its key attribute by
+    ``[q_lo − δ_hi, q_hi − δ_lo]``, an absolute one must meet the query;
+    rows whose bounded key box stays non-empty contribute it.
+    """
+    out_shape = t.key_shape if inverse else t.val_shape
+    n_out = t.n_key if inverse else t.n_val
+    key_lo, key_hi = t.key_lo.astype(np.int64), t.key_hi.astype(np.int64)
+    val_lo, val_hi = t.val_lo.astype(np.int64), t.val_hi.astype(np.int64)
+    los, his = [np.zeros((0, n_out), np.int64)], [np.zeros((0, n_out), np.int64)]
+    for i in range(q.n_rows):
+        if inverse:
+            lo, hi = key_lo.copy(), key_hi.copy()
+            ok = np.ones(t.n_rows, bool)
+            for a in range(t.n_val):
+                ref = t.val_ref[:, a]
+                ok &= (ref >= 0) | (
+                    (val_lo[:, a] <= q.hi[i, a]) & (q.lo[i, a] <= val_hi[:, a])
+                )
+                for j in range(t.n_key):
+                    on = ref == j
+                    lo[on, j] = np.maximum(lo[on, j], q.lo[i, a] - val_hi[on, a])
+                    hi[on, j] = np.minimum(hi[on, j], q.hi[i, a] - val_lo[on, a])
+            ok &= np.all(lo <= hi, axis=1)
+        else:
+            ilo = np.maximum(q.lo[i], key_lo)
+            ihi = np.minimum(q.hi[i], key_hi)
+            ok = np.all(ilo <= ihi, axis=1)
+            lo, hi = val_lo.copy(), val_hi.copy()
+            for a in range(t.n_val):
+                for j in range(t.n_key):
+                    on = t.val_ref[:, a] == j
+                    lo[on, a] += ilo[on, j]
+                    hi[on, a] += ihi[on, j]
+        los.append(lo[ok])
+        his.append(hi[ok])
+    return QueryBox(out_shape, np.concatenate(los), np.concatenate(his))
+
+
+def _assert_equals_brute(got: QueryBox, want: QueryBox, merge: bool) -> None:
+    """Unmerged: the same multiset of boxes.  Merged: the same boxes as
+    merging the reference's, which depends on that multiset alone."""
+    if merge:
+        _assert_boxes_equal(got, merge_boxes(want))
+        return
+    assert got.shape == want.shape
+
+    def rows(b):
+        return sorted(map(tuple, np.concatenate([b.lo, b.hi], axis=1).tolist()))
+
+    assert rows(got) == rows(want)
+
+
 # --------------------------------------------------------------------------- #
-# Executor parity vs the per-request joins
+# Executor vs a brute-force join per request
 # --------------------------------------------------------------------------- #
 def test_executor_matches_per_request_joins_exactly():
     """Every route/direction/merge combination, one packed run."""
@@ -78,19 +144,19 @@ def test_executor_matches_per_request_joins_exactly():
             _boxes(shape, int(rng.integers(0, 25)), seed=trial * 7 + j)
             for j in range(int(rng.integers(0, 3)))
         ]
+        want = [_brute_join(q, t, inverse) for q in qs]
         for path in ("auto", "dense", "index", "batched"):
             merge = (trial + len(reqs)) % 3 == 0
             reqs.append(
                 JoinRequest(qs, t, inverse=inverse, merge=merge, path=path)
             )
-            fn = theta_join_inverse_batch if inverse else theta_join_batch
-            oracle.append(fn(qs, t, merge=merge, path=path))
+            oracle.append((want, merge))
     got = BatchedJoinExecutor().run(reqs)
     assert len(got) == len(oracle)
-    for g_list, w_list in zip(got, oracle):
+    for g_list, (w_list, merge) in zip(got, oracle):
         assert len(g_list) == len(w_list)
         for g, w in zip(g_list, w_list):
-            _assert_boxes_equal(g, w)
+            _assert_equals_brute(g, w, merge)
 
 
 def test_executor_worker_count_is_bit_identical():
@@ -112,7 +178,7 @@ def test_executor_worker_count_is_bit_identical():
 
 
 # --------------------------------------------------------------------------- #
-# Property test: batched prov_query == per-hop oracle on random DAGs
+# Property test: prov_query == the uncompressed oracle on random DAGs
 # --------------------------------------------------------------------------- #
 @settings(max_examples=10, deadline=None)
 @given(
@@ -121,27 +187,24 @@ def test_executor_worker_count_is_bit_identical():
     n_shards=st.sampled_from([1, 4]),
 )
 def test_batched_execution_equals_perhop_oracle(n_ops, seed, n_shards):
+    """Graph and path forms, serial and ``parallel=N``, single and sharded
+    stores: every answer is the recorded relations' composed cell set."""
     log = DSLog()
     sharded = ShardedDSLog(n_shards=n_shards)
-    names = _build_random_dag([log, sharded], n_ops, seed)
+    record: list = []
+    names = _build_random_dag([log, sharded], n_ops, seed, record)
     r = np.random.default_rng(seed + 1)
     cells = np.stack([r.integers(0, SIDE, 3), r.integers(0, SIDE, 3)], axis=1)
     src, dst = names[0], names[-1]
+    path = [src, names[1], names[2]]
+    want_path = _oracle_path_cells(record, path, cells)
     for store in (log, sharded):
         for s, t, q in [(src, dst, cells), (dst, src, cells[:1])]:
-            want = store.prov_query(s, t, q, batched=False)
-            for kw in (
-                dict(batched=True),
-                dict(batched=True, parallel=2),
-                dict(batched=True, parallel=4),
-            ):
-                got = store.prov_query(s, t, q, **kw)
-                _assert_boxes_equal(got, want)
-        # path form through the same engines
-        path = [src, names[1], names[2]]
-        want = store.prov_query(path, cells, batched=False)
-        got = store.prov_query(path, cells, batched=True)
-        _assert_boxes_equal(got, want)
+            want = _oracle_cells(record, s, t, q)
+            for kw in (dict(), dict(parallel=2), dict(parallel=4)):
+                _assert_oracle_answer(store.prov_query(s, t, q, **kw), want)
+        # path form through the same engine
+        _assert_oracle_answer(store.prov_query(path, cells), want_path)
 
 
 def _force_kernel_engine(store):
@@ -163,44 +226,42 @@ def _force_kernel_engine(store):
 def test_kernel_engine_blockdiag_equals_perhop_oracle(n_ops, seed, n_shards):
     """ISSUE 8 tentpole, end to end: ``engine="kernel"`` forces every dense
     segment through ``segmented_range_join_pairs`` (block-diagonal schedule
-    when the frontier warrants it) — bit-identical to the per-hop loop on
+    when the frontier warrants it) — equal to the uncompressed oracle on
     random DAGs, DSLog and ShardedDSLog, serial and parallel, under the
     autouse race detector."""
     log = DSLog()
     sharded = ShardedDSLog(n_shards=n_shards)
-    names = _build_random_dag([log, sharded], n_ops, seed)
+    record: list = []
+    names = _build_random_dag([log, sharded], n_ops, seed, record)
     r = np.random.default_rng(seed + 1)
     cells = np.stack([r.integers(0, SIDE, 3), r.integers(0, SIDE, 3)], axis=1)
     src, dst = names[0], names[-1]
+    want = _oracle_cells(record, src, dst, cells)
     for store in (log, sharded):
         store.views.enabled = False  # answer cache would serve the repeats
-        want = store.prov_query(src, dst, cells, batched=False)
         _force_kernel_engine(store)
-        for kw in (
-            dict(batched=True),
-            dict(batched=True, parallel=2),
-            dict(batched=True, parallel=4),
-        ):
-            _assert_boxes_equal(store.prov_query(src, dst, cells, **kw), want)
+        for kw in (dict(), dict(parallel=2), dict(parallel=4)):
+            _assert_oracle_answer(store.prov_query(src, dst, cells, **kw), want)
         assert store.io_stats["batch_tiles_visited"] > 0
 
 
 def test_batch_and_multi_target_forms_parity():
     log = DSLog()
-    names = _build_random_dag([log], 7, seed=42)
+    record: list = []
+    names = _build_random_dag([log], 7, seed=42, record=record)
     r = np.random.default_rng(5)
     cells = np.stack([r.integers(0, SIDE, 4), r.integers(0, SIDE, 4)], axis=1)
     src, dst = names[0], names[-1]
-    want = log.prov_query_batch(src, dst, [cells, cells[:2]], batched=False)
-    got = log.prov_query_batch(src, dst, [cells, cells[:2]], batched=True)
-    for g, w in zip(got, want):
-        _assert_boxes_equal(g, w)
+    batch = [cells, cells[:2]]
+    got = log.prov_query_batch(src, dst, batch)
+    assert len(got) == len(batch)
+    for g, q in zip(got, batch):
+        _assert_oracle_answer(g, _oracle_cells(record, src, dst, q))
     mids = [names[2], dst]
-    want_m = log.prov_query(src, mids, cells, batched=False)
-    got_m = log.prov_query(src, mids, cells, batched=True, parallel=2)
-    assert set(got_m) == set(want_m)
-    for k in want_m:
-        _assert_boxes_equal(got_m[k], want_m[k])
+    got_m = log.prov_query(src, mids, cells, parallel=2)
+    assert set(got_m) == set(mids)
+    for k in mids:
+        _assert_oracle_answer(got_m[k], _oracle_cells(record, src, k, cells))
 
 
 # --------------------------------------------------------------------------- #
@@ -211,7 +272,7 @@ def test_io_stats_meter_batched_dispatches():
     names = _build_random_dag([log], 6, seed=9)
     cells = np.array([[1, 2], [5, 6]])
     base = dict(log.io_stats)
-    log.prov_query(names[0], names[-1], cells, batched=True)
+    log.prov_query(names[0], names[-1], cells)
     assert log.io_stats["kernel_launches"] > base["kernel_launches"]
     assert log.io_stats["joins_packed"] > base["joins_packed"]
     assert (
@@ -220,16 +281,21 @@ def test_io_stats_meter_batched_dispatches():
     # tile meters (ISSUE 8): every dense dispatch charges its schedule
     assert log.io_stats["batch_tiles_visited"] > 0
     assert log.io_stats["batch_tiles_skipped"] >= 0
-    # per-hop loop does not touch the batching meters
-    base = dict(log.io_stats)
-    log.prov_query(names[0], names[-1], cells, batched=False)
-    assert log.io_stats["kernel_launches"] == base["kernel_launches"]
+    # workers split a wave's segments, not its launch: the same query
+    # meters the same dispatches serial and with parallel=2
+    log.views.enabled = False  # the answer cache would serve the repeats
+    launches = []
+    for kw in (dict(), dict(parallel=2)):
+        base = dict(log.io_stats)
+        log.prov_query(names[0], names[-1], cells, **kw)
+        launches.append(log.io_stats["kernel_launches"] - base["kernel_launches"])
+    assert launches[0] == launches[1] > 0
 
 
 def test_sharded_io_stats_aggregate_batching_counters():
     sharded = ShardedDSLog(n_shards=2)
     names = _build_random_dag([sharded], 6, seed=9)
-    sharded.prov_query(names[0], names[-1], np.array([[1, 2]]), batched=True)
+    sharded.prov_query(names[0], names[-1], np.array([[1, 2]]))
     assert sharded.io_stats["kernel_launches"] > 0
     # the facade aggregates the tile meters across root + shards
     assert sharded.io_stats["batch_tiles_visited"] > 0
@@ -272,18 +338,34 @@ def test_int64_coordinates_join_correctly_via_numpy_dense():
     assert res_inv.n_rows >= 1
 
 
+def _claim_device_without_kernel(monkeypatch):
+    """Make the engine believe an accelerator is attached, and fail any
+    launch of the segmented kernel: a join must reach the twin."""
+    from repro.kernels import ops
+
+    def no_kernel(*a, **kw):
+        raise AssertionError("kernel launched for an unkernelable segment")
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    monkeypatch.setattr(ops, "segmented_range_join_pairs", no_kernel)
+
+
 def test_kernel_path_refuses_int64_and_twin_handles_it(monkeypatch):
-    from repro.core import query as qmod
     from repro.kernels import ops
 
     big = np.full((4, 2), 2**31 + 7, np.int64)
-    small = np.zeros((4, 2), np.int64)
     # the packer raises loudly instead of wrapping
     with pytest.raises(ValueError, match="int32"):
         ops.range_join_pairs(big, big, big, big)
-    # _kernel_pairs routes away (returns None) even when a device is claimed
-    monkeypatch.setattr(ops, "default_interpret", lambda: False)
-    assert qmod._kernel_pairs(big, big, small, small + 10) is None
+    # with a device claimed, the functional join's int64 hop is planned and
+    # answered by the twin, never packed for the kernel
+    _claim_device_without_kernel(monkeypatch)
+    assert dense_backend(1, int32_ok=False) == "np:i64"
+    t = _huge_coord_table()
+    qv = QueryBox(t.val_shape, t.val_lo[:3] - 1, t.val_hi[:3] + 1)
+    got = theta_join_inverse_batch([qv], t, merge=False, path="dense")[0]
+    _assert_equals_brute(got, _brute_join(qv, t, inverse=True), merge=False)
+    assert got.n_rows >= 3
 
 
 def test_executor_skips_kernel_pack_for_overflowing_segment():
@@ -296,15 +378,14 @@ def test_executor_skips_kernel_pack_for_overflowing_segment():
         JoinRequest([qv], t_big, inverse=True, path="dense"),
     ]
     want = [
-        theta_join_batch(reqs[0].queries, t_small, path="dense"),
-        theta_join_inverse_batch([qv], t_big, path="dense"),
+        _brute_join(reqs[0].queries[0], t_small, inverse=False),
+        _brute_join(qv, t_big, inverse=True),
     ]
-    # interpret=True: everything through the twin (this container has no TPU;
-    # the kernel-eligibility partition itself is covered by fits_int32 tests)
+    # interpret=True: everything through the twin (the kernel-eligibility
+    # partition itself is covered by the unkernelable-segment test below)
     got = BatchedJoinExecutor(interpret=True).run(reqs)
-    for g_list, w_list in zip(got, want):
-        for g, w in zip(g_list, w_list):
-            _assert_boxes_equal(g, w)
+    for (g,), w in zip(got, want):
+        _assert_equals_brute(g, w, merge=True)
 
 
 # --------------------------------------------------------------------------- #
@@ -333,11 +414,55 @@ def test_high_dimensional_table_joins_via_numpy(monkeypatch):
     with pytest.raises(ValueError, match="lane capacity"):
         ops.range_join_pairs(key_lo, key_lo + 1, key_lo, key_lo + 1)
     # even with a device claimed, the dense route must fall back, not raise
-    monkeypatch.setattr(ops, "default_interpret", lambda: False)
-    from repro.core.query import _kernel_pairs
-
-    assert _kernel_pairs(q.lo, q.hi, t.key_lo, t.key_hi) is None
+    _claim_device_without_kernel(monkeypatch)
     assert dense_backend(l) == "np:wide"
+    got = theta_join(q, t, merge=False, path="dense")
+    _assert_equals_brute(got, _brute_join(q, t, inverse=False), merge=False)
+
+
+def _wide_request():
+    """65 key attributes: past the kernel's 128-lane pack."""
+    l, n = 65, 30
+    r = np.random.default_rng(4)
+    key_lo = r.integers(0, 4, (n, l))
+    t = CompressedTable(
+        key_shape=(8,) * l,
+        val_shape=(8,),
+        key_lo=key_lo,
+        key_hi=key_lo + 1,
+        val_lo=r.integers(0, 4, (n, 1)),
+        val_hi=r.integers(4, 8, (n, 1)),
+        val_ref=np.full((n, 1), -1),
+    )
+    lo, hi = np.zeros((2, l), np.int64), np.full((2, l), 7)
+    hi[1, 0] = 1  # the second box meets only rows keyed low on axis 0
+    return JoinRequest([QueryBox(t.key_shape, lo, hi)], t, merge=False,
+                       path="dense")
+
+
+def _int64_request():
+    t = _huge_coord_table()
+    qv = QueryBox(t.val_shape, t.val_lo[:4] - 1, t.val_hi[:4] + 1)
+    return JoinRequest([qv], t, inverse=True, merge=False, path="dense")
+
+
+@pytest.mark.parametrize("make", [_wide_request, _int64_request],
+                         ids=["wide", "int64"])
+def test_executor_gives_unkernelable_segments_to_twin(make, monkeypatch):
+    """With a device claimed, a segment the kernel cannot express (lane
+    capacity, int32 overflow) is answered by the twin, never launched."""
+    _claim_device_without_kernel(monkeypatch)
+    req = make()
+    counts: dict = {}
+
+    def bump(key, n=1):
+        counts[key] = counts.get(key, 0) + n
+
+    (got,) = BatchedJoinExecutor(stats=bump).run([req])[0]
+    assert counts.get("twin_launches") == 1
+    want = _brute_join(req.queries[0], req.table, req.inverse)
+    assert want.n_rows > 0
+    _assert_equals_brute(got, want, merge=False)
 
 
 def test_describe_shows_route_backend_notes():
@@ -351,10 +476,8 @@ def test_describe_shows_route_backend_notes():
     text = plan.describe()
     assert "batched(" in text  # routing decision + backend note are visible
     assert "np:" in text  # this container has no TPU
-    # the per-hop engine plans the same hops as plain dense
-    log.planner.batched = False
-    assert "dense" in log.planner.plan("a", ["b"]).describe()
-    log.planner.batched = True
+    # every dense hop is planned for the packed engine
+    assert "/dense" not in text
 
 
 def test_sharded_describe_shows_notes():
@@ -385,7 +508,9 @@ def test_autotune_table_persists_across_save_load(tmp_path):
     assert log2.autotune.lookup("interpret", "k3q5r5w2") == geom
 
     sharded = ShardedDSLog(n_shards=2, root=d2)
-    names = _build_random_dag([sharded], 4, seed=1)
+    record: list = []
+    names = _build_random_dag([sharded], 4, seed=1, record=record)
+    # a twin ("np") entry as older stores persisted it still loads
     sharded.autotune.pick(
         "np", "k2q4r4w2",
         runner=lambda g: g, candidates=((1 << 20,), (1 << 22,)), warmup=False,
@@ -393,11 +518,11 @@ def test_autotune_table_persists_across_save_load(tmp_path):
     sharded.save()
     re = ShardedDSLog.load(d2)
     assert re.autotune.lookup("np", "k2q4r4w2") is not None
-    # queries still answer identically on the reopened store
+    # queries answer the oracle on the reopened store
     cells = np.array([[1, 1], [2, 3]])
-    _assert_boxes_equal(
-        re.prov_query(names[0], names[-1], cells, batched=True),
-        sharded.prov_query(names[0], names[-1], cells, batched=False),
+    _assert_oracle_answer(
+        re.prov_query(names[0], names[-1], cells),
+        _oracle_cells(record, names[0], names[-1], cells),
     )
 
 
@@ -428,7 +553,8 @@ def test_autotune_cache_invalidated_by_backend_change():
 
 def test_describe_note_renders_launch_geometry():
     """ISSUE 8 satellite: the hop note names the engine's launch geometry —
-    ``batched(np:cpu:4m)`` on this box (twin, 4M-cell mask blocks)."""
+    ``batched(np:cpu:4m)`` on this box (twin, its fixed 4M-cell mask
+    blocks); the kernel's label follows its last launch."""
     log = DSLog(store_forward=True)
     log.define_array("a", SHAPE)
     log.define_array("b", SHAPE)
@@ -437,9 +563,12 @@ def test_describe_note_renders_launch_geometry():
     log.add_lineage("a", "b", identity_lineage(SHAPE))
     text = log.planner.plan("a", ["b"]).describe()
     assert "batched(np:cpu:4m)" in text
-    # a tuned twin geometry shows up in later notes
-    log.planner.executor._last_geometry["np"] = (1 << 20,)
-    assert "np:cpu:1m" in log.planner.plan("a", ["b"]).describe()
+    log.prov_query("a", "b", np.array([[1, 1]]))
+    assert "batched(np:cpu:4m)" in log.planner.plan("a", ["b"]).describe()
+    ex = log.planner.executor
+    assert ex.geometry_label("tpu") == "256x256"  # default before a launch
+    ex._last_geometry = (64, 128)
+    assert ex.geometry_label("tpu") == "64x128"
 
 
 def test_planner_discount_tracks_measured_occupancy():

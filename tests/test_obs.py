@@ -268,16 +268,17 @@ def test_trace_dense_join_stages_nest_in_the_launch(engine, stages, race_detecto
         assert extract.attrs["pairs"] == 2
 
 
-@pytest.mark.parametrize("batched", [True, False], ids=["wave", "per_hop"])
-def test_trace_hop_record_per_engine(batched, race_detector):
-    """The per-hop loop times each join as a scoped ``hop`` span; batched
-    engines join a wave at once and record the hop as an instant."""
+@pytest.mark.parametrize("form", ["graph", "path"], ids=["wave", "path"])
+def test_trace_hop_record_per_engine(form, race_detector):
+    """A wave joins all its hops at once, so each query form records the
+    hop as an instant inside a ``wave`` span."""
     log = _one_hop(DSLog())
-    _, tr = log.prov_query("B", "A", np.array([[2, 2]]), batched=batched, trace=True)
+    args = ("B", "A") if form == "graph" else (["B", "A"],)
+    _, tr = log.prov_query(*args, np.array([[2, 2]]), trace=True)
     (hop,) = tr.spans(kind="hop")
     assert hop.attrs["u"] == "B" and hop.attrs["pairs"] >= 1
-    assert (hop.duration is None) is batched
-    assert ("wave" in tr.kinds()) is batched
+    assert hop.duration is None
+    assert "wave" in tr.kinds()
 
 
 @pytest.mark.parametrize("hop,route", [("sort", "sorted"), ("maxpool", "lattice")])
@@ -425,19 +426,11 @@ def test_describe_analyze_reports_est_vs_measured():
     assert "measured" not in plan.describe()
 
 
-def test_describe_analyze_serial_engine_times_each_hop():
-    log = _one_hop(DSLog())
-    plan = log.planner.plan("B", "A", batched=False)
-    boxes = log._as_boxes("B", [np.array([[2, 2]])])
-    log.planner.execute(plan, boxes, batched=False)
-    assert "time=" in plan.describe(analyze=True)
-
-
 def test_sharded_describe_analyze_includes_exchanges(race_detector):
     sl = ShardedDSLog(n_shards=4)
     _build_random_dag([sl], n_ops=6, seed=11)
     sl.prov_query("a6", "a0", np.array([[3, 3]]))
-    plan = sl.views.plan_get("a6", ("a0",), None) or sl.planner.plan("a6", "a0")
+    plan = sl.views.plan_get("a6", ("a0",)) or sl.planner.plan("a6", "a0")
     txt = plan.describe(analyze=True)
     assert "est_pairs=" in txt
 

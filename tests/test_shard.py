@@ -13,6 +13,7 @@ import tempfile
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
+from test_query import oracle_backward, oracle_forward
 
 from repro.core.capture import (
     flip_lineage,
@@ -23,6 +24,7 @@ from repro.core.capture import (
 )
 from repro.core.catalog import DSLog
 from repro.core.graph import CycleError
+from repro.core.query import QueryBox, canonical_boxes
 from repro.core.shard import (
     AffinityShardPolicy,
     HashShardPolicy,
@@ -48,12 +50,15 @@ _OPS = [
 ]
 
 
-def _build_random_dag(logs, n_ops: int, seed: int):
+def _build_random_dag(logs, n_ops: int, seed: int, record=None):
     """Drive identical op streams into several stores.
 
     A chain backbone (a0 → a1 → …) guarantees a route end to end; every
     third op is a two-input fan-in whose second parent is a random earlier
     array — under hashing those parents regularly land on distinct shards.
+    ``record``, when given, receives every op's raw relations in ingest
+    order as ``(input array, output array, LineageRelation)`` — what
+    :func:`_oracle_cells` composes.
     """
     rng = np.random.default_rng(seed)
     names = ["a0"]
@@ -76,6 +81,8 @@ def _build_random_dag(logs, n_ops: int, seed: int):
                     capture=lambda ra=rel_a, rb=rel_b: {(0, 0): ra, (0, 1): rb},
                     reuse=False,
                 )
+            if record is not None:
+                record += [(prev, new, rel_a), (other, new, rel_b)]
         else:
             state = rng.bit_generator.state
             for log in logs:
@@ -87,8 +94,56 @@ def _build_random_dag(logs, n_ops: int, seed: int):
                     capture=lambda r=rel: {(0, 0): r},
                     reuse=False,
                 )
+            if record is not None:
+                record.append((prev, new, rel))
         names.append(new)
     return names
+
+
+def _oracle_cells(record, src, dst, cells) -> set:
+    """The uncompressed answer of ``prov_query(src, dst, cells)``: the cell
+    sets of :func:`_build_random_dag`'s recorded relations composed along
+    the DAG — forward when ``dst`` is downstream of ``src``, else backward —
+    with a union at every fan-in.  Ingest order is a topological order."""
+    start = {tuple(int(v) for v in c) for c in cells}
+    front = {src: start}
+    for a, b, rel in record:
+        if a in front:
+            front[b] = front.get(b, set()) | oracle_forward(rel, front[a])
+    if dst in front:
+        return front[dst]
+    front = {src: start}
+    for a, b, rel in reversed(record):
+        if b in front:
+            front[a] = front.get(a, set()) | oracle_backward(rel, front[b])
+    return front[dst]
+
+
+def _oracle_path_cells(record, path, cells) -> set:
+    """The uncompressed answer of ``prov_query(path, cells)``: each hop
+    unions every recorded relation between its two arrays, in whichever
+    dataflow direction it was registered."""
+    cur = {tuple(int(v) for v in c) for c in cells}
+    for a, b in zip(path[:-1], path[1:]):
+        nxt = set()
+        for x, y, rel in record:
+            if (x, y) == (a, b):
+                nxt |= oracle_forward(rel, cur)
+            elif (x, y) == (b, a):
+                nxt |= oracle_backward(rel, cur)
+        cur = nxt
+    return cur
+
+
+def _assert_oracle_answer(got: QueryBox, want: set, merge: bool = True) -> None:
+    """``got`` covers exactly the cells ``want``; a merged answer is also
+    byte-identical to the canonical decomposition of those cells."""
+    assert got.cell_set() == want
+    if merge:
+        cells = np.array(sorted(want), np.int64).reshape(-1, len(got.shape))
+        exp = canonical_boxes(QueryBox.from_cells(got.shape, cells))
+        assert got.lo.tobytes() == exp.lo.tobytes()
+        assert got.hi.tobytes() == exp.hi.tobytes()
 
 
 def _diamond(log, pins=None):
